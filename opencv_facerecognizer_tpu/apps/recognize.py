@@ -164,8 +164,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--capacity", type=int, default=4096, help="gallery capacity")
     p.add_argument("--gallery-dtype", choices=["bf16", "f32"], default="bf16",
                    help="device dtype of gallery rows. bf16 (default): half "
-                        "the gallery HBM and 1.24x faster match at 1M rows "
-                        "(measured, BENCH_DETAIL.json:gallery_dtype), "
+                        "the gallery HBM and upload bytes (match speed on "
+                        "the local chip: not measured), "
                         "numerically identical — both matchers compute "
                         "bf16 x bf16 -> f32 regardless of storage")
     # ---- large-gallery matching (parallel.quantizer / ops.ivf_match;
@@ -211,9 +211,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "publishes degraded mode on the status topic and "
                         "(with --probe-on-degraded) checks the backend")
     p.add_argument("--probe-on-degraded", action="store_true",
-                   help="on entering degraded mode, run the bounded "
-                        "subprocess backend probe (utils.backend_probe) "
-                        "and attach its verdict to the status message")
+                   help="on entering degraded mode, run the deadline-"
+                        "bounded in-process device probe and attach its "
+                        "verdict to the status message; a dead device then "
+                        "rebuilds the pipeline on the host CPU (explicit, "
+                        "announced)")
     p.add_argument("--supervised", action="store_true",
                    help="wrap the service in a ServiceSupervisor: a crash "
                         "that kills the serving loop is restarted with "
@@ -471,7 +473,12 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def _load_stack(args):
+def _load_stack(args, mesh=None):
+    """Checkpoints + startup gallery -> (pipeline, subject names).
+    ``mesh`` overrides the default (every visible device on ``tp``) for
+    callers that place the fused path themselves (``chip_smoke.py`` pins
+    its kernel phases to one device so they mean the same thing on a
+    one-chip and a four-chip host)."""
     import numpy as np
 
     from opencv_facerecognizer_tpu.models.detector import CNNFaceDetector
@@ -531,7 +538,7 @@ def _load_stack(args):
                 f"{e}; use --parallel fused on this host"
             )
     else:
-        gallery_mesh = make_mesh()
+        gallery_mesh = mesh if mesh is not None else make_mesh()
 
     import jax.numpy as jnp
 
@@ -601,6 +608,102 @@ def _load_stack(args):
             cascade=face_gate,
         )
     return pipeline, names
+
+
+def train_quantizer_if_wanted(gallery) -> None:
+    """Train the IVF shortlist before serving starts when the gallery's
+    tier wants it and no build has published yet (sidecar missed, or no
+    ``--state-dir``) — predictable startup beats a recall-free window.
+    ``skip_if_ready`` rides out the background build an enrolment or
+    recovery poke may already have fired instead of training twice.
+    ``--match-mode auto`` below the capacity threshold skips this and
+    lets the staleness poke build it if the gallery ever grows there."""
+    quantizer = getattr(gallery, "quantizer", None)
+    if quantizer is None or quantizer.ready or not gallery._ivf_wanted():
+        return
+    print(f"training IVF coarse quantizer (nlist={quantizer.nlist})...",
+          file=sys.stderr)
+    quantizer.rebuild_now(wait=True, skip_if_ready=True)
+    print(f"IVF quantizer: {quantizer.stats()}", file=sys.stderr)
+
+
+def build_service(args, pipeline, names, connector, metrics, *,
+                  admission=None, brownout=None, journal=None, state=None,
+                  tracer=None, slo_monitor=None, replica=None):
+    """The ``RecognizerService`` exactly as ``main`` wires it from the
+    parsed flags (tracker, cascade, ingest, ladder, resilience policy) —
+    one construction site, so ``chip_smoke.py`` serves through the same
+    wiring the CLI does instead of a copy of it."""
+    from opencv_facerecognizer_tpu.runtime.ingest import (
+        IngestConfig, resolve_ingest_mode,
+    )
+    from opencv_facerecognizer_tpu.runtime.recognizer import RecognizerService
+    from opencv_facerecognizer_tpu.runtime.resilience import (
+        ResiliencePolicy, rebuild_pipeline_on_cpu,
+    )
+
+    # The --transfer-uint8 deprecation warning fires HERE, once (the
+    # _load_stack probe resolves silently).
+    ingest_cfg = IngestConfig(
+        mode=resolve_ingest_mode(args.ingest_mode, args.transfer_uint8),
+        ring_depth=args.ingest_ring_depth or None,
+        decode_workers=args.ingest_decode_workers)
+
+    tracker = None
+    if not args.no_track_cache:
+        from opencv_facerecognizer_tpu.runtime.tracker import (
+            IdentityTracker, TrackerConfig,
+        )
+
+        # Replica-local by construction: the tracker lives on THIS
+        # service instance, and PR 10's rendezvous routing pins each
+        # topic to one replica — failover/resync lands on a replica
+        # whose cache simply starts cold.
+        tracker = IdentityTracker(
+            TrackerConfig(reverify_frames=max(1, args.track_reverify_frames),
+                          iou_min=args.track_iou_min),
+            metrics=metrics)
+
+    return RecognizerService(
+        pipeline, connector,
+        batch_size=args.batch_size,
+        frame_shape=tuple(args.frame_size),
+        flush_timeout=args.flush_ms / 1e3,
+        similarity_threshold=args.similarity_threshold,
+        subject_names=names,
+        metrics=metrics,
+        # The ingest config owns the transfer dtype now (uint8/jpeg stage
+        # as uint8 through the ring; f32 keeps the legacy dtype).
+        ingest=ingest_cfg,
+        readback_worker=not args.no_readback_worker,
+        readback_poll_s=args.readback_poll_ms / 1e3,
+        drain_poll_s=args.drain_poll_ms / 1e3,
+        bucket_sizes=tuple(b for b in args.bucket_sizes if b > 0),
+        target_latency_s=(None if args.target_latency_ms is None
+                          else args.target_latency_ms / 1e3),
+        admission=admission,
+        brownout=brownout,
+        dead_letter_journal=journal,
+        shed_stale_after_s=(args.shed_stale_after_ms / 1e3
+                            if args.shed_stale_after_ms > 0 else None),
+        state_store=state,
+        resilience=ResiliencePolicy(
+            dispatch_retries=args.dispatch_retries,
+            readback_deadline_s=args.readback_deadline,
+            degraded_after=args.degraded_after,
+            probe_backend_on_degraded=args.probe_on_degraded,
+        ),
+        # Dead accelerator -> rebuild the pipeline on host devices: the
+        # job degrades to CPU speed instead of wedging (README "Failure
+        # handling"). Only reachable with --probe-on-degraded.
+        cpu_fallback=rebuild_pipeline_on_cpu if args.probe_on_degraded else None,
+        tracer=tracer,
+        slo_monitor=slo_monitor,
+        replica=replica,
+        cascade=not args.no_cascade,
+        cascade_threshold=args.cascade_threshold,
+        tracker=tracker,
+    )
 
 
 def run_router(args) -> int:
@@ -824,22 +927,16 @@ def main(argv=None) -> int:
     from opencv_facerecognizer_tpu.runtime.admission import AdmissionController
     from opencv_facerecognizer_tpu.runtime.journal import DeadLetterJournal
     from opencv_facerecognizer_tpu.runtime.resilience import (
-        BrownoutPolicy, ResiliencePolicy, ServiceSupervisor,
-        rebuild_pipeline_on_cpu,
-    )
-    from opencv_facerecognizer_tpu.runtime.ingest import (
-        IngestConfig, resolve_ingest_mode,
+        BrownoutPolicy, ServiceSupervisor,
     )
     from opencv_facerecognizer_tpu.runtime.state_store import StateLifecycle
+    from opencv_facerecognizer_tpu.utils import compile_cache
     from opencv_facerecognizer_tpu.utils.metrics import Metrics
 
-    # The --transfer-uint8 deprecation warning fires HERE, once (the
-    # _load_stack probe resolves silently).
-    ingest_mode = resolve_ingest_mode(args.ingest_mode, args.transfer_uint8)
-    ingest_cfg = IngestConfig(mode=ingest_mode,
-                              ring_depth=args.ingest_ring_depth or None,
-                              decode_workers=args.ingest_decode_workers)
+    compile_cache.enable()
     pipeline, names = _load_stack(args)
+    for line in pipeline.gallery.describe_matchers():
+        print(f"ocvf-recognize: {line}", file=sys.stderr)
     metrics_sink = open(args.metrics_jsonl, "a") if args.metrics_jsonl else None
     # The latency rolling horizon must cover the longest SLO evaluation
     # window and the ring resolution must cover the shortest (SLOMonitor
@@ -1007,18 +1104,7 @@ def main(argv=None) -> int:
         durability.attach_sinks(journal=journal, span_sink=span_journal,
                                 tracer=tracer)
 
-    if (quantizer is not None and not quantizer.ready
-            and pipeline.gallery._ivf_wanted()):
-        # Sidecar missed (or no --state-dir): train the shortlist before
-        # serving starts — predictable startup beats a recall-free window.
-        # skip_if_ready rides out the background build a recovery poke
-        # may already have fired instead of training a second time.
-        # --match-mode auto below the capacity threshold skips this and
-        # lets the staleness poke build it if the gallery ever grows there.
-        print("training IVF coarse quantizer "
-              f"(nlist={quantizer.nlist})...", file=sys.stderr)
-        quantizer.rebuild_now(wait=True, skip_if_ready=True)
-        print(f"IVF quantizer: {quantizer.stats()}", file=sys.stderr)
+    train_quantizer_if_wanted(pipeline.gallery)
 
     slo_monitor = None
     if args.slo:
@@ -1063,61 +1149,10 @@ def main(argv=None) -> int:
     else:
         connector = FakeConnector()
 
-    tracker = None
-    if not args.no_track_cache:
-        from opencv_facerecognizer_tpu.runtime.tracker import (
-            IdentityTracker, TrackerConfig,
-        )
-
-        # Replica-local by construction: the tracker lives on THIS
-        # service instance, and PR 10's rendezvous routing pins each
-        # topic to one replica — failover/resync lands on a replica
-        # whose cache simply starts cold.
-        tracker = IdentityTracker(
-            TrackerConfig(reverify_frames=max(1, args.track_reverify_frames),
-                          iou_min=args.track_iou_min),
-            metrics=metrics)
-
-    service = RecognizerService(
-        pipeline, connector,
-        batch_size=args.batch_size,
-        frame_shape=tuple(args.frame_size),
-        flush_timeout=args.flush_ms / 1e3,
-        similarity_threshold=args.similarity_threshold,
-        subject_names=names,
-        metrics=metrics,
-        # The ingest config owns the transfer dtype now (uint8/jpeg stage
-        # as uint8 through the ring; f32 keeps the legacy dtype).
-        ingest=ingest_cfg,
-        readback_worker=not args.no_readback_worker,
-        readback_poll_s=args.readback_poll_ms / 1e3,
-        drain_poll_s=args.drain_poll_ms / 1e3,
-        bucket_sizes=tuple(b for b in args.bucket_sizes if b > 0),
-        target_latency_s=(None if args.target_latency_ms is None
-                          else args.target_latency_ms / 1e3),
-        admission=admission,
-        brownout=brownout,
-        dead_letter_journal=journal,
-        shed_stale_after_s=(args.shed_stale_after_ms / 1e3
-                            if args.shed_stale_after_ms > 0 else None),
-        state_store=state,
-        resilience=ResiliencePolicy(
-            dispatch_retries=args.dispatch_retries,
-            readback_deadline_s=args.readback_deadline,
-            degraded_after=args.degraded_after,
-            probe_backend_on_degraded=args.probe_on_degraded,
-        ),
-        # Dead accelerator -> rebuild the pipeline on host devices: the
-        # job degrades to CPU speed instead of wedging (README "Failure
-        # handling"). Only reachable with --probe-on-degraded.
-        cpu_fallback=rebuild_pipeline_on_cpu if args.probe_on_degraded else None,
-        tracer=tracer,
-        slo_monitor=slo_monitor,
-        replica=replica,
-        cascade=not args.no_cascade,
-        cascade_threshold=args.cascade_threshold,
-        tracker=tracker,
-    )
+    service = build_service(
+        args, pipeline, names, connector, metrics,
+        admission=admission, brownout=brownout, journal=journal, state=state,
+        tracer=tracer, slo_monitor=slo_monitor, replica=replica)
     # Registry wiring: published results + the tracker key on the full
     # stamp; a reader's re-anchor onto a post-swap manifest flushes the
     # identity caches (the writer-side flush rides the swap coordinator).
@@ -1200,6 +1235,8 @@ def main(argv=None) -> int:
             print(f"profile trace written to {args.profile_dir}", file=sys.stderr)
 
     interrupted = False
+    source_finished = False
+    unsettled_at_deadline = 0
     try:
         if args.source == "dir":
             import json
@@ -1217,14 +1254,19 @@ def main(argv=None) -> int:
                     continue
                 img = np.asarray(image_ops.resize(img, tuple(args.frame_size)))
                 connector.inject(FRAME_TOPIC, {**encode_frame(img), "meta": {"file": fn}})
+            # Wait until every admitted frame has SETTLED (published or
+            # counted as a drop) — not for a result per file, which an
+            # abandoned batch would never deliver.
             deadline = time.monotonic() + 60
-            while (len(connector.messages(RESULT_TOPIC)) < len(files)
+            while (service.frames_in_system() > 0
                    and time.monotonic() < deadline
                    and not term_event.is_set()):
                 _stop_profile_if_due()
                 time.sleep(0.05)
+            unsettled_at_deadline = int(service.frames_in_system())
             for message in connector.messages(RESULT_TOPIC):
                 print(json.dumps(message))
+            source_finished = not term_event.is_set()
         else:
             # Serve until the input stream/socket ends (stdin EOF terminates
             # the process instead of spinning forever), SIGTERM, or Ctrl-C;
@@ -1235,6 +1277,8 @@ def main(argv=None) -> int:
                 if term_event.is_set():
                     print("SIGTERM: draining before shutdown", file=sys.stderr)
                     break
+            else:
+                source_finished = True
             service.drain()
     except KeyboardInterrupt:
         interrupted = True
@@ -1281,7 +1325,39 @@ def main(argv=None) -> int:
             lease.release()
         if metrics_sink:
             metrics_sink.close()
+    if source_finished:
+        # A finite source (--source dir, or EOF on jsonl/socket) ran to
+        # its end: every admitted frame must have completed. Anything
+        # else — an abandoned or dead-lettered batch, a shed frame, a
+        # frame still in the system when the wait expired — is a failed
+        # run, named by its counter, not a quiet exit 0.
+        problem = _incomplete_run(shutdown["ledger"], metrics)
+        if unsettled_at_deadline and not problem:
+            problem = (f"{unsettled_at_deadline} frames were still in the "
+                       f"system when the 60 s wait for the directory "
+                       f"replay expired (their results were not printed)")
+        if problem:
+            print(f"ocvf-recognize: FAILED: {problem}", file=sys.stderr)
+            return 1
     return 0
+
+
+def _incomplete_run(ledger, metrics):
+    """Why a finished finite source did not complete every admitted
+    frame, or None when it did (``RecognizerService.ledger()`` shape)."""
+    drops = ledger["drops_by_reason"]
+    if not drops and not ledger["in_system"]:
+        return None
+    from opencv_facerecognizer_tpu.utils import metric_names as mn
+
+    counters = metrics.counters()
+    batches = {name: int(counters[name])
+               for name in (mn.BATCHES_FAILED, mn.BATCHES_DEAD_LETTERED)
+               if counters.get(name)}
+    return (f"{int(sum(drops.values()) + ledger['in_system'])} of "
+            f"{int(ledger['admitted'])} admitted frames did not complete: "
+            f"drops_by_reason={ {k: int(v) for k, v in drops.items()} }, "
+            f"in_system={int(ledger['in_system'])}, {batches}")
 
 
 if __name__ == "__main__":

@@ -61,6 +61,9 @@ def main(argv=None) -> int:
     if args.knn_k != 1 and args.classifier != "nn":
         parser.error(f"--knn-k only applies with --classifier nn "
                      f"(got --classifier {args.classifier})")
+    from opencv_facerecognizer_tpu.utils import compile_cache
+
+    compile_cache.enable()
     from opencv_facerecognizer_tpu.runtime.trainer import TheTrainer, TrainerConfig
 
     if args.model == "auto":

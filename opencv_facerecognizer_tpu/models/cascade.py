@@ -3,7 +3,8 @@ cascade (ISSUE 13; design anchors PAPERS.md — *Compact Convolutional
 Neural Network Cascade for Face Detection* (1508.01292) and *A Fast Face
 Detection Method via CNN* (1803.10103)).
 
-BENCH_DETAIL says detect dominates device cost at every dispatch bucket
+The pre-PR-1 stage table (source deleted in PR 21; not re-measured on the
+local chip) had detect dominating device cost at every dispatch bucket
 (b128: 0.716 ms detect vs 0.449/0.561/0.454 ms for crop/embed/match), yet
 most real camera frames carry zero faces. The cascade answer: run a tiny
 proposal net at REDUCED resolution over every frame first, and invoke the

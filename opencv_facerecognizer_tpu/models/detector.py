@@ -235,7 +235,10 @@ def train_detector(
         boxes, num_boxes, (h, w), boxes.shape[1]
     )
     if params is None:
-        params = model.init(jax.random.PRNGKey(seed), jnp.zeros((1, h, w)))["params"]
+        # jitted: an eager init dispatches (and on an accelerator compiles)
+        # every initializer op one by one; the values are bit-identical.
+        params = jax.jit(model.init)(
+            jax.random.PRNGKey(seed), jnp.zeros((1, h, w)))["params"]
     optimizer = optax.adam(learning_rate)
     opt_state = optimizer.init(params)
     step = make_detector_train_step(model, optimizer)

@@ -379,7 +379,9 @@ def init_embedder(
     """Initialize {net, head} params for training."""
     rng = jax.random.PRNGKey(seed)
     dummy = jnp.zeros((1, *input_shape), dtype=jnp.float32)
-    variables = model.init(rng, dummy)
+    # jitted: an eager init dispatches (and on an accelerator compiles)
+    # every initializer op one by one; the values are bit-identical.
+    variables = jax.jit(model.init)(rng, dummy)
     head = jax.random.normal(
         jax.random.fold_in(rng, 1), (num_classes, model.embed_dim), dtype=jnp.float32
     )

@@ -26,18 +26,6 @@ DP_AXIS = "dp"
 TP_AXIS = "tp"
 
 
-def _distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` where it exists (jax >= 0.5);
-    on older jax fall back to probing the module-level client state — the
-    call must degrade to "not initialized", never AttributeError, on any
-    jax this repo's env gates allow."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    state = getattr(jax.distributed, "global_state", None)
-    return state is not None and getattr(state, "client", None) is not None
-
-
 def initialize_multihost(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -61,7 +49,7 @@ def initialize_multihost(
     False when neither arguments nor env vars ask for multi-host — callers
     never need to branch.
     """
-    if _distributed_is_initialized():
+    if jax.distributed.is_initialized():
         return True
     coordinator_address = coordinator_address or os.environ.get(
         "JAX_COORDINATOR_ADDRESS"

@@ -72,10 +72,12 @@ registered in ``utils.metric_names``:
 - ``stage_share_b<bucket>_<detect|crop|embed|match>`` — per-bucket stage
   shares of the fused device step. The stages run inside ONE jitted call
   at serving time (deliberately — the single-readback design), so live
-  per-stage splits are unobservable; the shares come from the committed
-  ablated-prefix measurements in ``BENCH_DETAIL.json``
-  (``stage_attribution.per_batch``, measured by ``bench.py`` on this
-  hardware) for exactly the buckets the dispatch spans show serving.
+  per-stage splits are unobservable; the shares come from the
+  ablated-prefix stage table a benchmark run ON THIS MACHINE writes to
+  ``BENCH_DETAIL.json`` (``stage_attribution.per_batch``, by ``bench.py``)
+  for exactly the buckets the dispatch spans show serving. No such table
+  is committed — another machine's numbers must never ride a live gauge —
+  so these gauges stay UNSET until that benchmark has run here.
 """
 
 from __future__ import annotations
@@ -110,7 +112,8 @@ class _BadQuery(ValueError):
 
 #: default bench artifact location: resolved relative to the REPO (two
 #: levels above this module), not the process CWD — ``ocvf-recognize``
-#: launched from any directory must still find the committed stage table.
+#: launched from any directory must still find the stage table a local
+#: benchmark run wrote (none is committed; absent = gauges unset).
 DEFAULT_BENCH_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "BENCH_DETAIL.json")
@@ -118,7 +121,7 @@ DEFAULT_BENCH_PATH = os.path.join(
 
 def load_stage_quotes(bench_path: str = DEFAULT_BENCH_PATH
                       ) -> Dict[int, Dict[str, float]]:
-    """Per-batch-size stage cost quotes (ms) from the committed bench
+    """Per-batch-size stage cost quotes (ms) from the local bench
     artifact's ``stage_attribution.per_batch`` table; ``{}`` when the
     artifact (or the section) is absent — the gauges are then simply not
     set, never fabricated."""

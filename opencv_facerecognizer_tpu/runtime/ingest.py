@@ -1,8 +1,9 @@
 """Ingest pipeline: the subsystem between connector/admission and the
 dispatch ladder (ROADMAP item #1 — the serving loop is transfer-bound).
 
-BENCH_DETAIL's evidence: b32 H2D crosses at 6.2 ms p50 (1.3 GB/s, f32)
-against ~0.64 ms of device compute, so e2e is ~10x device cost — and the
+The record this was built on (pre-PR-1 backend, source deleted in PR 21,
+not reproducible; not re-measured on the local chip): b32 H2D crossed at
+6.2 ms p50 (1.3 GB/s, f32) against ~0.64 ms of device compute — and the
 old ``--transfer-uint8`` shortcut, which cut bytes 4x, paid a catastrophic
 118 ms p99 because every batch staged through a freshly-allocated host
 array (page faults + allocator churn on the hot path) and synchronized

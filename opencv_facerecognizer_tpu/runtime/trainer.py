@@ -34,7 +34,9 @@ from opencv_facerecognizer_tpu.models import (
     SpatialHistogram,
     TanTriggsPreprocessing,
 )
-from opencv_facerecognizer_tpu.models.embedder import CNNEmbedding
+from opencv_facerecognizer_tpu.models.embedder import (
+    SERVING_EMBEDDER_KWARGS, CNNEmbedding,
+)
 from opencv_facerecognizer_tpu.ops import lbp as lbp_ops
 from opencv_facerecognizer_tpu.ops.distance import (
     ChiSquareDistance,
@@ -133,12 +135,17 @@ class TheTrainer:
             classifier = NearestNeighbor(CosineDistance(), k=cfg.knn_k)
         elif cfg.model == "cnn":
             serialization.register(CNNEmbedding)
-            feature = CNNEmbedding(
-                embed_dim=cfg.embed_dim,
-                input_size=cfg.image_size,
-                train_steps=cfg.train_steps,
+            # The serving embedder's structure (stem/stage widths, block,
+            # norm) is the trainer's default, so ``ocvf-train --model cnn
+            # --embed-dim 256 --image-size 64 64`` yields exactly the net
+            # ``SERVING_EMBEDDER_KWARGS`` names; ``cnn_kwargs`` overrides.
+            feature = CNNEmbedding(**{
+                **SERVING_EMBEDDER_KWARGS,
+                "embed_dim": cfg.embed_dim,
+                "input_size": cfg.image_size,
+                "train_steps": cfg.train_steps,
                 **cfg.cnn_kwargs,
-            )
+            })
             classifier = NearestNeighbor(CosineDistance(), k=cfg.knn_k)
         else:
             raise ValueError(f"unknown model type {self.config.model!r}")

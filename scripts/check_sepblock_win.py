@@ -1,7 +1,6 @@
-"""Gate for the measurement queue's conditional fused-schedule re-run:
-exit 0 iff BENCH_DETAIL.json's sepblock_fused A/B (scripts/
-bench_sepblock.py) recorded a >= 5% speedup at any measured batch.
-Kept as a script (not a heredoc in run_measurement_queue.sh) so the
+"""Gate for a conditional fused-schedule re-run: exit 0 iff
+BENCH_DETAIL.json's sepblock_fused A/B (scripts/bench_sepblock.py)
+recorded a >= 5% speedup at any measured batch. Kept as a script so the
 decision logic is unit-testable — tests/test_queue_gate.py."""
 
 from __future__ import annotations

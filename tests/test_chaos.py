@@ -451,19 +451,46 @@ def test_transient_error_classification():
     assert is_transient_error(ConnectionResetError("connection reset by peer"))
     assert not is_transient_error(ValueError("shape mismatch [8, 64, 64]"))
     assert not is_transient_error(TypeError("not an array"))
+    # A local chip's OOM / scoped-VMEM refusal is permanent for that shape.
+    assert not is_transient_error(RuntimeError(
+        "RESOURCE_EXHAUSTED: Resource exhausted: out of memory in HBM"))
 
 
-def test_probe_for_recovery_injectable_and_bounded():
-    from opencv_facerecognizer_tpu.utils.backend_probe import probe_for_recovery
+def test_probe_device_ok_raises_and_deadline(monkeypatch):
+    """The degraded-mode probe runs IN this process against the device the
+    service holds (a child process could never open a locally attached
+    chip): healthy -> ok, a failing device op -> the error as the reason,
+    a device call that never returns -> bounded by the deadline."""
+    import threading
 
-    usable, reason = probe_for_recovery(
-        timeout_s=30.0, probe_source="import sys; sys.exit(0)")
+    import jax
+
+    from opencv_facerecognizer_tpu.runtime.resilience import probe_device
+
+    usable, reason = probe_device(jax.devices()[0], timeout_s=30.0)
     assert usable and reason == "ok"
-    t0 = time.monotonic()
-    usable, reason = probe_for_recovery(
-        timeout_s=0.5, probe_source="import time; time.sleep(30)")
-    assert not usable and "hang-mode" in reason
-    assert time.monotonic() - t0 < 5.0  # bounded, killed at the deadline
+
+    usable, reason = probe_device("not-a-device", timeout_s=30.0)
+    assert not usable and reason.startswith("device op failed")
+
+    release = threading.Event()
+    monkeypatch.setattr(jax, "device_put",
+                        lambda *a, **k: release.wait(timeout=60.0))
+    try:
+        t0 = time.monotonic()
+        usable, reason = probe_device(jax.devices()[0], timeout_s=0.3)
+        assert not usable and "deadline" in reason
+        assert time.monotonic() - t0 < 5.0  # bounded, not the call's 60 s
+    finally:
+        release.set()
+
+
+def test_default_backend_probe_uses_the_held_device(chaos_stack):
+    """No injected probe fn: the service asks the device its gallery lives
+    on, in-process, and gets a (usable, reason) pair back."""
+    pipe, _ = chaos_stack
+    service, _connector = _make_service(pipe)
+    assert service._probe_backend() == (True, "ok")
 
 
 # ---------- chaos soak ----------

@@ -267,10 +267,7 @@ def test_initialize_multihost_env_and_args(monkeypatch):
     calls = []
     monkeypatch.setattr(jax.distributed, "initialize",
                         lambda **kw: calls.append(kw))
-    # raising=False: jax < 0.5 has no is_initialized — the attr is created
-    # here and mesh._distributed_is_initialized picks it up via getattr.
-    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False,
-                        raising=False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: False)
     # env-var path
     monkeypatch.setenv("JAX_COORDINATOR_ADDRESS", "10.0.0.1:1234")
     monkeypatch.setenv("JAX_NUM_PROCESSES", "4")
@@ -286,8 +283,7 @@ def test_initialize_multihost_env_and_args(monkeypatch):
     assert calls[-1] == {"coordinator_address": None,
                          "num_processes": 8, "process_id": 3}
     # already-initialized short circuit
-    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True,
-                        raising=False)
+    monkeypatch.setattr(jax.distributed, "is_initialized", lambda: True)
     n = len(calls)
     assert mesh_mod.initialize_multihost() is True
     assert len(calls) == n
@@ -478,7 +474,7 @@ def test_gallery_async_grow_failed_upload_restores_rows_and_retries():
     def dying_build(*a, **k):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise RuntimeError("tunnel RPC died mid-upload")
+            raise RuntimeError("device RPC died mid-upload")
         return real_build(*a, **k)
 
     g._build_snapshot = dying_build

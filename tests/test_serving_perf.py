@@ -1,7 +1,7 @@
 """Tier-1 serving-loop perf smoke (fast, deterministic, no hardware).
 
 Drives ``bench_serving.run_smoke`` over the fake instant backend
-(``runtime.fakes.InstantPipeline``), which emulates the tunneled backend's
+(``runtime.fakes.InstantPipeline``), which emulates a backend's
 ~100 ms ``is_ready`` sync-poll floor on CPU. The overlapped pipeline
 (readback worker + continuous batching) must sustain the offered load with
 **zero drops** and keep ``ready_wait`` p50 far below that poll floor — the
