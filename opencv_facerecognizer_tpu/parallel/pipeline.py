@@ -345,12 +345,15 @@ class RecognitionPipeline:
         if fn is None:
             net = gate.net
 
-            def stage1(params, fr):
+            def gate_stage1(params, fr):
                 # uint8 ingest frames cast on device, like the fused step.
                 return cascade_mod.frame_scores(net, params,
                                                 fr.astype(jnp.float32))
 
-            fn = self._cascade_cache[key] = jax.jit(stage1)  # ocvf-lint: boundary=jit-recompile-hazard -- cache-keyed stage-1 builder: warmup compiles every (rung, ingest dtype) signature up front; serving lands here only on a genuinely new shape
+            # The closure's name is the program's on the device trace's
+            # "XLA Modules" line (``jit_gate_stage1``), where device time
+            # per program is read: rename it and those readers go blind.
+            fn = self._cascade_cache[key] = jax.jit(gate_stage1)  # ocvf-lint: boundary=jit-recompile-hazard -- cache-keyed stage-1 builder: warmup compiles every (rung, ingest dtype) signature up front; serving lands here only on a genuinely new shape
         return fn(gate.params, frames)
 
     # ---- model-registry installs (runtime.registry swaps) ----

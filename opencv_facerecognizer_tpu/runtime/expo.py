@@ -61,14 +61,9 @@ Metrics surface (``expo_requests`` / ``expo_errors``). The one nuance:
 on the serving loop's tick and (as a liveness backstop for wedged loops)
 on this server's background refresh thread — never on a request thread.
 
-**Stage attribution** (``fold_attribution``): two derived gauge families
+**Stage attribution** (``fold_attribution``): one derived gauge family
 registered in ``utils.metric_names``:
 
-- ``device_busy_fraction`` — union of the tracer's recent ``ready_wait``
-  batch-span intervals over a trailing window (the same interval-union
-  technique ``scripts/trace_summary.py`` applies to offline device
-  traces, fed from live spans — a periodic in-process probe instead of an
-  xplane capture);
 - ``stage_share_b<bucket>_<detect|crop|embed|match>`` — per-bucket stage
   shares of the fused device step. The stages run inside ONE jitted call
   at serving time (deliberately — the single-readback design), so live
@@ -155,10 +150,6 @@ def fold_attribution(tracer, metrics, bench_path: str = DEFAULT_BENCH_PATH,
     out: Dict[str, float] = {}
     if tracer is None or metrics is None:
         return out
-    spans = tracer.snapshot(topic=tracing.BATCH_TOPIC)
-    busy = tracing.device_busy_fraction(spans, window_s=window_s)
-    metrics.set_gauge(mn.DEVICE_BUSY_FRACTION, busy)
-    out[mn.DEVICE_BUSY_FRACTION] = busy
     quotes = _quotes_cache.get(bench_path)
     if quotes is None:
         quotes = load_stage_quotes(bench_path)
@@ -166,6 +157,7 @@ def fold_attribution(tracer, metrics, bench_path: str = DEFAULT_BENCH_PATH,
             _quotes_cache[bench_path] = quotes
     if not quotes:
         return out
+    spans = tracer.snapshot(topic=tracing.BATCH_TOPIC)
     lo = time.monotonic() - window_s
     buckets = {s.get("bucket") for s in spans
                if s.get("stage") == "dispatch" and s["t0"] >= lo

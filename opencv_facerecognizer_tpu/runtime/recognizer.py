@@ -221,6 +221,31 @@ class _ReadbackBlocker:
         return "ready" if self._ok else "raised"
 
 
+class _Leaf:
+    """One leaf of the serving loop's time (README "Observability", the
+    table of leaves): the block's seconds are added to ``busy[stage]``,
+    which the loop flushes as the always-on counter ``loop_s_<stage>``
+    once per batch, and ``span`` (the tracer's, or ``NULL_SPAN`` for an
+    untraced batch) is entered around the same block."""
+
+    __slots__ = ("_busy", "_stage", "_span", "_t0")
+
+    def __init__(self, busy: Dict[str, float], stage: str, span):
+        self._busy = busy
+        self._stage = stage
+        self._span = span
+
+    def __enter__(self):
+        self._t0 = time.monotonic()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self._busy[self._stage] = (self._busy.get(self._stage, 0.0)
+                                   + time.monotonic() - self._t0)
+        return False
+
+
 class RecognizerService:
     def __init__(
         self,
@@ -387,6 +412,12 @@ class RecognizerService:
         self._reject_last_pub: Dict[str, float] = {}
         self._reject_lock = threading.Lock()
         self.tracer = tracer
+        # Busy time of the serving loop's current iteration by leaf
+        # (``_leaf``), and the span id of the batch's open ``dispatch``
+        # span, which its leaves name as parent. Touched by the loop's
+        # thread only.
+        self._loop_busy: Dict[str, float] = {}
+        self._dispatch_span = 0
         self.slo = slo_monitor
         self.replica = replica
         # Embedder-rollout coordinator (runtime.rollout.RolloutCoordinator),
@@ -796,6 +827,36 @@ class RecognizerService:
             return self._bucket_ladder[0]
         return None
 
+    # ---- the serving loop's leaves (busy time + spans) ----
+
+    def _leaf(self, stage: str, batch_tid: int = 0,
+              parent: Optional[int] = None, **attrs) -> _Leaf:
+        """``with self._leaf(stage, batch_tid):`` around one leaf of the
+        loop's iteration, on the loop's thread: always counts the block's
+        seconds (``loop_s_<stage>``), and for a traced batch records the
+        span ``stage`` under the open ``dispatch`` span (or ``parent``)."""
+        span = tracing.NULL_SPAN
+        if batch_tid:
+            span = self.tracer.span(  # ocvf-lint: disable=resource-pairing -- entered and left by the _Leaf returned here, which every caller uses as `with self._leaf(...)`
+                batch_tid, stage,
+                parent=self._dispatch_span if parent is None else parent,
+                **attrs)
+        return _Leaf(self._loop_busy, stage, span)
+
+    def _flush_loop_busy(self, wall: float) -> None:
+        """One ``incr`` per leaf the iteration passed, the rest of its
+        wall time under ``loop_s_unnamed``: the counters tile the loop's
+        time, so a window's deltas say where a lost second sat."""
+        busy = self._loop_busy
+        named = 0.0
+        for stage, seconds in busy.items():
+            self.metrics.incr(mn.LOOP_S_PREFIX + stage, seconds)
+            named += seconds
+        busy.clear()
+        self.metrics.incr(mn.LOOP_S_PREFIX + "unnamed",
+                          max(0.0, wall - named))
+        self.metrics.incr(mn.LOOP_BATCHES)
+
     # ---- cascade early-exit gate (ISSUE 13) ----
 
     def _effective_cascade_threshold(self) -> float:
@@ -822,38 +883,44 @@ class RecognizerService:
         early-exit decision point; its host wall (incl. that readback)
         lands in the ``cascade_score`` window."""
         thr = self._effective_cascade_threshold()
-        t0 = time.perf_counter()
-        bucket = self._pick_bucket(count)
-        view = frames[:bucket] if bucket < len(frames) else frames
-        try:
-            scores = np.asarray(self.pipeline.cascade_scores(view))  # ocvf-lint: boundary=host-sync -- the cascade's designed decision readback: a [B]-float materialize whose entire purpose is deciding whether the expensive stage-2 dispatch happens at all (ISSUE 13)
-        except Exception:  # noqa: BLE001 — fail open: stage 2 serves the batch
-            logging.getLogger(__name__).exception(
-                "cascade stage-1 scoring failed; serving the full batch")
-            self.metrics.incr(mn.CASCADE_ERRORS)
-            return None
-        dur = time.perf_counter() - t0
-        self.metrics.observe(mn.CASCADE_SCORE, dur)
-        info = getattr(self.pipeline, "last_cascade_info", None) or {}
-        if self._warmed and info.get("cache_hit") is False:
-            self._note_recompile(bucket, count, "cascade")
-        keep = np.asarray(scores)[:count] >= thr
-        if self._faults is not None:
-            # Chaos boundary: ``cascade: reject_all`` forces the
-            # pathological all-face-free verdict (runtime.faults).
-            keep = self._faults.on_cascade(keep)
-        rejected = count - int(keep.sum())
-        self._cascade_scored += count
-        self._cascade_rejected += rejected
-        self.metrics.incr(mn.CASCADE_FRAMES_SCORED, count)
-        reject_rate = self._cascade_rejected / max(1, self._cascade_scored)
-        self.metrics.set_gauge(mn.CASCADE_REJECT_RATE, reject_rate)
-        self.metrics.set_gauge(mn.CASCADE_PASS_RATE, 1.0 - reject_rate)
-        self.metrics.set_gauge(mn.CASCADE_THRESHOLD, thr)
-        if batch_tid:
-            self.tracer.emit(batch_tid, "cascade", topic=tracing.BATCH_TOPIC,
-                             dur=dur, frames=count, rejected=rejected,
-                             threshold=round(thr, 4))
+        with (self.tracer.span(batch_tid, "cascade",
+                               parent=self._dispatch_span, frames=count,
+                               threshold=round(thr, 4))
+              if batch_tid else tracing.NULL_SPAN) as cascade:
+            t0 = time.monotonic()
+            bucket = self._pick_bucket(count)
+            view = frames[:bucket] if bucket < len(frames) else frames
+            try:
+                # Two leaves: the enqueue returns at once; the readback
+                # waits for whatever the device still has queued ahead of
+                # stage 1 (step n), then for the scores' way back.
+                with self._leaf("gate_enqueue", batch_tid, parent=cascade.id):
+                    scores = self.pipeline.cascade_scores(view)
+                with self._leaf("gate_wait", batch_tid, parent=cascade.id):
+                    scores = np.asarray(scores)  # ocvf-lint: boundary=host-sync -- the cascade's designed decision readback: a [B]-float materialize whose entire purpose is deciding whether the expensive stage-2 dispatch happens at all (ISSUE 13)
+            except Exception:  # noqa: BLE001 — fail open: stage 2 serves the batch
+                logging.getLogger(__name__).exception(
+                    "cascade stage-1 scoring failed; serving the full batch")
+                self.metrics.incr(mn.CASCADE_ERRORS)
+                return None
+            self.metrics.observe(mn.CASCADE_SCORE, time.monotonic() - t0)
+            info = getattr(self.pipeline, "last_cascade_info", None) or {}
+            if self._warmed and info.get("cache_hit") is False:
+                self._note_recompile(bucket, count, "cascade")
+            keep = scores[:count] >= thr
+            if self._faults is not None:
+                # Chaos boundary: ``cascade: reject_all`` forces the
+                # pathological all-face-free verdict (runtime.faults).
+                keep = self._faults.on_cascade(keep)
+            rejected = count - int(keep.sum())
+            self._cascade_scored += count
+            self._cascade_rejected += rejected
+            self.metrics.incr(mn.CASCADE_FRAMES_SCORED, count)
+            reject_rate = self._cascade_rejected / max(1, self._cascade_scored)
+            self.metrics.set_gauge(mn.CASCADE_REJECT_RATE, reject_rate)
+            self.metrics.set_gauge(mn.CASCADE_PASS_RATE, 1.0 - reject_rate)
+            self.metrics.set_gauge(mn.CASCADE_THRESHOLD, thr)
+            cascade.attrs["rejected"] = rejected
         return keep
 
     def _complete_empty(self, rejected, batch_tid: int) -> None:
@@ -1087,6 +1154,7 @@ class RecognizerService:
                     else self._faults.on_receive(message))
         tracer = self.tracer
         for msg in messages:
+            t_in = time.monotonic()
             priority = parse_priority(msg.get("priority"))
             # Trace starts at receive: the span covers wire-decode (when
             # the connector stamped ``_recv_ts``) through the admission
@@ -1096,7 +1164,7 @@ class RecognizerService:
                 # ``_recv_ts`` is an optional producer/transport stamp
                 # (monotonic) for wire transports that record parse time;
                 # absent it, the receive span starts at handler entry.
-                t_recv = msg.get("_recv_ts") or time.monotonic()
+                t_recv = msg.get("_recv_ts") or t_in
             # Idempotent intake (ISSUE 16): a fid this replica already
             # ADMITTED is refused before admission — like rejections,
             # dedup sits OUTSIDE the ledger, so a duplicated transport
@@ -1137,32 +1205,43 @@ class RecognizerService:
                 tracer.emit(tid, "receive", topic=topic, t0=t_recv,
                             dur=time.monotonic() - t_recv,
                             verdict="admitted", priority=priority)
-            if JPEG_KEY in msg and (self.ingest is not None
-                                    and self.ingest.decoder is not None):
-                # Compressed intake: hand the ADMITTED payload to the
-                # decode pool — the connector thread never decodes. A
-                # full decode queue is an explicit ledger drop (the
-                # bounded-backlog mirror of the batcher's overflow).
-                if not self.ingest.submit_decode(msg, priority, tid):
-                    self.metrics.incr(mn.FRAMES_DROPPED_DECODE)
-                    self._trace_settle([tid], mn.FRAMES_DROPPED_DECODE,
-                                       "ingest.decode_backlog")
-                    self._journal_drop("decode_backlog", self._drop_entries(
-                        [msg.get("meta")], None, [tid],
-                        "ingest.decode_backlog", priority=priority))
-                continue
-            # A JPEG payload with no decode pool falls through: the pixel
-            # decode below fails and the frame counts malformed — the
-            # operator forgot --ingest-mode jpeg, loudly.
-            try:
-                frame = decode_frame(msg) if "__frame__" in msg else np.asarray(
-                    msg["frame"]
-                )
-            except Exception:
-                self.metrics.incr(mn.FRAMES_MALFORMED)
-                self._trace_settle([tid], mn.FRAMES_MALFORMED, "decode")
-                continue
-            self._intake_frame(frame, msg.get("meta"), priority, tid)
+            # ``intake``: from the verdict to the return of ``put`` —
+            # the decode and the copy into staging. ``intake_s`` counts
+            # the handler from its entry, beside ``frames_admitted``.
+            with (tracer.span(tid, "intake", topic=topic) if tid
+                  else tracing.NULL_SPAN):
+                self._intake_admitted(msg, priority, tid)
+            self.metrics.incr(mn.INTAKE_S, time.monotonic() - t_in)
+
+    def _intake_admitted(self, msg, priority: int, tid: int) -> None:
+        """Decode one admitted frame and hand it to the batcher (or to
+        the decode pool, on the compressed path)."""
+        if JPEG_KEY in msg and (self.ingest is not None
+                                and self.ingest.decoder is not None):
+            # Compressed intake: hand the ADMITTED payload to the
+            # decode pool — the connector thread never decodes. A
+            # full decode queue is an explicit ledger drop (the
+            # bounded-backlog mirror of the batcher's overflow).
+            if not self.ingest.submit_decode(msg, priority, tid):
+                self.metrics.incr(mn.FRAMES_DROPPED_DECODE)
+                self._trace_settle([tid], mn.FRAMES_DROPPED_DECODE,
+                                   "ingest.decode_backlog")
+                self._journal_drop("decode_backlog", self._drop_entries(
+                    [msg.get("meta")], None, [tid],
+                    "ingest.decode_backlog", priority=priority))
+            return
+        # A JPEG payload with no decode pool falls through: the pixel
+        # decode below fails and the frame counts malformed — the
+        # operator forgot --ingest-mode jpeg, loudly.
+        try:
+            frame = decode_frame(msg) if "__frame__" in msg else np.asarray(
+                msg["frame"]
+            )
+        except Exception:
+            self.metrics.incr(mn.FRAMES_MALFORMED)
+            self._trace_settle([tid], mn.FRAMES_MALFORMED, "decode")
+            return
+        self._intake_frame(frame, msg.get("meta"), priority, tid)
 
     def _dedup_hit(self, fid) -> bool:
         """True iff ``fid`` was already admitted within the window."""
@@ -1224,12 +1303,16 @@ class RecognizerService:
         frame as a decode drop is the right bias, and doing it HERE
         (where the ledger semantics live) keeps the pool's backstop from
         ever having to guess."""
+        t_in = time.monotonic()
         try:
             self._intake_frame(frame, message.get("meta"), priority, tid)
         except Exception:  # noqa: BLE001 — an intake bug costs this frame's result, never a decode worker; the ledger settles it below
             logging.getLogger(__name__).exception(
                 "decoded-frame intake failed; settling as decode drop")
             self._decode_failed(message, priority, tid, "decode_error")
+        # The worker's share of ``intake_s`` (the decode itself reads
+        # under ``decode_latency``).
+        self.metrics.incr(mn.INTAKE_S, time.monotonic() - t_in)
 
     def _decode_failed(self, message, priority: int, tid: int,
                        reason: str) -> None:
@@ -1339,7 +1422,8 @@ class RecognizerService:
                                             daemon=True,
                                             name="ocvf-readback-worker")
             self._worker.start()
-        self._thread = threading.Thread(target=self._loop, daemon=True)
+        self._thread = threading.Thread(target=self._loop, daemon=True,
+                                        name="ocvf-serve-loop")
         self._thread.start()
 
     def warmup(self) -> None:
@@ -1484,7 +1568,8 @@ class RecognizerService:
                                             name="ocvf-readback-worker")
             self._worker.start()
         if serve_dead:
-            self._thread = threading.Thread(target=self._loop, daemon=True)
+            self._thread = threading.Thread(target=self._loop, daemon=True,
+                                            name="ocvf-serve-loop")
             self._thread.start()
 
     def _loop(self) -> None:
@@ -1497,12 +1582,22 @@ class RecognizerService:
             self._publish_status({"status": "crashed"})
 
     def _serve_loop(self) -> None:
+        busy = self._loop_busy
+        busy.clear()  # a crashed predecessor's half iteration
+        # An iteration runs from the end of the one before to the end of
+        # the batch it pops; idle ticks in between belong to it.
+        t_iter = time.monotonic()
         while self._running:
-            batch = self.batcher.get_batch(block=True)
+            t_pop = time.monotonic()
+            with (tracing.annotation("pop_wait") if self.tracer is not None
+                  else tracing.NULL_SPAN):
+                batch = self.batcher.get_batch(block=True)
+            t_popped = time.monotonic()
+            busy["pop_wait"] = busy.get("pop_wait", 0.0) + t_popped - t_pop
             # Liveness stamp: placed AFTER the pop so a loop wedged
             # anywhere in the iteration body (dispatch, inflight wait,
             # publish) stops refreshing it and ``loop_staleness_s`` grows.
-            self._loop_progress_t = time.monotonic()
+            self._loop_progress_t = t_popped
             # Durable-state tick: a cheap WAL row-count/age threshold
             # check; when due it SPAWNS the checkpoint worker (snapshot +
             # write happen off-thread, single-flight) — dispatch never
@@ -1549,11 +1644,15 @@ class RecognizerService:
                 if not self._use_worker:
                     self._drain()
                 continue
-            self._serve_one(batch)
+            self._serve_one(batch, t_pop, t_popped)
+            t_end = time.monotonic()
+            self._flush_loop_busy(t_end - t_iter)
+            t_iter = t_end
         if not self._use_worker:
             self._drain(force=True)
 
-    def _serve_one(self, batch) -> None:
+    def _serve_one(self, batch, t_pop: Optional[float] = None,
+                   t_popped: Optional[float] = None) -> None:
         frames, metas, count = batch.frames, batch.metas, batch.count
         trace_ids = batch.trace_ids
         tracer = self.tracer
@@ -1563,12 +1662,21 @@ class RecognizerService:
         # sampled independently — it exists iff any member frame is traced.
         batch_tid = (tracer.new_trace()
                      if tracer is not None and any(trace_ids) else 0)
-        t0 = time.perf_counter()
+        t0 = now_mono = time.monotonic()
+        # ``dispatch`` runs from here to the step's enqueue, through three
+        # exits: it cannot be one ``with`` block, so its id is drawn now
+        # for the leaves under it to name as ``parent``.
+        disp_id = self._dispatch_span = (tracer.new_span_id()
+                                         if batch_tid else 0)
+        if batch_tid and t_pop is not None:
+            # Before ``dispatch``, a root of the same trace: the
+            # ``get_batch`` call that returned this batch.
+            tracer.emit(batch_tid, "pop_wait", topic=tracing.BATCH_TOPIC,
+                        t0=t_pop, dur=t_popped - t_pop)
         # Queue-wait: frame enqueue -> batch pop. The batching-delay
         # term of the end-to-end latency decomposition (continuous-batching
         # deadline + waiting for batch_size peers), measured per frame —
         # and the brownout controller's load signal (batch mean).
-        now_mono = time.monotonic()
         for ts, tid in zip(batch.enqueue_ts, trace_ids):
             self.metrics.observe(mn.QUEUE_WAIT, now_mono - ts)
             if tid:
@@ -1602,54 +1710,59 @@ class RecognizerService:
             # buffer's front exactly like the cascade's, so the rungs
             # below dispatch only what actually needs device work.
             if count and self.tracker is not None:
-                stretch = self._track_reverify_stretch()
-                track_ver = getattr(self.pipeline.gallery,
-                                    "embedder_version", None)
-                if track_ver is not None:
-                    track_ver = int(track_ver)
-                # Full registry stamp when the registry is wired: a
-                # detector/cascade cutover invalidates cached verdicts
-                # exactly like an embedder cutover (opaque equality).
-                track_ver = self._model_stamp(track_ver)
-                cached = []
-                keep_list = []
-                for i in range(count):
-                    hit = self._track_lookup(metas[i], frames[i],
-                                             track_ver, stretch)
-                    if hit is not None:
-                        cached.append((metas[i], batch.enqueue_ts[i],
-                                       trace_ids[i], batch.priorities[i],
-                                       hit))
-                    else:
-                        keep_list.append(i)
+                with self._leaf("track_cache", batch_tid,
+                                frames=count) as span:
+                    stretch = self._track_reverify_stretch()
+                    track_ver = getattr(self.pipeline.gallery,
+                                        "embedder_version", None)
+                    if track_ver is not None:
+                        track_ver = int(track_ver)
+                    # Full registry stamp when the registry is wired: a
+                    # detector/cascade cutover invalidates cached verdicts
+                    # exactly like an embedder cutover (opaque equality).
+                    track_ver = self._model_stamp(track_ver)
+                    cached = []
+                    keep_list = []
+                    for i in range(count):
+                        hit = self._track_lookup(metas[i], frames[i],
+                                                 track_ver, stretch)
+                        if hit is not None:
+                            cached.append((metas[i], batch.enqueue_ts[i],
+                                           trace_ids[i], batch.priorities[i],
+                                           hit))
+                        else:
+                            keep_list.append(i)
+                    span.attrs["hits"] = len(cached)
                 if cached:
-                    keep_idx = np.asarray(keep_list, dtype=np.intp)
-                    kept = len(keep_idx)
-                    if kept:
-                        frames[:kept] = frames[keep_idx]
-                    metas = ([metas[i] for i in keep_list]
-                             + [None] * (len(metas) - kept))
-                    batch = batch._replace(
-                        metas=metas, count=kept,
-                        enqueue_ts=[batch.enqueue_ts[i] for i in keep_list],
-                        trace_ids=[trace_ids[i] for i in keep_list],
-                        priorities=[batch.priorities[i] for i in keep_list])
-                    trace_ids = batch.trace_ids
-                    count = kept
-                    if batch_tid:
-                        tracer.emit(batch_tid, "track_cache",
-                                    topic=tracing.BATCH_TOPIC,
-                                    frames=kept + len(cached),
-                                    hits=len(cached))
-                    self._complete_cached(cached, batch_tid)
+                    with self._leaf("compact", batch_tid,
+                                    kept=len(keep_list)):
+                        keep_idx = np.asarray(keep_list, dtype=np.intp)
+                        kept = len(keep_idx)
+                        if kept:
+                            frames[:kept] = frames[keep_idx]
+                        metas = ([metas[i] for i in keep_list]
+                                 + [None] * (len(metas) - kept))
+                        batch = batch._replace(
+                            metas=metas, count=kept,
+                            enqueue_ts=[batch.enqueue_ts[i]
+                                        for i in keep_list],
+                            trace_ids=[trace_ids[i] for i in keep_list],
+                            priorities=[batch.priorities[i]
+                                        for i in keep_list])
+                        trace_ids = batch.trace_ids
+                        count = kept
+                    with self._leaf("settle_early", batch_tid,
+                                    exit="cache", frames=len(cached)):
+                        self._complete_cached(cached, batch_tid)
                     if not count:
                         # Whole batch answered from the cache: no device
                         # work at all this iteration.
                         self.metrics.incr(mn.TRACK_BATCH_EXITS)
                         if batch_tid:
                             tracer.emit(batch_tid, "dispatch",
-                                        topic=tracing.BATCH_TOPIC,
-                                        dur=time.perf_counter() - t0,
+                                        topic=tracing.BATCH_TOPIC, t0=t0,
+                                        dur=time.monotonic() - t0,
+                                        span_id=disp_id,
                                         bucket=0, frames=0,
                                         exit="track_cache",
                                         brownout=self._brownout_level)
@@ -1657,7 +1770,7 @@ class RecognizerService:
                         self._mark_completed()
                         self.batcher.recycle(frames)
                         self.batcher.report_service_time(
-                            time.perf_counter() - t0)
+                            time.monotonic() - t0)
                         return
             # Stage-1 cascade gate (ISSUE 13): score the whole batch at
             # its ladder rung, settle face-free frames as
@@ -1672,26 +1785,33 @@ class RecognizerService:
             if count and self._cascade_active:
                 keep = self._cascade_keep_mask(frames, count, batch_tid)
                 if keep is not None and not keep.all():
-                    keep_idx = np.flatnonzero(keep)
-                    rejected = [(metas[i], batch.enqueue_ts[i],
-                                 trace_ids[i], batch.priorities[i])
-                                for i in np.flatnonzero(~keep)]
-                    kept = len(keep_idx)
-                    if kept:
-                        # Fancy-index gather copies survivors out before
-                        # the front rows are overwritten: safe in-place
-                        # compaction of the pooled staging buffer.
-                        frames[:kept] = frames[keep_idx]
-                    metas = ([metas[i] for i in keep_idx]
-                             + [None] * (len(metas) - kept))
-                    batch = batch._replace(
-                        metas=metas, count=kept,
-                        enqueue_ts=[batch.enqueue_ts[i] for i in keep_idx],
-                        trace_ids=[trace_ids[i] for i in keep_idx],
-                        priorities=[batch.priorities[i] for i in keep_idx])
-                    trace_ids = batch.trace_ids
-                    count = kept
-                    self._complete_empty(rejected, batch_tid)
+                    with self._leaf("compact", batch_tid,
+                                    kept=int(keep.sum())):
+                        keep_idx = np.flatnonzero(keep)
+                        rejected = [(metas[i], batch.enqueue_ts[i],
+                                     trace_ids[i], batch.priorities[i])
+                                    for i in np.flatnonzero(~keep)]
+                        kept = len(keep_idx)
+                        if kept:
+                            # Fancy-index gather copies survivors out
+                            # before the front rows are overwritten: safe
+                            # in-place compaction of the pooled staging
+                            # buffer.
+                            frames[:kept] = frames[keep_idx]
+                        metas = ([metas[i] for i in keep_idx]
+                                 + [None] * (len(metas) - kept))
+                        batch = batch._replace(
+                            metas=metas, count=kept,
+                            enqueue_ts=[batch.enqueue_ts[i]
+                                        for i in keep_idx],
+                            trace_ids=[trace_ids[i] for i in keep_idx],
+                            priorities=[batch.priorities[i]
+                                        for i in keep_idx])
+                        trace_ids = batch.trace_ids
+                        count = kept
+                    with self._leaf("settle_early", batch_tid,
+                                    exit="gate", frames=len(rejected)):
+                        self._complete_empty(rejected, batch_tid)
                     if not count:
                         # Zero survivors: the whole batch exits at stage
                         # 1 — no stage-2 dispatch at all, THE early-exit
@@ -1700,8 +1820,9 @@ class RecognizerService:
                         self.metrics.incr(mn.CASCADE_BATCH_EXITS)
                         if batch_tid:
                             tracer.emit(batch_tid, "dispatch",
-                                        topic=tracing.BATCH_TOPIC,
-                                        dur=time.perf_counter() - t0,
+                                        topic=tracing.BATCH_TOPIC, t0=t0,
+                                        dur=time.monotonic() - t0,
+                                        span_id=disp_id,
                                         bucket=0, frames=0,
                                         exit="cascade",
                                         brownout=self._brownout_level)
@@ -1711,7 +1832,7 @@ class RecognizerService:
                         # fences the buffer's H2D read: safe to recycle.
                         self.batcher.recycle(frames)
                         self.batcher.report_service_time(
-                            time.perf_counter() - t0)
+                            time.monotonic() - t0)
                         return
             # Bucketed dispatch: slice the padded staging array down to the
             # smallest warmed ladder size that fits the real frames — a
@@ -1723,7 +1844,8 @@ class RecognizerService:
                 # and which bucket it dispatches at (rung >= bucket; the
                 # ring hands the smallest rung that fits).
                 tracer.emit(batch_tid, "stage", topic=tracing.BATCH_TOPIC,
-                            rung=len(frames), bucket=bucket, frames=count)
+                            parent=disp_id, rung=len(frames), bucket=bucket,
+                            frames=count)
             # Embedder-version stamp captured AT DISPATCH: the batch's
             # scores are computed against the gallery data this dispatch
             # reads, so its published results carry the version serving
@@ -1765,9 +1887,9 @@ class RecognizerService:
                 return
             # Host-side dispatch cost (H2D + trace-cache hit + async enqueue
             # — never device compute, which is async from here).
-            t_disp = time.perf_counter()
+            t_disp = time.monotonic()
             self.metrics.observe(mn.DISPATCH, t_disp - t0)
-            deadline = time.monotonic() + self.resilience.readback_deadline_s
+            deadline = t_disp + self.resilience.readback_deadline_s
             with self._inflight_cv:
                 self._inflight.append((packed, frames, metas, count,
                                        batch.enqueue_ts, t0, t_disp, deadline,
@@ -1802,10 +1924,13 @@ class RecognizerService:
             # and the cascade exit stage (``full`` = stage 2 ran; a batch
             # that never got here carries ``exit="cascade"`` instead).
             tracer.emit(batch_tid, "dispatch", topic=tracing.BATCH_TOPIC,
-                        dur=t_disp - t0, bucket=bucket, frames=count,
+                        t0=t0, dur=t_disp - t0, span_id=disp_id,
+                        bucket=bucket, frames=count,
                         cache_hit=info.get("cache_hit"),
                         mode=info.get("mode"), exit="full",
                         brownout=self._brownout_level)
+        # What follows lies after ``dispatch``: a root of the batch trace.
+        self._dispatch_span = 0
         if self._warmed and info.get("cache_hit") is False:
             # Recompile watchdog (see _note_recompile): a serving
             # dispatch missed the jit cache AFTER warmup compiled the
@@ -1821,10 +1946,13 @@ class RecognizerService:
             # pipeline. Deliberately NOT escaped on a worker crash: parking
             # here keeps the in-flight queue bounded until the supervisor
             # respawns the worker (or stop() clears _running).
-            with self._inflight_cv:
-                while (self._running
-                       and len(self._inflight) > self.inflight_depth):
-                    self._inflight_cv.wait(timeout=self._drain_poll_s)
+            # The leaf closes (and its span is emitted) once the
+            # condition's lock is released.
+            with self._leaf("inflight_wait", batch_tid):
+                with self._inflight_cv:
+                    while (self._running
+                           and len(self._inflight) > self.inflight_depth):
+                        self._inflight_cv.wait(timeout=self._drain_poll_s)
         else:
             self._drain()
 
@@ -1850,16 +1978,17 @@ class RecognizerService:
             try:
                 send = frames
                 if self.ingest is not None:
-                    send, up_bytes, up_dur = self.ingest.upload(frames)
-                    if batch_tid:
-                        self.tracer.emit(batch_tid, "upload",
-                                         topic=tracing.BATCH_TOPIC,
-                                         dur=up_dur, bytes=up_bytes,
-                                         dtype=str(frames.dtype))
+                    with self._leaf("upload", batch_tid,
+                                    dtype=str(frames.dtype)) as span:
+                        send, up_bytes, _up_dur = self.ingest.upload(frames)
+                        span.attrs["bytes"] = up_bytes
                 # Packed path: ONE output array -> one D2H readback per
-                # batch instead of five (see pipeline.pack_result).
-                packed = self.pipeline.recognize_batch_packed(send)
-                packed.copy_to_host_async()
+                # batch instead of five (see pipeline.pack_result). Without
+                # an ingest subsystem the implicit host->device transfer
+                # of ``send`` is part of this leaf.
+                with self._leaf("step_enqueue", batch_tid):
+                    packed = self.pipeline.recognize_batch_packed(send)
+                    packed.copy_to_host_async()
             except Exception as exc:  # noqa: BLE001 — classified below
                 self.metrics.incr(mn.DISPATCH_FAILURES)
                 self._consecutive_dispatch_failures += 1
@@ -2164,7 +2293,7 @@ class RecognizerService:
           dead-letters the batch (``readback_errors``) instead of crashing
           the thread — the readback-side mirror of the dispatch retry
           classification;
-        - ``ready_wait`` is stamped AFTER ``np.asarray``: on the blocking
+        - ``ready_wait`` ends AFTER ``np.asarray``: on the blocking
           (over-depth/forced) fallback path the conversion IS the readback
           (device compute + D2H land in this term), and it must never
           leak into 'publish';
@@ -2182,19 +2311,19 @@ class RecognizerService:
             self.batcher.forfeit(frames)
             self._dead_letter(count, metas, enqueue_ts, trace_ids, batch_tid)
             return
-        ready_dur = time.perf_counter() - t_disp
-        self.metrics.observe(mn.READY_WAIT, ready_dur)
+        t_pub = time.monotonic()
+        self.metrics.observe(mn.READY_WAIT, t_pub - t_disp)
         if batch_tid:
-            # Dispatch -> readback-complete: the device round-trip term
-            # (perf_counter durations are epoch-free, so the span rides a
-            # fresh monotonic stamp minus the measured duration).
+            # Dispatch -> readback-complete: the device round-trip term,
+            # from the instant ``dispatch`` ended.
             self.tracer.emit(batch_tid, "ready_wait",
-                             topic=tracing.BATCH_TOPIC, dur=ready_dur,
-                             frames=count)
-        t_pub = time.perf_counter()
+                             topic=tracing.BATCH_TOPIC, t0=t_disp,
+                             dur=t_pub - t_disp, frames=count)
         try:
-            self._publish(arr, frames, metas, count, trace_ids, batch_tid,
-                          gallery_ver)
+            with (self.tracer.span(batch_tid, "publish", frames=count)
+                  if batch_tid else tracing.NULL_SPAN) as span:
+                self._publish(arr, frames, metas, count, trace_ids,
+                              batch_tid, gallery_ver, publish_span=span.id)
         except BaseException:
             self._mark_completed()
             # The readback COMPLETED before publish, so the staging
@@ -2205,10 +2334,7 @@ class RecognizerService:
             self.batcher.recycle(frames)
             raise
         self._mark_completed()
-        now = time.perf_counter()
-        if batch_tid:
-            self.tracer.emit(batch_tid, "publish", topic=tracing.BATCH_TOPIC,
-                             dur=now - t_pub, frames=count)
+        now = time.monotonic()
         self.metrics.observe(mn.PUBLISH, now - t_pub)
         self.metrics.observe(mn.BATCH_LATENCY, now - t0)
         # Per-frame end-to-end latency (batcher enqueue -> published):
@@ -2216,13 +2342,12 @@ class RecognizerService:
         # the interactive objective never averages in bulk traffic.
         # enqueue_ts stamps are monotonic; one clock read covers the run.
         if enqueue_ts:
-            now_mono = time.monotonic()
             for i in range(min(count, len(enqueue_ts))):
                 self._observe_e2e(
                     enqueue_ts[i],
                     priorities[i] if i < len(priorities)
                     else PRIORITY_INTERACTIVE + 1,
-                    now_mono)
+                    now)
         # Feed the continuous batcher's adaptive deadline with the
         # realized downstream time (pop -> published).
         self.batcher.report_service_time(now - t0)
@@ -2234,10 +2359,15 @@ class RecognizerService:
             self._inflight_cv.notify_all()
 
     def _publish(self, packed, frames, metas, count, trace_ids=(),
-                 batch_tid=0, gallery_ver=None) -> None:
+                 batch_tid=0, gallery_ver=None, publish_span=0) -> None:
         from opencv_facerecognizer_tpu.parallel.pipeline import unpack_result
 
+        t_pub = time.monotonic()
         published = 0
+        # ``tracker.update``: seconds and calls of this batch, counted
+        # once below; a span (child of ``publish_span``) per sampled frame.
+        track_s, track_n = 0.0, 0
+        tracer = self.tracer
         rollout = self.rollout
         registry_swap = self.registry_swap
         # ``gallery_ver`` is the DISPATCH-time model stamp: a plain int
@@ -2298,14 +2428,22 @@ class RecognizerService:
                     # bug costs future cache wins, never this result.
                     key = self._track_stream_key(metas[i])
                     if key is not None:
+                        tid = (trace_ids[i] if batch_tid
+                               and i < len(trace_ids) else 0)
+                        t_track = time.monotonic()
                         try:
-                            self.tracker.update(
-                                key, faces, frames[i],
-                                embedder_version=stamp)
+                            with (tracer.span(batch_tid, "track_update",
+                                              parent=publish_span, frame=tid)
+                                  if tid else tracing.NULL_SPAN):
+                                self.tracker.update(
+                                    key, faces, frames[i],
+                                    embedder_version=stamp)
                         except Exception:  # noqa: BLE001 — cache only
                             logging.getLogger(__name__).exception(
                                 "tracker update failed")
                             self.metrics.incr(mn.TRACK_ERRORS)
+                        track_s += time.monotonic() - t_track
+                        track_n += 1
                 if rollout is not None and faces:
                     # Dual-score parity sampling (rate-limited + copied
                     # inside; scored on the rollout thread). A coordinator
@@ -2337,6 +2475,9 @@ class RecognizerService:
             # frames must not stay in limbo between those events). The
             # terminal spans mirror the same split exactly.
             self.metrics.incr(mn.FRAMES_COMPLETED, published)
+            if track_n:
+                self.metrics.incr(mn.PUBLISH_S_TRACK_UPDATE, track_s)
+                self.metrics.incr(mn.TRACK_UPDATES, track_n)
             self._trace_settle(trace_ids[:published],
                                tracing.OUTCOME_COMPLETED, "publish",
                                batch=batch_tid)
@@ -2345,6 +2486,9 @@ class RecognizerService:
                 self._trace_settle(trace_ids[published:count],
                                    mn.FRAMES_DROPPED_CRASHED,
                                    "publish.crashed", batch=batch_tid)
+            # Busy time of publishing, beside its count (frames_completed
+            # above): the whole of this method, settle spans included.
+            self.metrics.incr(mn.PUBLISH_S, time.monotonic() - t_pub)
 
     # ---- enrolment (interactive-trainer protocol) ----
 

@@ -43,6 +43,29 @@ CPU_FALLBACKS = "cpu_fallbacks"
 DEGRADED_TRANSITIONS = "degraded_transitions"
 DEGRADED_RECOVERIES = "degraded_recoveries"
 
+# ---- serving loop: busy time, counted where the leaf spans are cut ---------
+# Seconds summed (counters, not windows): always on, one ``incr`` per
+# counter per batch, so a window's deltas tile the time of each thread.
+#: the serving loop's wall time by leaf, ``loop_s_<stage>`` for each stage
+#: of LOOP_LEAVES (the ``Tracer`` span of the same name covers the same
+#: block), and ``loop_s_unnamed``: the iteration's wall time under no leaf.
+LOOP_S_PREFIX = "loop_s_"
+LOOP_LEAVES = ("pop_wait", "track_cache", "gate_enqueue", "gate_wait",
+               "compact", "settle_early", "upload", "step_enqueue",
+               "inflight_wait")
+#: iterations of the serving loop that popped a batch (the denominator of
+#: every ``loop_s_*`` per-batch quotient).
+LOOP_BATCHES = "loop_batches"
+#: the readback worker's ``_publish`` as a whole, beside its count
+#: ``frames_completed``; of it, the ``tracker.update`` calls and their count.
+PUBLISH_S = "publish_s"
+PUBLISH_S_TRACK_UPDATE = "publish_s_track_update"
+TRACK_UPDATES = "track_updates"
+#: the connector thread's handler from entry to the return of
+#: ``batcher.put`` (on the JPEG path plus the decode worker's hand-over),
+#: admitted frames only: beside ``frames_admitted``.
+INTAKE_S = "intake_s"
+
 # ---- serving loop: latency windows (observe) ------------------------------
 WARMUP = "warmup"
 QUEUE_WAIT = "queue_wait"
@@ -307,7 +330,6 @@ EXPO_ERRORS = "expo_errors"
 #: derived stage-attribution gauge family:
 #: ``stage_share_b<bucket>_<detect|crop|embed|match>``
 STAGE_SHARE_PREFIX = "stage_share_"
-DEVICE_BUSY_FRACTION = "device_busy_fraction"
 
 # ---- signals layer: SLO / health / watchdogs (runtime.slo) -----------------
 #: health state machine gauge: 0 = ok, 1 = warn, 2 = critical.

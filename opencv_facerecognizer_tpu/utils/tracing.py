@@ -6,8 +6,18 @@ Before this module, a frame that vanished left behind aggregate counters
 happened to frame 48123" or "what was in flight when the soak wedged".
 This layer records one causal **span** per stage a frame passes through:
 
-    receive (verdict) -> queue_wait (batch ancestry) -> [batch trace:
-    dispatch / ready_wait / publish] -> settle (terminal outcome)
+    receive (verdict) -> intake -> queue_wait (batch ancestry) -> [batch
+    trace: pop_wait, dispatch{leaves}, inflight_wait, ready_wait,
+    publish{track_update}] -> settle (terminal outcome)
+
+Every span carries ``parent``: the ``span`` id of the span that caused it
+(0 for a root), so a reader can rebuild the tree and take a span's self
+time as its duration minus its children's. Spans opened through
+``Tracer.span`` are also ``jax.profiler.TraceAnnotation`` objects named
+``ocvf:<stage>``: inside a profiling session they are events of the same
+``.xplane.pb`` as the device's operations, on the thread that did the
+work and on the profiler's own clock; outside one, opening them is a
+flag test.
 
 plus **lifecycle spans** for the slow machinery (checkpoints, WAL appends,
 IVF retrains, brownout transitions, recovery). Spans are plain dicts held
@@ -44,8 +54,9 @@ in **per-topic bounded ring buffers** — a flight recorder, not an archive:
   offline analysis beyond the ring's horizon. Off by default: it adds a
   file write per span, which is what the sampling knob is for.
 
-Overhead: one dict + one deque append per span, ~3 spans per frame at
-``sample=1.0``. The bench gate (``bench_serving.py --smoke`` section
+Overhead: one dict + one deque append per span (plus, for ``span()``, a
+small handle and the annotation object), ~4 spans per frame and ~15 per
+batch at ``sample=1.0``. The bench gate (``bench_serving.py --smoke`` section
 ``tracing_overhead``) holds the fully-enabled e2e p50 regression under 3%.
 """
 
@@ -87,6 +98,81 @@ OUTCOME_COMPLETED_CACHED = "completed_cached"
 
 _HASH_MULT = 2654435761  # Knuth multiplicative hash (mod 2^32)
 
+#: prefix of the profiler annotations ``Tracer.span`` opens
+ANNOTATION_PREFIX = "ocvf:"
+#: ``jax.profiler.TraceAnnotation`` once first needed, False where JAX
+#: cannot be imported (then nothing is opened); tests patch it.
+_annotation_factory: Any = None
+
+
+def annotation(stage: str):
+    """A profiler annotation ``ocvf:<stage>`` (a context manager), or the
+    shared ``NULL_SPAN`` without JAX. Resolved on first use: ``utils``
+    imports no JAX."""
+    global _annotation_factory
+    factory = _annotation_factory
+    if factory is None:
+        try:
+            from jax.profiler import TraceAnnotation as factory
+        except ImportError:
+            factory = False
+        _annotation_factory = factory
+    return factory(ANNOTATION_PREFIX + stage) if factory else NULL_SPAN
+
+
+class _NullSpan:
+    """What ``Tracer.span`` gives for trace id 0, and what a site whose
+    tracer is None enters in its place: shared, records nothing."""
+
+    __slots__ = ()
+    id = 0
+
+    @property
+    def attrs(self) -> Dict[str, Any]:
+        return {}  # a fresh dict each time: writes to it vanish
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """An open span (``Tracer.span``): ``id`` exists from entry, so
+    children can name it as their ``parent`` before it is emitted;
+    ``attrs`` may be enriched until exit."""
+
+    __slots__ = ("id", "attrs", "_tracer", "_trace", "_stage", "_topic",
+                 "_parent", "_t0", "_note")
+
+    def __init__(self, tracer: "Tracer", trace_id: int, stage: str,
+                 topic: Optional[str], parent: int, attrs: Dict[str, Any]):
+        self.attrs = attrs
+        self._tracer = tracer
+        self._trace = trace_id
+        self._stage = stage
+        self._topic = topic
+        self._parent = parent
+
+    def __enter__(self) -> "_Span":
+        self.id = self._tracer.new_span_id()
+        self._note = annotation(self._stage)
+        self._t0 = time.monotonic()
+        self._note.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.monotonic() - self._t0
+        self._note.__exit__(*exc)
+        self._tracer.emit(self._trace, self._stage, topic=self._topic,
+                          t0=self._t0, dur=dur, parent=self._parent,
+                          span_id=self.id, **self.attrs)
+        return False
+
 
 class Tracer:
     """Per-topic span ring buffers with deterministic sampling and an
@@ -127,7 +213,9 @@ class Tracer:
         #   not shift it between replayed runs;
         # - batch/lifecycle trace ids (EVEN): disjoint from frame ids so
         #   the two families can never collide in one span stream;
-        # - span ids: a global emission-order sequence for sorting only.
+        # - span ids: one global sequence, drawn when a span is emitted
+        #   or, for ``span()`` and pre-drawn ids, when it opens (a parent's
+        #   id is below its children's); ``parent`` refers to them.
         self._frame_ids = itertools.count(0)
         self._aux_ids = itertools.count(1)
         self._span_ids = itertools.count(1)
@@ -174,18 +262,27 @@ class Tracer:
                     topic, deque(maxlen=self.ring_size))
         return ring
 
+    def new_span_id(self) -> int:
+        """A span id drawn ahead of emission, for ``emit(span_id=)``: the
+        sites that cannot be a ``with self.span(...)`` block but whose
+        children must name them as ``parent``."""
+        return next(self._span_ids)
+
     def emit(self, trace_id: int, stage: str, topic: Optional[str] = None,
              t0: Optional[float] = None, dur: float = 0.0,
-             **attrs: Any) -> None:
+             parent: int = 0, span_id: int = 0, **attrs: Any) -> None:
         """Record one finished span. ``t0`` is ``time.monotonic()`` at
-        span start (defaults to now - dur); ``dur`` seconds. No-op for
-        trace id 0 (sampled out). Lock-free: one dict + one thread-safe
-        deque append."""
+        span start (defaults to now - dur); ``dur`` seconds on the same
+        clock. ``parent`` is the ``span`` id of the span that caused this
+        one (0: a root); ``span_id`` an id drawn by ``new_span_id`` when
+        the span opened. No-op for trace id 0 (sampled out). Lock-free:
+        one dict + one thread-safe deque append."""
         if not trace_id:
             return
         span: Dict[str, Any] = {
             "trace": trace_id,
-            "span": next(self._span_ids),
+            "span": span_id or next(self._span_ids),
+            "parent": parent,
             "stage": stage,
             "t0": (time.monotonic() - dur) if t0 is None else t0,
             "dur": dur,
@@ -198,12 +295,26 @@ class Tracer:
             sink.append_line(json.dumps({"topic": topic or BATCH_TOPIC,
                                          **span}, default=repr))
 
+    def span(self, trace_id: int, stage: str, topic: Optional[str] = None,
+             parent: int = 0, **attrs: Any):
+        """Context manager around one stage's work: on entry draws the
+        span id, stamps ``t0`` and opens the profiler annotation
+        ``ocvf:<stage>``; yields a handle (``.id`` for the children's
+        ``parent=``, ``.attrs`` to enrich); on exit closes the annotation
+        and emits with the measured duration, also when the body raised.
+        Trace id 0 (sampled out): the shared ``NULL_SPAN``, nothing else.
+        Like ``emit``, never leave the block while a serving-path lock is
+        held: with a ``span_sink`` the emission writes a file."""
+        if not trace_id:
+            return NULL_SPAN
+        return _Span(self, trace_id, stage, topic, parent, attrs)
+
     @contextlib.contextmanager
     def lifecycle(self, stage: str, **attrs: Any):
-        """Span a lifecycle operation: yields a mutable attr dict the
-        body may enrich; the span is emitted on exit with the measured
-        duration, ``ok`` False plus the error repr when the body raised
-        (re-raised).
+        """Span a lifecycle operation (``span`` on a trace of its own,
+        lifecycle topic): yields a mutable attr dict the body may enrich;
+        the span is emitted on exit with the measured duration, ``ok``
+        False plus the error repr when the body raised (re-raised).
 
         Use this when the spanned body holds NO locks at exit. The
         runtime's own lifecycle sites (WAL append, checkpoint, IVF
@@ -213,18 +324,16 @@ class Tracer:
         file I/O, and I/O under ``_enroll_lock``/``_ckpt_lock``/
         ``_train_lock`` is exactly what the blocking-under-lock
         discipline forbids."""
-        tid = self.new_trace()
-        t0 = time.monotonic()
-        try:
-            yield attrs
-        except BaseException as exc:
-            attrs.setdefault("ok", False)
-            attrs.setdefault("error", repr(exc))
-            raise
-        finally:
-            attrs.setdefault("ok", True)
-            self.emit(tid, stage, topic=LIFECYCLE_TOPIC, t0=t0,
-                      dur=time.monotonic() - t0, **attrs)
+        with self.span(self.new_trace(), stage, topic=LIFECYCLE_TOPIC,
+                       **attrs) as span:
+            try:
+                yield span.attrs
+            except BaseException as exc:
+                span.attrs.setdefault("ok", False)
+                span.attrs.setdefault("error", repr(exc))
+                raise
+            finally:
+                span.attrs.setdefault("ok", True)
 
     # ---- reading ----
 
@@ -391,34 +500,3 @@ def account_spans(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     return {"traced": len(admitted_traces), "completed": completed,
             "completed_empty": completed_empty,
             "completed_cached": completed_cached, "drops": drops}
-
-
-def device_busy_fraction(batch_spans: Iterable[Dict[str, Any]],
-                         window_s: float = 30.0,
-                         now: Optional[float] = None) -> float:
-    """Fraction of the trailing ``window_s`` the device spent on batch
-    round-trips, from ``ready_wait`` spans: the union of their
-    ``[t0, t0+dur]`` intervals over the window — the same interval-union
-    technique ``scripts/trace_summary.py`` applies to device trace lines,
-    fed from live spans instead of an offline xplane capture. Overlapping
-    in-flight batches are not double-counted."""
-    now = time.monotonic() if now is None else now
-    lo = now - window_s
-    ivals = sorted(
-        (max(s["t0"], lo), min(s["t0"] + s["dur"], now))
-        for s in batch_spans
-        if s.get("stage") == "ready_wait" and s["t0"] + s["dur"] > lo)
-    busy = 0.0
-    cur_s = cur_e = None
-    for s, e in ivals:
-        if e <= s:
-            continue
-        if cur_e is None or s > cur_e:
-            if cur_e is not None:
-                busy += cur_e - cur_s
-            cur_s, cur_e = s, e
-        else:
-            cur_e = max(cur_e, e)
-    if cur_e is not None:
-        busy += cur_e - cur_s
-    return busy / window_s if window_s > 0 else 0.0

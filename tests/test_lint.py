@@ -1209,21 +1209,26 @@ def test_resource_pairing_seq_burn_abort_path_clean(tmp_path):
     assert findings == []
 
 
-def test_resource_pairing_lifecycle_needs_with(tmp_path):
+@pytest.mark.parametrize("method", ["lifecycle", "span"])
+def test_resource_pairing_tracer_contexts_need_with(tmp_path, method):
     findings = lint_tree(tmp_path, {
-        "mod.py": """\
+        "mod.py": f"""\
             class Worker:
                 def bad(self):
-                    span = self._tracer.lifecycle("swap")
+                    span = self._tracer.{method}("swap")
                     return span
 
                 def good(self):
-                    with self._tracer.lifecycle("swap"):
+                    with self._tracer.{method}("swap"):
+                        return 1
+
+                def good_when_sampled(self, tid):
+                    with (self._tracer.{method}("swap") if tid else NULL):
                         return 1
             """,
     }, rules=["resource-pairing"])
     assert rules_and_lines(findings) == [("resource-pairing", 3)]
-    assert "contextmanager" in findings[0].message
+    assert f"Tracer.{method} is a contextmanager" in findings[0].message
 
 
 # ---------------- fence-ordering (v3) ----------------

@@ -10,6 +10,8 @@ hardware.
 
 import json
 import os
+import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -73,17 +75,19 @@ def test_span_lifecycle_and_ordering_through_pipeline():
                         if s["stage"] == "dispatch"}
     for spans in by_trace.values():
         stages = [s["stage"] for s in spans]
-        # Causal order: receive -> queue_wait -> settle, in emission order
-        # (span ids are globally monotonic).
-        assert stages == ["receive", "queue_wait", "settle"]
-        assert spans[0]["span"] < spans[1]["span"] < spans[2]["span"]
+        # Causal order: receive -> intake -> queue_wait -> settle, in
+        # span-id order (ids are drawn when a span opens or is emitted).
+        assert stages == ["receive", "intake", "queue_wait", "settle"]
+        assert [s["span"] for s in spans] == sorted(s["span"] for s in spans)
         assert spans[0]["verdict"] == "admitted"
-        assert spans[2]["outcome"] == tracing.OUTCOME_COMPLETED
+        assert spans[3]["outcome"] == tracing.OUTCOME_COMPLETED
+        # intake starts where receive ended: at the admission verdict.
+        assert spans[1]["t0"] >= spans[0]["t0"] + spans[0]["dur"]
         # Coalescing ancestry: the queue_wait span names the batch trace
         # that carried the frame, and that batch has a dispatch span with
         # the bucket it served at.
-        batch = spans[1]["batch"]
-        assert batch and batch == spans[2]["batch"]
+        batch = spans[2]["batch"]
+        assert batch and batch == spans[3]["batch"]
         assert dispatch_by_batch[batch]["bucket"] >= 1
     # Batch spans: every dispatched batch has its round-trip recorded.
     stages = {s["stage"] for s in batch_spans}
@@ -295,9 +299,9 @@ def test_expo_endpoint_read_only_contract():
         assert brownout["level"] == 0
         status, spans = _get(base + f"/spans?topic={FRAME_TOPIC}&n=1000")
         assert {s["stage"] for s in spans["spans"]} \
-            == {"receive", "queue_wait", "settle"}
+            == {"receive", "intake", "queue_wait", "settle"}
         status, attribution = _get(base + "/attribution")
-        assert status == 200 and "device_busy_fraction" in attribution
+        assert status == 200 and "device_busy_fraction" not in attribution
 
         # Unknown path -> 404; every mutating verb -> 405 (read-only).
         with pytest.raises(urllib.error.HTTPError) as err:
@@ -318,19 +322,6 @@ def test_expo_endpoint_read_only_contract():
 # ---- stage attribution ----
 
 
-def test_device_busy_fraction_interval_union():
-    now = 100.0
-    spans = [
-        {"stage": "ready_wait", "t0": 90.0, "dur": 2.0},
-        {"stage": "ready_wait", "t0": 91.0, "dur": 2.0},  # overlaps above
-        {"stage": "ready_wait", "t0": 95.0, "dur": 1.0},
-        {"stage": "dispatch", "t0": 96.0, "dur": 50.0},  # wrong stage
-        {"stage": "ready_wait", "t0": 10.0, "dur": 1.0},  # out of window
-    ]
-    busy = tracing.device_busy_fraction(spans, window_s=10.0, now=now)
-    assert busy == pytest.approx((3.0 + 1.0) / 10.0)
-
-
 def test_fold_attribution_sets_registered_gauges():
     tracer = Tracer(sample=1.0)
     batch_tid = tracer.new_trace()
@@ -341,8 +332,7 @@ def test_fold_attribution_sets_registered_gauges():
     gauges = fold_attribution(tracer, metrics,
                               bench_path=os.path.join(REPO_ROOT,
                                                       "BENCH_DETAIL.json"))
-    assert "device_busy_fraction" in gauges
-    assert metrics.gauge("device_busy_fraction") >= 0.0
+    assert "device_busy_fraction" not in gauges
     # Stage shares come from the committed bench stage table for the
     # observed bucket, sum to ~1, and ride registered gauge names.
     shares = {k: v for k, v in gauges.items()
@@ -351,6 +341,322 @@ def test_fold_attribution_sets_registered_gauges():
         assert sum(shares.values()) == pytest.approx(1.0)
         assert metrics.gauge("stage_share_b8_detect") == shares[
             "stage_share_b8_detect"]
+
+
+# ---- leaf spans, parents, profiler annotations, busy-time counters ----
+
+#: children of ``dispatch`` in the order the loop passes them (README
+#: "Observability", the table of leaves); ``cascade`` holds the two gate
+#: leaves, ``stage`` is the instant ingest-provenance span.
+DISPATCH_CHILD_RANK = {"track_cache": 0, "cascade": 1, "compact": 2,
+                       "settle_early": 2, "stage": 3, "upload": 4,
+                       "step_enqueue": 5}
+VIDEO_HW = (32, 32)
+
+
+class _Notes:
+    """Stand-in for ``jax.profiler.TraceAnnotation``: records every
+    object constructed and the thread that entered and left it."""
+
+    def __init__(self):
+        self.made = []
+
+    def __call__(self, name):
+        note = _Note(name)
+        self.made.append(note)
+        return note
+
+
+class _Note:
+    def __init__(self, name):
+        self.name = name
+        self.entered = self.left = None
+
+    def __enter__(self):
+        self.entered = threading.get_ident()
+        return self
+
+    def __exit__(self, *exc):
+        self.left = threading.get_ident()
+        return False
+
+
+def _run_video(tracer, monkeypatch=None, n=96):
+    """A threaded service with every leaf's machinery on (gate stub,
+    track cache, ingest upload), fed two coherent camera streams with
+    some face-free frames; returns what a test may look at afterwards."""
+    from opencv_facerecognizer_tpu.runtime.fakes import synthetic_video_stream
+    from opencv_facerecognizer_tpu.runtime.ingest import IngestConfig
+    from opencv_facerecognizer_tpu.runtime.tracker import (
+        IdentityTracker,
+        TrackerConfig,
+    )
+
+    metrics = Metrics()
+    pipeline = InstantPipeline(VIDEO_HW, cascade_stub=True, video_oracle=True,
+                               compute_s=0.002)
+    connector = FakeConnector()
+    service = RecognizerService(
+        pipeline, connector, batch_size=4, frame_shape=VIDEO_HW,
+        flush_timeout=0.01, inflight_depth=1, similarity_threshold=0.0,
+        metrics=metrics, bucket_sizes=(1, 2, 4), cascade=True,
+        subject_names=["id0", "id1", "id2", "id3"], tracer=tracer,
+        tracker=IdentityTracker(TrackerConfig(reverify_frames=3),
+                                metrics=metrics),
+        ingest=IngestConfig(mode="uint8"))
+    flushes = []
+    flush = service._flush_loop_busy
+
+    def recorded_flush(wall):
+        flush(wall)
+        flushes.append((time.monotonic(), wall))
+
+    service._flush_loop_busy = recorded_flush
+    counters_seen = []
+    t_before = time.monotonic()
+    service.start(warmup=False)
+    t_started = time.monotonic()
+    try:
+        rows = synthetic_video_stream(n, VIDEO_HW, streams=2, coherence=1.0,
+                                      face_density=0.7, seed=3)
+        for i, (frame, key, _k) in enumerate(rows):
+            connector.inject(FRAME_TOPIC, {"frame": frame,
+                                           "meta": {"seq": i, "stream": key}})
+            if i % 8 == 7:
+                assert service.drain(timeout=20.0)
+                counters_seen.append(metrics.counters())
+        assert service.drain(timeout=20.0)
+    finally:
+        threads = {"loop": service._thread.ident,
+                   "readback": service._worker.ident,
+                   "connector": threading.get_ident()}
+        service.stop()
+    return {"service": service, "metrics": metrics, "flushes": flushes,
+            "counters_seen": counters_seen, "threads": threads,
+            "t_before": t_before, "t_started": t_started}
+
+
+@pytest.fixture(scope="module")
+def video_run():
+    notes = _Notes()
+    saved = tracing._annotation_factory
+    tracing._annotation_factory = notes
+    try:
+        tracer = Tracer(ring_size=1 << 16, sample=1.0)
+        run = _run_video(tracer)
+    finally:
+        tracing._annotation_factory = saved
+    run["tracer"], run["notes"] = tracer, notes
+    by_trace = {}
+    for span in tracer.snapshot(topic=tracing.BATCH_TOPIC):
+        by_trace.setdefault(span["trace"], []).append(span)
+    run["by_trace"] = by_trace
+    return run
+
+
+def _inside(child, parent, slack=1e-9):
+    return (child["t0"] >= parent["t0"] - slack
+            and child["t0"] + child["dur"]
+            <= parent["t0"] + parent["dur"] + slack)
+
+
+def _assert_disjoint_in_order(spans):
+    spans = sorted(spans, key=lambda s: (s["t0"], s["span"]))
+    for a, b in zip(spans, spans[1:]):
+        assert a["t0"] + a["dur"] <= b["t0"] + 1e-9, (a, b)
+    return spans
+
+
+def test_dispatch_children_name_it_lie_inside_and_tile_in_order(video_run):
+    by_trace = video_run["by_trace"]
+    assert len(by_trace) >= 10
+    stages_seen, exits = set(), set()
+    for spans in by_trace.values():
+        dispatch = [s for s in spans if s["stage"] == "dispatch"]
+        assert len(dispatch) == 1
+        dispatch = dispatch[0]
+        assert dispatch["parent"] == 0
+        exits.add(dispatch["exit"])
+        ids = {s["span"] for s in spans}
+        # every parent resolves to a span of the same trace
+        assert all(s["parent"] in ids for s in spans if s["parent"])
+        children = [s for s in spans if s["parent"] == dispatch["span"]]
+        assert children and all(_inside(c, dispatch) for c in children)
+        assert all(c["span"] > dispatch["span"] for c in children)
+        ordered = _assert_disjoint_in_order(children)
+        ranks = [DISPATCH_CHILD_RANK[c["stage"]] for c in ordered]
+        # table B's order; the cache's compact + settle_early (rank 2)
+        # come before the gate, hence the one allowed step down
+        assert ranks == sorted(ranks) or [
+            r for r in ranks if r != 2] == sorted(r for r in ranks if r != 2)
+        stages_seen.update(c["stage"] for c in children)
+        cascade = [c for c in children if c["stage"] == "cascade"]
+        for gate in cascade:
+            leaves = _assert_disjoint_in_order(
+                [s for s in spans if s["parent"] == gate["span"]])
+            assert [s["stage"] for s in leaves] == ["gate_enqueue", "gate_wait"]
+            assert all(_inside(s, gate) for s in leaves)
+        if dispatch["exit"] == "full":
+            assert {"upload", "step_enqueue"} <= {c["stage"] for c in children}
+    assert exits >= {"full"} and len(exits) >= 2
+    assert stages_seen == set(DISPATCH_CHILD_RANK)
+
+
+def test_pop_wait_and_inflight_wait_are_roots_around_dispatch(video_run):
+    seen_inflight = 0
+    for spans in video_run["by_trace"].values():
+        by_stage = {}
+        for s in spans:
+            by_stage.setdefault(s["stage"], []).append(s)
+        dispatch = by_stage["dispatch"][0]
+        (pop,) = by_stage["pop_wait"]
+        assert pop["parent"] == 0
+        assert pop["t0"] + pop["dur"] <= dispatch["t0"] + 1e-9
+        for wait in by_stage.get("inflight_wait", ()):
+            seen_inflight += 1
+            assert wait["parent"] == 0
+            assert wait["t0"] >= dispatch["t0"] + dispatch["dur"] - 1e-9
+        # real starts on one clock: ready_wait begins where dispatch ended
+        for ready in by_stage.get("ready_wait", ()):
+            assert ready["t0"] == pytest.approx(
+                dispatch["t0"] + dispatch["dur"], abs=1e-9)
+    assert seen_inflight > 0
+
+
+def test_publish_children_are_track_updates_of_sampled_frames(video_run):
+    frame_traces = {s["trace"] for s in
+                    video_run["tracer"].snapshot(topic=FRAME_TOPIC)}
+    updates = 0
+    for spans in video_run["by_trace"].values():
+        for publish in (s for s in spans if s["stage"] == "publish"):
+            children = [s for s in spans if s["parent"] == publish["span"]]
+            assert all(c["stage"] == "track_update" for c in children)
+            assert all(_inside(c, publish) for c in children)
+            _assert_disjoint_in_order(children)
+            assert all(c["frame"] in frame_traces for c in children)
+            assert len(children) == publish["frames"]  # sample=1.0, all named
+            updates += len(children)
+    assert updates == video_run["metrics"].counter("track_updates") > 0
+
+
+def test_annotations_are_ocvf_stage_on_the_emitting_thread(video_run):
+    notes, threads = video_run["notes"].made, video_run["threads"]
+    assert notes and all(n.name.startswith("ocvf:") for n in notes)
+    assert all(n.entered is not None and n.entered == n.left for n in notes)
+    by_name = {}
+    for note in notes:
+        by_name.setdefault(note.name[len("ocvf:"):], set()).add(note.entered)
+    on_loop = {"pop_wait", "track_cache", "cascade", "gate_enqueue",
+               "gate_wait", "compact", "settle_early", "upload",
+               "step_enqueue", "inflight_wait"}
+    assert set(by_name) == on_loop | {"publish", "track_update", "intake"}
+    for stage in on_loop:
+        assert by_name[stage] == {threads["loop"]}, stage
+    assert by_name["publish"] == by_name["track_update"] == {threads["readback"]}
+    assert by_name["intake"] == {threads["connector"]}
+    # one annotation per span that ``span()`` opened, none for ``emit``
+    opened = sum(1 for topic in video_run["tracer"].topics()
+                 for s in video_run["tracer"].snapshot(topic)
+                 if s["stage"] in by_name and s["stage"] != "pop_wait")
+    assert opened == sum(1 for n in notes if n.name != "ocvf:pop_wait")
+
+
+def test_busy_counters_registered_monotone_and_tile_the_loops_wall_time(
+        video_run):
+    from opencv_facerecognizer_tpu.utils import metric_names as mn
+
+    assert mn.LOOP_S_PREFIX in mn.all_prefixes()
+    assert {mn.LOOP_BATCHES, mn.PUBLISH_S, mn.PUBLISH_S_TRACK_UPDATE,
+            mn.TRACK_UPDATES, mn.INTAKE_S} <= set(mn.all_names())
+    metrics = video_run["metrics"]
+    final = metrics.counters()
+    loop = {k: v for k, v in final.items() if k.startswith(mn.LOOP_S_PREFIX)}
+    assert set(loop) == ({mn.LOOP_S_PREFIX + leaf for leaf in mn.LOOP_LEAVES}
+                         | {mn.LOOP_S_PREFIX + "unnamed"})
+    busy = [k for k in final if k.startswith(mn.LOOP_S_PREFIX)] + [
+        mn.LOOP_BATCHES, mn.PUBLISH_S, mn.PUBLISH_S_TRACK_UPDATE,
+        mn.TRACK_UPDATES, mn.INTAKE_S]
+    seen = video_run["counters_seen"] + [final]
+    for before, after in zip(seen, seen[1:]):
+        assert all(after.get(k, 0.0) >= before.get(k, 0.0) for k in busy)
+    assert all(final[k] > 0 for k in busy if k != mn.LOOP_S_PREFIX + "unnamed")
+    # leaves + unnamed == the wall time the loop handed to each flush ...
+    flushes = video_run["flushes"]
+    assert final[mn.LOOP_BATCHES] == len(flushes)
+    walls = sum(wall for _at, wall in flushes)
+    assert sum(loop.values()) == pytest.approx(walls, abs=1e-6)
+    # ... and the flushes are contiguous from the loop's start to the last
+    last = flushes[-1][0]
+    assert (last - video_run["t_started"] - 1e-3 <= walls
+            <= last - video_run["t_before"] + 1e-3)
+    # the parts lie inside the wholes
+    assert final[mn.PUBLISH_S_TRACK_UPDATE] <= final[mn.PUBLISH_S]
+    assert final[mn.INTAKE_S] > 0 and final["frames_admitted"] == 96
+
+
+def test_no_annotation_is_constructed_without_a_tracer(monkeypatch):
+    notes = _Notes()
+    monkeypatch.setattr(tracing, "_annotation_factory", notes)
+    run = _run_video(None, n=24)
+    assert notes.made == []
+    # the busy-time counters run all the same
+    assert run["metrics"].counter("loop_batches") == len(run["flushes"]) > 0
+    assert run["metrics"].counter("intake_s") > 0
+
+
+def test_span_with_trace_id_zero_emits_and_annotates_nothing(monkeypatch):
+    notes = _Notes()
+    monkeypatch.setattr(tracing, "_annotation_factory", notes)
+    tracer = Tracer(sample=1.0)
+    with tracer.span(0, "compact", parent=7, frames=3) as span:
+        assert span is tracing.NULL_SPAN and span.id == 0
+        span.attrs["kept"] = 1  # vanishes
+    assert tracing.NULL_SPAN.attrs == {}
+    assert tracer.snapshot() == [] and notes.made == []
+    # sampled in: one annotation, one span, id drawn at entry
+    with tracer.span(tracer.new_trace(), "compact", parent=7, frames=3) as span:
+        assert span.id > 0 and notes.made[0].entered and not notes.made[0].left
+        span.attrs["kept"] = 1
+    (emitted,) = tracer.snapshot()
+    assert [n.name for n in notes.made] == ["ocvf:compact"]
+    assert (emitted["span"], emitted["parent"], emitted["stage"]) \
+        == (span.id, 7, "compact")
+    assert emitted["frames"] == 3 and emitted["kept"] == 1
+    assert emitted["dur"] >= 0 and emitted["t0"] <= time.monotonic()
+
+
+def test_emit_takes_parent_and_a_predrawn_span_id():
+    tracer = Tracer(sample=1.0)
+    tid = tracer.new_trace()
+    parent_id = tracer.new_span_id()
+    tracer.emit(tid, "compact", t0=1.0, dur=0.5, parent=parent_id)
+    tracer.emit(tid, "dispatch", t0=0.5, dur=2.0, span_id=parent_id)
+    child, parent = tracer.snapshot(topic=tracing.BATCH_TOPIC)
+    assert parent["span"] == parent_id < child["span"]
+    assert child["parent"] == parent_id and parent["parent"] == 0
+    # a body that raises still closes and emits its span
+    with pytest.raises(RuntimeError):
+        with tracer.span(tid, "upload", parent=parent_id):
+            raise RuntimeError("boom")
+    assert tracer.snapshot(topic=tracing.BATCH_TOPIC)[-1]["stage"] == "upload"
+
+
+def test_annotation_without_jax_opens_nothing(monkeypatch):
+    monkeypatch.setattr(tracing, "_annotation_factory", False)
+    assert tracing.annotation("compact") is tracing.NULL_SPAN
+    tracer = Tracer(sample=1.0)
+    with tracer.span(tracer.new_trace(), "compact"):
+        pass
+    assert [s["stage"] for s in tracer.snapshot()] == ["compact"]
+
+
+def test_annotation_factory_resolves_to_jax_profiler(monkeypatch):
+    import jax.profiler
+
+    monkeypatch.setattr(tracing, "_annotation_factory", None)
+    note = tracing.annotation("compact")
+    assert isinstance(note, jax.profiler.TraceAnnotation)
+    assert tracing._annotation_factory is jax.profiler.TraceAnnotation
 
 
 # ---- Metrics empty/short-window fixes (satellite) ----

@@ -20,7 +20,7 @@ not a checker edit — see README "declaring a new paired resource"):
   ``append_abort`` on failure).  A burned-but-unreleased sequence leaves a
   hole in the WAL that recovery must special-case forever.  Watermark
   seeding (``self._wal_seq = max(...)``) is not a burn and is ignored.
-- ``context``: ``Tracer.lifecycle`` is a contextmanager; calling it
+- ``context``: ``Tracer.lifecycle`` and ``Tracer.span`` are contextmanagers; calling one
   anywhere but a ``with`` item produces a span that never closes.  This is
   a plain AST check, no path enumeration needed.
 
@@ -114,14 +114,20 @@ class ResourcePairingChecker(Checker):
         for node in ast.walk(ctx.tree):
             if isinstance(node, (ast.With, ast.AsyncWith)):
                 for item in node.items:
-                    with_items.add(id(item.context_expr))
+                    expr = item.context_expr
+                    with_items.add(id(expr))
+                    if isinstance(expr, ast.IfExp):
+                        # ``with (tracer.span(...) if tid else NULL_SPAN):``
+                        # enters whichever branch it picks.
+                        with_items.update((id(expr.body), id(expr.orelse)))
         findings: List[Finding] = []
         for node in ast.walk(ctx.tree):
             if not isinstance(node, ast.Call) or id(node) in with_items:
                 continue
             for pairing in pairings:
                 if self._matches_method(node, pairing["context_methods"]):
-                    cls, method = pairing["context_methods"][0]
+                    cls, method = pairing["context_methods"][0][0], \
+                        node.func.attr
                     findings.append(ctx.finding(
                         self.rule, node,
                         f"{cls}.{method} is a contextmanager — call it as "

@@ -268,9 +268,9 @@ RESOURCE_PAIRINGS: Tuple[Dict[str, Any], ...] = (
     {
         "kind": "context",
         "name": "tracer-span",
-        "context_methods": (("Tracer", "lifecycle"),),
+        "context_methods": (("Tracer", "lifecycle"), ("Tracer", "span")),
         "module_suffixes": (),  # everywhere
-        "what": "lifecycle span contextmanager",
+        "what": "span contextmanager",
     },
 )
 
