@@ -1,14 +1,15 @@
 """Streaming pallas top-k matcher vs the lax.top_k oracle.
 
 Runs in interpret mode on the CPU suite (SURVEY.md §4 prescription: every
-kernel gets an oracle test); the compiled-TPU path is exercised by bench.py
-and the gallery fast path on the real chip.
+kernel gets an oracle test); the last test compiles the kernel for the chip
+without one, and ``chip_smoke.py`` runs it compiled on the chip.
 """
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from opencv_facerecognizer_tpu.ops import pallas_match
 from opencv_facerecognizer_tpu.ops.pallas_match import streaming_match_topk
 
 RNG = np.random.default_rng(3)
@@ -107,3 +108,224 @@ def test_streaming_topk_duplicate_scores_unique_indices():
     idx = np.asarray(idx)
     for row in idx:
         assert len(set(row.tolist())) == 4, row
+
+
+# ---- the layout of PR 27: whole-batch query block, gallery tiles of several
+# products ("chunks") each, running bests per (row mod 8, query) slot ----
+
+ROWS = pallas_match._SIDE  # gallery rows per MXU product at k = 1
+TILE = 2 * ROWS  # block_n of these tests: two products a tile
+
+
+def _bf16(x):
+    return np.asarray(jnp.asarray(x, jnp.float32).astype(jnp.bfloat16)
+                      .astype(jnp.float32), np.float64)
+
+
+def _stable_oracle(q, g, valid, k):
+    """Exact top-k of the bf16-rounded operands, ties to the lowest row."""
+    with np.errstate(invalid="ignore"):  # an invalid row may hold inf or NaN
+        sims = _bf16(q) @ _bf16(g).T
+    sims = np.where(np.asarray(valid, bool)[None, :], sims, -1e30)
+    idx = np.argsort(-sims, axis=1, kind="stable")[:, :k]
+    vals = np.take_along_axis(sims, idx, axis=1)
+    return vals, np.where(vals > -1e29, idx, -1), sims
+
+
+def _unit(rng, shape):
+    x = rng.normal(size=shape).astype(np.float32)
+    return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+
+def _check(q, g, valid, k, *, exact_idx, **blocks):
+    vals, idx = (np.asarray(v) for v in streaming_match_topk(
+        jnp.asarray(q), jnp.asarray(g), jnp.asarray(valid), k=k,
+        interpret=True, **blocks))
+    ovals, oidx, sims = _stable_oracle(q, g, valid, k)
+    assert vals.shape == ovals.shape and idx.dtype == np.int32
+    empty = ovals < -1e29
+    assert np.all(idx[empty] == -1) and np.all(vals[empty] == np.float32(-1e30))
+    np.testing.assert_allclose(vals[~empty], ovals[~empty], atol=1e-5)
+    assert np.all(np.asarray(valid, bool)[idx[~empty]]), "an invalid row surfaced"
+    if exact_idx:
+        assert (idx == oidx).mean() == 1.0, (idx, oidx)
+    else:  # random rows: a near-tie may swap, the served row must score the same
+        served = np.take_along_axis(sims, np.maximum(idx, 0), axis=1)
+        np.testing.assert_allclose(served[~empty], ovals[~empty], atol=1e-5)
+        for row in idx:
+            real = row[row >= 0]
+            assert len(set(real.tolist())) == len(real), row
+    return vals, idx
+
+
+def test_plan_derives_blocks_from_shapes():
+    plan = pallas_match._plan
+    # serving: the whole ladder batch resident, 4,096-row tiles, no padding
+    assert plan(1024, 8388608, 256, 1, 2, None, None) == (1024, 4096, ROWS, 1024)
+    assert plan(64, 65536, 256, 1, 2, None, None) == (128, 4096, ROWS, 128)
+    # a larger batch comes back as blocks of 1,024; bounds under one lane
+    # row are rounded up; a small gallery is one tile
+    assert plan(4096, 65536, 256, 1, 2, None, None)[0] == 1024
+    assert plan(8, 128, 32, 4, 4, 8, 32) == (128, 128, 128, 128)
+    assert plan(13, 300, 48, 3, 4, 8, 128) == (128, 128, 128, 128)
+    assert plan(13, 300, 48, 1, 4, None, None) == (128, 384, 384, 128)
+    # the unrolled fold of one product stays the same size as k grows
+    assert [plan(1024, 65536, 256, k, 2, None, None)[2:] for k in (1, 2, 4, 8, 64)] == [
+        (ROWS, 1024), (ROWS // 2, 512), (ROWS // 4, 256), (ROWS // 8, 128), (ROWS // 8, 128)]
+    assert plan(300, 65536, 256, 4, 2, None, None)[::3] == (512, 256)  # whole products
+    # an IVF bucket: tiles cover N rounded up to 128 with little over
+    bq, bn, rows, _ = plan(1024, 196709, 256, 5, 2, None, None)
+    assert bn % rows == 0 and -(-196709 // bn) * bn - 196709 < bn
+    # wide rows or many levels shrink the blocks to the VMEM budget
+    for d, k, row_bytes in ((256, 1, 2), (256, 64, 2), (2048, 8, 4), (8192, 1, 4)):
+        bq, bn, rows, lanes = plan(1024, 1 << 20, d, k, row_bytes, None, None)
+        cand = -(-8 * k // 128) * 128
+        held = (2 * d * bq * 2 + 2 * cand * bq * 4 + rows * lanes * 4
+                + 2 * bn * (d * row_bytes + 4))
+        assert bq % lanes == 0 and lanes % 128 == 0 and bn % rows == 0 and rows % 128 == 0
+        assert held <= pallas_match._VMEM_BUDGET or (bq, bn) == (128, 128), (d, k, held)
+
+
+@pytest.mark.parametrize("k", [1, 4, 8])
+@pytest.mark.parametrize("qn,n", [(8, 3 * TILE), (37, 2 * TILE + 777),
+                                  (130, TILE + 1)])
+def test_new_layout_matches_stable_oracle(qn, n, k):
+    """One resident query block over several gallery tiles of two products
+    each; Q not a multiple of 8 (37) nor of 128 (130: two lane blocks), N
+    not a multiple of 128; scattered invalid rows."""
+    rng = np.random.default_rng(1000 * k + n + qn)
+    q, g = _unit(rng, (qn, 32)), _unit(rng, (n, 32))
+    valid = rng.random(n) > 0.1
+    _check(q, g, valid, k, exact_idx=False, block_n=TILE)
+
+
+@pytest.mark.parametrize("k", [1, 4, 8])
+@pytest.mark.parametrize("apart", [4, 8, 128, ROWS, TILE])
+def test_ties_across_slots_groups_products_and_tiles(apart, k):
+    """Every row appears 2k times, copies ``apart`` rows from each other:
+    4 = another slot of the same 8-row group, 8 = the same slot of the next
+    group, 128, one product and one tile apart. Index equality 1.0 against
+    a stable argsort: the k lowest copies of the best row, in order."""
+    rng = np.random.default_rng(apart + k)
+    copies, n = 2 * k, 2 * TILE + 2 * 8 * TILE // ROWS
+    base = _unit(rng, (apart, 16))
+    g = _unit(rng, (max(n, copies * apart), 16)) * 0.5  # filler scores lower
+    g[:copies * apart] = np.tile(base, (copies, 1))
+    q = base[rng.permutation(apart)[:min(apart, 24)]]
+    valid = np.ones(len(g), bool)
+    _, idx = _check(q, g, valid, k, exact_idx=True, block_n=TILE)
+    assert np.all(np.diff(idx, axis=1) == apart)
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("hole", ["first_row", "last_row", "slot", "group",
+                                  "product", "tile", "best_rows", "padding"])
+def test_invalid_rows_in_every_position_class(hole, k):
+    n = 3 * TILE - (100 if hole == "padding" else 0)
+    rng = np.random.default_rng(len(hole) + k)
+    q, g = _unit(rng, (16, 16)), _unit(rng, (n, 16))
+    valid = np.ones(n, bool)
+    if hole == "first_row":
+        valid[[0, TILE, 2 * TILE]] = False
+    elif hole == "last_row":
+        valid[[TILE - 1, 2 * TILE - 1, n - 1]] = False
+    elif hole == "slot":  # one row mod 8 everywhere
+        valid[3::8] = False
+    elif hole == "group":
+        valid[TILE + 64:TILE + 72] = False
+    elif hole == "product":
+        valid[ROWS:2 * ROWS] = False
+    elif hole == "tile":
+        valid[TILE:2 * TILE] = False
+    elif hole == "best_rows":  # what every query would have been served
+        valid[np.argsort(-(q @ g.T), axis=1)[:, :k + 1].ravel()] = False
+    # an invalid row may hold anything: it must not surface, nor poison a slot
+    g[~valid] = np.where(rng.random((int((~valid).sum()), 1)) < 0.5, np.inf, np.nan)
+    _check(q, g, valid, k, exact_idx=False, block_n=TILE)
+
+
+@pytest.mark.parametrize("k,n_valid", [(1, 0), (4, 0), (4, 3), (8, 7), (8, 1)])
+def test_fewer_valid_rows_than_k_leaves_sentinels(k, n_valid):
+    rng = np.random.default_rng(k + n_valid)
+    n = 2 * TILE
+    q, g = _unit(rng, (9, 16)), _unit(rng, (n, 16))
+    valid = np.zeros(n, bool)
+    valid[rng.permutation(n)[:n_valid]] = True
+    vals, idx = _check(q, g, valid, k, exact_idx=True, block_n=TILE)
+    assert np.all((idx >= 0).sum(axis=1) == n_valid)
+    assert np.all(idx[:, n_valid:] == -1) and np.all(vals[:, n_valid:] < -1e29)
+
+
+def test_query_blocks_over_tiles_and_stored_bf16_rows():
+    """Q over the block bound: an outer query axis re-reads the gallery per
+    block and restarts the running bests; rows stored as bf16 stay bf16."""
+    rng = np.random.default_rng(11)
+    q, g = _unit(rng, (300, 32)), _unit(rng, (TILE + 300, 32))
+    valid = rng.random(len(g)) > 0.05
+    a = _check(q, g, valid, 4, exact_idx=False, block_q=128, block_n=ROWS)
+    b = streaming_match_topk(jnp.asarray(q), jnp.asarray(g, jnp.bfloat16),
+                             jnp.asarray(valid), k=4, interpret=True)
+    for x, y in zip(a, b):  # one block and default tiles: the same answer
+        np.testing.assert_array_equal(x, np.asarray(y))
+
+
+@pytest.mark.parametrize("k", [1, 4, 8])
+def test_agrees_with_match_global(k):
+    """Same inputs through the XLA matcher: equal indices on a tie-heavy
+    gallery with invalid rows, equal similarities to float rounding."""
+    import jax
+    from jax.sharding import Mesh
+
+    from opencv_facerecognizer_tpu.parallel.gallery import match_global
+    from opencv_facerecognizer_tpu.parallel.mesh import DP_AXIS, TP_AXIS
+
+    rng = np.random.default_rng(7 + k)
+    base = _unit(rng, (40, 32))
+    g = np.tile(base, (2 * TILE // 40 + 1, 1))[:2 * TILE]
+    q = base[:24]
+    valid = rng.random(len(g)) > 0.3
+    labels = np.arange(len(g), dtype=np.int32) % 40
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), (DP_AXIS, TP_AXIS))
+    args = (jnp.asarray(q), jnp.asarray(g), jnp.asarray(valid))
+    _, ref_v, ref_i = match_global(*args, jnp.asarray(labels), k=k, mesh=mesh)
+    vals, idx = streaming_match_topk(*args, k=k, block_n=TILE, interpret=True)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_i))
+    np.testing.assert_allclose(np.asarray(vals), np.asarray(ref_v), atol=1e-5)
+
+
+# ---- the chip's compiler, without the chip (on-chip-measurement guide §2) ----
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("qn,n,k", [(1024, 8388608, 1), (256, 8388608, 1),
+                                    (64, 8388608, 1), (1024, 196709, 5)])
+def test_compiles_for_v5e_under_the_name_the_benchmark_reads(one_chip, qn, n, k):
+    """Mosaic takes the kernel at the serving widths (every ladder rung over
+    the benchmark's gallery, and an IVF bucket with k > 1) inside the default
+    VMEM limit, as ONE custom call whose name holds ``streaming_match_topk``
+    and whose first output is f32[Q, k] — what the profiler's event, and
+    with it the benchmark's roofline reader, is found by."""
+    import re
+
+    import jax
+
+    shapes = (jax.ShapeDtypeStruct((qn, 256), jnp.float32, sharding=one_chip),
+              jax.ShapeDtypeStruct((n, 256), jnp.bfloat16, sharding=one_chip),
+              jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip))
+    text = jax.jit(lambda q, g, v: streaming_match_topk(q, g, v, k=k)).lower(
+        *shapes).compile().as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    found = re.search(r"%(\S*streaming_match_topk\S*) = \(f32\[(\d+),(\d+)\]",
+                      calls[0])
+    assert found, calls[0]
+    assert int(found.group(2)) == -(-qn // 128) * 128 and int(found.group(3)) == k
