@@ -483,6 +483,7 @@ def _load_stack(args, mesh=None):
 
     from opencv_facerecognizer_tpu.models.detector import CNNFaceDetector
     from opencv_facerecognizer_tpu.models.embedder import CNNEmbedding
+    from opencv_facerecognizer_tpu.models.iresnet import IResNetEmbedding
     from opencv_facerecognizer_tpu.parallel import ShardedGallery, make_mesh
     from opencv_facerecognizer_tpu.parallel.pipeline import RecognitionPipeline
     from opencv_facerecognizer_tpu.utils import dataset as dataset_utils
@@ -501,11 +502,15 @@ def _load_stack(args, mesh=None):
         raise SystemExit("--cascade applies to --parallel fused only (the "
                          "pipeline-parallel path carries no stage-1 gate)")
 
-    serialization.register(CNNEmbedding)
     model = serialization.load_model(args.model)
     feature = model.feature
-    if not isinstance(feature, CNNEmbedding):
-        raise SystemExit("--model must be a cnn checkpoint (ocvf-train --model cnn)")
+    if not isinstance(feature, (CNNEmbedding, IResNetEmbedding)):
+        raise SystemExit("--model must be a cnn checkpoint (ocvf-train "
+                         "--model cnn) or an IResNetEmbedding one")
+    if args.fused_embedder and not isinstance(feature, CNNEmbedding):
+        raise SystemExit("--fused-embedder covers the separable "
+                         "FaceEmbedNet only; this checkpoint holds "
+                         f"{type(feature.net).__name__}")
     detector = CNNFaceDetector.load(args.detector)
     face_gate = None
     if args.cascade:

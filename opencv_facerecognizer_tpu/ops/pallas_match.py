@@ -43,6 +43,17 @@ matrix anywhere:
   lowest row among equals, ``-1`` from the value) run once per call, not
   once per tile.
 
+* **Width.** Nothing is special to one D: ``_plan`` sizes the resident
+  queries (2 * D * Q bytes, double-buffered) and the gallery tile from D
+  against the VMEM budget. Doubling D doubles a row's bytes and its
+  multiply-adds, so N rows at D = 512 cost what 2N rows cost at 256, in
+  HBM and in time; the tile halves (2,048 rows at Q = 1,024, D = 512, bf16;
+  4,096 at D = 256) and the product stays 1,024 rows; wider rows yet
+  shrink the product (512 rows at D = 1,024) and then the query block
+  (512 queries at D = 2,048). On the chip: 96.1 % of the MXU's floor
+  at Q = 1,024 over 4,194,304 x 512 rows, 95.6 % over 8,388,608 x 256
+  (PERF.md).
+
 Used by ``ShardedGallery`` as the single-shard fast path and by
 ``ops.ivf_match`` to rerank its bucket; the XLA formulation stays both the
 multi-chip GSPMD path (XLA cannot partition a custom call across tp

@@ -16,7 +16,7 @@ inserts the collectives; nothing here names a wire protocol.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Protocol, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +28,14 @@ from opencv_facerecognizer_tpu.models import embedder as embedder_mod
 from opencv_facerecognizer_tpu.ops import image as image_ops
 from opencv_facerecognizer_tpu.parallel.gallery import ShardedGallery
 from opencv_facerecognizer_tpu.parallel.mesh import DP_AXIS, TP_AXIS
+
+
+class EmbedNet(Protocol):
+    """What the step needs of an embedder: a flax-style ``apply`` taking
+    ``{"params": ...}`` and [N, h, w] standardized crops, giving [N, E]
+    unit rows (``FaceEmbedNet``, ``IResNet``)."""
+
+    def apply(self, variables: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray: ...
 
 
 class RecognitionResult(NamedTuple):
@@ -74,7 +82,7 @@ class RecognitionPipeline:
     def __init__(
         self,
         detector: detector_mod.CNNFaceDetector,
-        embed_net: embedder_mod.FaceEmbedNet,
+        embed_net: EmbedNet,
         embed_params: Dict[str, Any],
         gallery: ShardedGallery,
         face_size: Tuple[int, int] = (112, 112),
@@ -116,6 +124,11 @@ class RecognitionPipeline:
             raise ValueError(
                 "fused_embedder=True requires a single-device mesh "
                 f"(got {gallery.mesh.size} devices)")
+        if fused_embedder and not isinstance(embed_net,
+                                             embedder_mod.FaceEmbedNet):
+            raise ValueError(
+                "fused_embedder=True covers FaceEmbedNet only (got "
+                f"{type(embed_net).__name__})")
         self.fused_embedder = bool(fused_embedder)
         # Chaos hook (runtime.faults.FaultInjector): checked at the device-
         # dispatch boundary of both recognize paths, so an injected
@@ -173,29 +186,33 @@ class RecognitionPipeline:
             # to f32 happens here, on device.
             frames = frames.astype(jnp.float32)
             # 1) detect (dense convs; dp-sharded batch)
-            outputs = det.net.apply({"params": det_params}, frames)
-            boxes, det_scores, valid = detector_mod.decode_detections(
-                outputs, max_faces, det.score_threshold, det.iou_threshold
-            )
-            # 2) align: dynamic crop+resize, all slots (invalid ones too)
-            crops = image_ops.batched_crop_resize(frames, boxes, face_size)
-            flat = crops.reshape((batch * max_faces, *face_size))
+            with jax.named_scope("ocvf_detect"):
+                outputs = det.net.apply({"params": det_params}, frames)
+                boxes, det_scores, valid = detector_mod.decode_detections(
+                    outputs, max_faces, det.score_threshold, det.iou_threshold
+                )
+            # 2) align: dynamic crop+resize and per-crop standardization,
+            # all slots (invalid ones too)
+            with jax.named_scope("ocvf_crop"):
+                crops = image_ops.batched_crop_resize(frames, boxes, face_size)
+                flat = embedder_mod.normalize_faces(
+                    crops.reshape((batch * max_faces, *face_size)), face_size)
             # 3) embed (flax graph, or the fused pallas schedule when
             # self.fused_embedder — same params either way)
-            emb = embed_apply(
-                emb_params, embedder_mod.normalize_faces(flat, face_size)
-            )  # [B*K, E] unit-norm
+            with jax.named_scope("ocvf_embed"):
+                emb = embed_apply(emb_params, flat)  # [B*K, E] unit-norm
             # 4) match against the gallery (selection in gallery.match_fn:
             # two-stage ivf for a ready quantizer above its threshold,
             # GSPMD global view when sharded, pallas streaming single-chip)
-            if use_ivf:
-                labels, sims, _ = match(
-                    emb, gallery_emb, gallery_valid, gallery_labels, ivf
-                )
-            else:
-                labels, sims, _ = match(
-                    emb, gallery_emb, gallery_valid, gallery_labels
-                )
+            with jax.named_scope("ocvf_match"):
+                if use_ivf:
+                    labels, sims, _ = match(
+                        emb, gallery_emb, gallery_valid, gallery_labels, ivf
+                    )
+                else:
+                    labels, sims, _ = match(
+                        emb, gallery_emb, gallery_valid, gallery_labels
+                    )
             return RecognitionResult(
                 boxes=boxes,
                 det_scores=det_scores,
@@ -279,8 +296,12 @@ class RecognitionPipeline:
         # Host-side dispatch provenance for the frame-lifecycle tracer's
         # batch spans (runtime.recognizer reads it right after the call):
         # plain attr store, best-effort — informational, never synchronized.
-        self.last_dispatch_info = {"cache_hit": packed is not None,
-                                   "mode": "ivf" if ivf is not None else "exact"}
+        # ``embed_slots``: face slots this step sends through the embedder
+        # (every frame of the rung carries max_faces, valid or not).
+        self.last_dispatch_info = {
+            "cache_hit": packed is not None,
+            "mode": "ivf" if ivf is not None else "exact",
+            "embed_slots": int(frames.shape[0]) * int(self.detector.max_faces)}
         if packed is None:
             self._evict_stale_ivf(key)
             step = self._step_cache.get(key)

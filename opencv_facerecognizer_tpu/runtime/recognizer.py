@@ -1917,6 +1917,8 @@ class RecognizerService:
         # Dispatch provenance is read for the batch span AND the recompile
         # watchdog, so it is fetched regardless of tracing.
         info = getattr(self.pipeline, "last_dispatch_info", None) or {}
+        if info.get("embed_slots"):
+            self.metrics.incr(mn.EMBED_SLOTS, info["embed_slots"])
         if batch_tid:
             # Bucketed-dispatch provenance: bucket size, jit-cache verdict
             # and exact-vs-ivf matcher mode (the pipeline records both on

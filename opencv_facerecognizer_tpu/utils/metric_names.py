@@ -35,6 +35,10 @@ BATCHES_DISPATCHED = "batches_dispatched"
 BATCHES_BUCKETED = "batches_bucketed"
 BATCHES_FAILED = "batches_failed"
 BATCHES_DEAD_LETTERED = "batches_dead_lettered"
+#: face slots sent through the embedder: rung frames x max_faces of every
+#: dispatched step (the pipeline reports it with the dispatch), so that a
+#: share of peak counts the work that ran, not what a rung implies.
+EMBED_SLOTS = "embed_slots"
 LOOP_CRASHES = "loop_crashes"
 DISPATCH_FAILURES = "dispatch_failures"
 DISPATCH_RETRIES = "dispatch_retries"

@@ -158,13 +158,15 @@ def _registry() -> Dict[str, type]:
             m.ExtendedPredictableModel,
         ):
             _REGISTRY[cls.name] = cls
-        # CNNEmbedding lives in its own module (heavier deps); it is part
-        # of the default registry all the same — a checkpoint saved through
+        # The CNN embedders live in their own modules (heavier deps); they
+        # are part of the default registry all the same — a checkpoint saved through
         # the plain save_model API must load without first touching the
         # trainer or the serving app (round-3 drive finding).
         from opencv_facerecognizer_tpu.models import embedder as e
+        from opencv_facerecognizer_tpu.models import iresnet as r
 
         _REGISTRY[e.CNNEmbedding.name] = e.CNNEmbedding
+        _REGISTRY[r.IResNetEmbedding.name] = r.IResNetEmbedding
     return _REGISTRY
 
 

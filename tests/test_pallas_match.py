@@ -306,11 +306,15 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("qn,n,k", [(1024, 8388608, 1), (256, 8388608, 1),
-                                    (64, 8388608, 1), (1024, 196709, 5)])
-def test_compiles_for_v5e_under_the_name_the_benchmark_reads(one_chip, qn, n, k):
+@pytest.mark.parametrize("qn,n,k,d", [
+    (1024, 8388608, 1, 256), (256, 8388608, 1, 256), (64, 8388608, 1, 256),
+    (1024, 196709, 5, 256),
+    # the 512-d rows of ``watchlist4m-r50``: same algorithm, 2,048-row tiles
+    (1024, 4194304, 1, 512), (256, 4194304, 1, 512), (64, 4194304, 1, 512),
+    (1024, 98355, 5, 512)])
+def test_compiles_for_v5e_under_the_name_the_benchmark_reads(one_chip, qn, n, k, d):
     """Mosaic takes the kernel at the serving widths (every ladder rung over
-    the benchmark's gallery, and an IVF bucket with k > 1) inside the default
+    each benchmark gallery, and an IVF bucket with k > 1) inside the default
     VMEM limit, as ONE custom call whose name holds ``streaming_match_topk``
     and whose first output is f32[Q, k] — what the profiler's event, and
     with it the benchmark's roofline reader, is found by."""
@@ -318,8 +322,8 @@ def test_compiles_for_v5e_under_the_name_the_benchmark_reads(one_chip, qn, n, k)
 
     import jax
 
-    shapes = (jax.ShapeDtypeStruct((qn, 256), jnp.float32, sharding=one_chip),
-              jax.ShapeDtypeStruct((n, 256), jnp.bfloat16, sharding=one_chip),
+    shapes = (jax.ShapeDtypeStruct((qn, d), jnp.float32, sharding=one_chip),
+              jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=one_chip),
               jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip))
     text = jax.jit(lambda q, g, v: streaming_match_topk(q, g, v, k=k)).lower(
         *shapes).compile().as_text()
@@ -329,3 +333,25 @@ def test_compiles_for_v5e_under_the_name_the_benchmark_reads(one_chip, qn, n, k)
                       calls[0])
     assert found, calls[0]
     assert int(found.group(2)) == -(-qn // 128) * 128 and int(found.group(3)) == k
+
+
+def test_iresnet_r50_compiles_for_v5e_at_the_top_rung(one_chip):
+    """The published IResNet-50 over one top-rung step's 1,024 crops of
+    112x112 fits the chip beside a 4.3 GB gallery (here with the kernels'
+    compiles, because one test file may describe the topology: the
+    on-chip-measurement guide, section 2)."""
+    import jax
+
+    from opencv_facerecognizer_tpu.models import iresnet
+
+    net = iresnet.IResNet()
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, *iresnet.R50_FACE_SIZE)))["params"]
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
+    crops = jax.ShapeDtypeStruct((1024, *iresnet.R50_FACE_SIZE), jnp.float32,
+                                 sharding=one_chip)
+    memory = jax.jit(lambda p, x: net.apply({"params": p}, x)).lower(
+        params, crops).compile().memory_analysis()
+    assert memory.output_size_in_bytes == 1024 * 512 * 4
+    assert memory.temp_size_in_bytes < 4 * 2**30, memory.temp_size_in_bytes
