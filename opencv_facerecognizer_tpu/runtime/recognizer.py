@@ -24,6 +24,17 @@ finding):
   ``readback_worker=False`` (the fallback non-threaded mode) with its
   two poll sleeps promoted to the named knobs ``readback_poll_s`` /
   ``drain_poll_s``.
+- **Feed the chip, then settle.** The one readback the loop does make
+  is the stage-1 gate's scores, and everything the loop does between a
+  step's end and the next step's enqueue is time the chip sits out. So
+  ``_serve_one`` first does what the chip waits on (scores read,
+  survivors compacted, upload, step enqueue) and only then publishes the
+  batch's early exits; and when a closed batch is already waiting (the
+  tracker was not consulted for this one, and the loop waits for the
+  chip, not the chip for the loop), that batch's gate goes onto the
+  device's queue AHEAD of this batch's step, so its scores come back
+  while the step runs and its step is queued behind. The loop never
+  waits for frames to look ahead.
 - **Bucketed dispatch cache**: a partial batch is sliced down to the
   smallest size in a fixed ``bucket_sizes`` ladder (default 8/32/128,
   filtered to the mesh's dp divisibility and capped at ``batch_size``)
@@ -221,6 +232,21 @@ class _ReadbackBlocker:
         return "ready" if self._ok else "raised"
 
 
+#: The loop puts the next batch's gate ahead of a step only while it
+#: waits for the chip (``gate_wait``) for more than this share of its
+#: iterations' wall time, both smoothed over about twenty iterations
+#: (``CHIP_BOUND_ALPHA``): the chip is then the slower party and still
+#: busy when the loop comes to enqueue. Below it the host (or the source
+#: of frames) sets the pace and looking ahead only reorders host work: on
+#: one v5e chip it cost 4 % of ``served_fps`` where the share is 0.47
+#: (``watchlist8m.crowd``) and gained 7 % where it is 0.89
+#: (``watchlist4m-r50.crowd``), and a verdict taken from single
+#: iterations flips every third one and loses in both (PERF.md section 6,
+#: PR 30).
+CHIP_BOUND_SHARE = 2.0 / 3.0
+CHIP_BOUND_ALPHA = 0.05
+
+
 class _Leaf:
     """One leaf of the serving loop's time (README "Observability", the
     table of leaves): the block's seconds are added to ``busy[stage]``,
@@ -244,6 +270,30 @@ class _Leaf:
         self._busy[self._stage] = (self._busy.get(self._stage, 0.0)
                                    + time.monotonic() - self._t0)
         return False
+
+
+class _Held:
+    """One popped batch in the serving loop's hands, from its pop to its
+    step's enqueue: the batch as the early exits have compacted it so far
+    (``batch.count`` real frames at the staging buffer's front), the spans
+    it rides (``batch_tid``, the id of its open ``dispatch`` span, the
+    instant that span began), and the early exits gathered and not yet
+    published: ``cached`` rows for ``_complete_cached``, ``rejected`` rows
+    for ``_complete_empty``. ``tracked``: some frame of the batch names a
+    stream, so the tracker was consulted for it and is owed the batch's
+    misses before it is consulted for the next."""
+
+    __slots__ = ("batch", "batch_tid", "disp_id", "t0", "cached", "rejected",
+                 "tracked")
+
+    def __init__(self, batch, batch_tid: int, disp_id: int, t0: float):
+        self.batch = batch
+        self.batch_tid = batch_tid
+        self.disp_id = disp_id
+        self.t0 = t0
+        self.cached: List[tuple] = []
+        self.rejected: List[tuple] = []
+        self.tracked = False
 
 
 class RecognizerService:
@@ -418,6 +468,18 @@ class RecognizerService:
         # thread only.
         self._loop_busy: Dict[str, float] = {}
         self._dispatch_span = 0
+        # The batch whose gate went to the device ahead of the step before
+        # it (``_serve_one``), until the next iteration serves it — kept
+        # across a crash of the loop for the restarted one — and the
+        # stage-1 scores enqueued and not yet read, with the staging
+        # buffer they were computed from (``_gate_enqueue``).
+        self._ahead: Optional[_Held] = None
+        self._gate_pending: Optional[tuple] = None
+        # Smoothed seconds an iteration of the loop waits for the chip
+        # (``gate_wait``: the scores come back behind whatever the device
+        # had queued) and lasts: their ratio is ``CHIP_BOUND_SHARE``'s.
+        self._chip_wait_s = 0.0
+        self._iteration_s = 0.0
         self.slo = slo_monitor
         self.replica = replica
         # Embedder-rollout coordinator (runtime.rollout.RolloutCoordinator),
@@ -848,6 +910,9 @@ class RecognizerService:
         wall time under ``loop_s_unnamed``: the counters tile the loop's
         time, so a window's deltas say where a lost second sat."""
         busy = self._loop_busy
+        self._chip_wait_s += CHIP_BOUND_ALPHA * (
+            busy.get("gate_wait", 0.0) - self._chip_wait_s)
+        self._iteration_s += CHIP_BOUND_ALPHA * (wall - self._iteration_s)
         named = 0.0
         for stage, seconds in busy.items():
             self.metrics.incr(mn.LOOP_S_PREFIX + stage, seconds)
@@ -872,41 +937,63 @@ class RecognizerService:
             thr = min(0.99, thr + self.cascade_brownout_notch)
         return thr
 
+    def _gate_enqueue(self, frames, count: int, batch_tid: int) -> None:
+        """Stage 1 over the batch's dispatch rung, put on the device's
+        queue and not read: the scores wait in ``_gate_pending`` for
+        ``_cascade_keep_mask``, so the loop may enqueue other work (the
+        batch before's step) between the two. An enqueue that raises
+        leaves nothing pending, and the batch fails OPEN to the full
+        chain (the cascade may save device time, never cost
+        availability)."""
+        self._gate_pending = None
+        bucket = self._pick_bucket(count)
+        view = frames[:bucket] if bucket < len(frames) else frames
+        t0 = time.monotonic()
+        try:
+            with self._leaf("gate_enqueue", batch_tid):
+                scores = self.pipeline.cascade_scores(view)
+        except Exception:  # noqa: BLE001 — fail open: stage 2 serves the batch
+            logging.getLogger(__name__).exception(
+                "cascade stage-1 scoring failed; serving the full batch")
+            self.metrics.incr(mn.CASCADE_ERRORS)
+            return
+        info = getattr(self.pipeline, "last_cascade_info", None) or {}
+        if self._warmed and info.get("cache_hit") is False:
+            self._note_recompile(bucket, count, "cascade")
+        self._gate_pending = (frames, scores, time.monotonic() - t0)
+
     def _cascade_keep_mask(self, frames, count: int,
                            batch_tid: int) -> Optional[np.ndarray]:
-        """One stage-1 pass over the batch's dispatch rung: returns the
-        per-frame keep mask (True = face-possible, survives to the full
-        detector) for the first ``count`` frames, or None when stage 1
-        is unavailable this batch — a scoring error fails OPEN to the
-        full chain (the cascade may save device time, never cost
-        availability). The tiny [B]-float readback here IS the
-        early-exit decision point; its host wall (incl. that readback)
-        lands in the ``cascade_score`` window."""
+        """Read the stage-1 scores that ``_gate_enqueue`` put on the
+        device for this batch: returns the per-frame keep mask (True =
+        face-possible, survives to the full detector) for the first
+        ``count`` frames, or None when stage 1 is unavailable this batch
+        — a scoring error fails OPEN to the full chain. The tiny
+        [B]-float readback here IS the early-exit decision point, and
+        the fence of the staging buffer's first H2D read; the host wall
+        of both halves lands in the ``cascade_score`` window."""
+        pending, self._gate_pending = self._gate_pending, None
+        if pending is None or pending[0] is not frames:
+            return None  # the enqueue failed, and was counted: fail open
+        _frames, scores, enqueue_s = pending
         thr = self._effective_cascade_threshold()
         with (self.tracer.span(batch_tid, "cascade",
                                parent=self._dispatch_span, frames=count,
                                threshold=round(thr, 4))
               if batch_tid else tracing.NULL_SPAN) as cascade:
             t0 = time.monotonic()
-            bucket = self._pick_bucket(count)
-            view = frames[:bucket] if bucket < len(frames) else frames
             try:
-                # Two leaves: the enqueue returns at once; the readback
-                # waits for whatever the device still has queued ahead of
-                # stage 1 (step n), then for the scores' way back.
-                with self._leaf("gate_enqueue", batch_tid, parent=cascade.id):
-                    scores = self.pipeline.cascade_scores(view)
+                # The readback waits for whatever the device still has
+                # queued ahead of stage 1, then for the scores' way back.
                 with self._leaf("gate_wait", batch_tid, parent=cascade.id):
                     scores = np.asarray(scores)  # ocvf-lint: boundary=host-sync -- the cascade's designed decision readback: a [B]-float materialize whose entire purpose is deciding whether the expensive stage-2 dispatch happens at all (ISSUE 13)
             except Exception:  # noqa: BLE001 — fail open: stage 2 serves the batch
                 logging.getLogger(__name__).exception(
-                    "cascade stage-1 scoring failed; serving the full batch")
+                    "cascade stage-1 readback failed; serving the full batch")
                 self.metrics.incr(mn.CASCADE_ERRORS)
                 return None
-            self.metrics.observe(mn.CASCADE_SCORE, time.monotonic() - t0)
-            info = getattr(self.pipeline, "last_cascade_info", None) or {}
-            if self._warmed and info.get("cache_hit") is False:
-                self._note_recompile(bucket, count, "cascade")
+            self.metrics.observe(mn.CASCADE_SCORE,
+                                 enqueue_s + time.monotonic() - t0)
             keep = scores[:count] >= thr
             if self._faults is not None:
                 # Chaos boundary: ``cascade: reject_all`` forces the
@@ -923,6 +1010,24 @@ class RecognizerService:
             cascade.attrs["rejected"] = rejected
         return keep
 
+    def _note_gate_misses(self, rejected, batch_tid: int) -> None:
+        """A face-free verdict on a tracked stream is a miss for its
+        live tracks: a vanished subject ages out within the miss TTL
+        instead of being served from a stale cache entry. The tracker is
+        told when the verdict is read — ahead of the step's enqueue, so
+        ahead of the ``tracker.update`` calls of that step's results, and
+        of every later lookup; the publish (``_complete_empty``) follows
+        the enqueue. The calls wait for the tracker's lock, which the
+        readback thread's ``tracker.update`` holds: leaf ``track_miss``."""
+        with self._leaf("track_miss", batch_tid, frames=len(rejected)):
+            for meta, _ts, _tid, _pri in rejected:
+                key = self._track_stream_key(meta)
+                if key is not None:
+                    try:
+                        self.tracker.note_miss(key)
+                    except Exception:  # noqa: BLE001 — observation only
+                        self.metrics.incr(mn.TRACK_ERRORS)
+
     def _complete_empty(self, rejected, batch_tid: int) -> None:
         """Settle cascade-rejected frames as ``completed_empty``: each
         publishes a result with an empty face list (producers get an
@@ -932,17 +1037,6 @@ class RecognizerService:
         ``rejected`` rows are ``(meta, enqueue_ts, trace_id, priority)``.
         A crash escaping mid-run settles the remainder as crashed,
         exactly like ``_publish`` — no frame is ever left in limbo."""
-        if self.tracker is not None:
-            # A face-free verdict on a tracked stream is a miss for its
-            # live tracks: a vanished subject ages out within the miss
-            # TTL instead of being served from a stale cache entry.
-            for meta, _ts, _tid, _pri in rejected:
-                key = self._track_stream_key(meta)
-                if key is not None:
-                    try:
-                        self.tracker.note_miss(key)
-                    except Exception:  # noqa: BLE001 — observation only
-                        self.metrics.incr(mn.TRACK_ERRORS)
         published = 0
         try:
             for meta, _ts, _tid, _pri in rejected:
@@ -1581,23 +1675,36 @@ class RecognizerService:
             self._crashed = True
             self._publish_status({"status": "crashed"})
 
-    def _serve_loop(self) -> None:
+    def _pop(self, block: bool):
+        """``get_batch`` under the ``pop_wait`` leaf: the batch (None
+        when nothing is flushable) and the call's two instants."""
+        t_pop = time.monotonic()
+        with (tracing.annotation("pop_wait") if self.tracer is not None
+              else tracing.NULL_SPAN):
+            batch = self.batcher.get_batch(block=block)
+        t_popped = time.monotonic()
         busy = self._loop_busy
-        busy.clear()  # a crashed predecessor's half iteration
+        busy["pop_wait"] = busy.get("pop_wait", 0.0) + t_popped - t_pop
+        return batch, t_pop, t_popped
+
+    def _serve_loop(self) -> None:
+        self._loop_busy.clear()  # a crashed predecessor's half iteration
         # An iteration runs from the end of the one before to the end of
-        # the batch it pops; idle ticks in between belong to it.
+        # the batch it serves; idle ticks in between belong to it.
         t_iter = time.monotonic()
-        while self._running:
-            t_pop = time.monotonic()
-            with (tracing.annotation("pop_wait") if self.tracer is not None
-                  else tracing.NULL_SPAN):
-                batch = self.batcher.get_batch(block=True)
-            t_popped = time.monotonic()
-            busy["pop_wait"] = busy.get("pop_wait", 0.0) + t_popped - t_pop
+        # A batch the loop already holds (``_ahead``: its gate went to the
+        # device ahead of the step before it) is served before anything
+        # is popped, after stop() and after a supervisor's restart too:
+        # the batcher has counted it delivered.
+        while self._running or self._ahead is not None:
+            held, self._ahead = self._ahead, None
+            batch = None
+            if held is None:
+                batch, t_pop, t_popped = self._pop(block=True)
             # Liveness stamp: placed AFTER the pop so a loop wedged
             # anywhere in the iteration body (dispatch, inflight wait,
             # publish) stops refreshing it and ``loop_staleness_s`` grows.
-            self._loop_progress_t = t_popped
+            self._loop_progress_t = time.monotonic()
             # Durable-state tick: a cheap WAL row-count/age threshold
             # check; when due it SPAWNS the checkpoint worker (snapshot +
             # write happen off-thread, single-flight) — dispatch never
@@ -1631,29 +1738,36 @@ class RecognizerService:
                     logging.getLogger(__name__).exception(
                         "read-replica WAL poll failed")
                     self.metrics.incr(mn.REPLICATION_POLL_ERRORS)
-            if batch is None:
-                if not self._running:
-                    break
-                # Idle tick: an empty queue means zero queue wait — feed
-                # the brownout EWMA so it recovers even when the flood
-                # stops dead (no batches would otherwise update it) — and
-                # announce any rejections still pending from a flood that
-                # ended mid-aggregation-window.
-                self._note_queue_wait(0.0)
-                self._flush_rejections()
-                if not self._use_worker:
-                    self._drain()
-                continue
-            self._serve_one(batch, t_pop, t_popped)
+            if held is None:
+                if batch is None:
+                    if not self._running:
+                        break
+                    # Idle tick: an empty queue means zero queue wait — feed
+                    # the brownout EWMA so it recovers even when the flood
+                    # stops dead (no batches would otherwise update it) — and
+                    # announce any rejections still pending from a flood that
+                    # ended mid-aggregation-window.
+                    self._note_queue_wait(0.0)
+                    self._flush_rejections()
+                    if not self._use_worker:
+                        self._drain()
+                    continue
+                held = self._open_batch(batch, t_pop, t_popped)
+            self._serve_one(held)
             t_end = time.monotonic()
             self._flush_loop_busy(t_end - t_iter)
             t_iter = t_end
         if not self._use_worker:
             self._drain(force=True)
 
-    def _serve_one(self, batch, t_pop: Optional[float] = None,
-                   t_popped: Optional[float] = None) -> None:
-        frames, metas, count = batch.frames, batch.metas, batch.count
+    def _open_batch(self, batch, t_pop: float, t_popped: float) -> _Held:
+        """A popped batch's way through the loop up to its gate's
+        enqueue: queue-wait observations, the brownout trim, the
+        track-cache lookups (hits leave the staging buffer's front) and
+        stage 1 put on the device. Nothing is read back and nothing is
+        published, so the loop runs this for batch n+1 ahead of step n's
+        enqueue when a closed batch is already waiting. A crash settles
+        the batch before it propagates."""
         trace_ids = batch.trace_ids
         tracer = self.tracer
         # Batch trace: the coalescing ancestor every traced frame in this
@@ -1664,11 +1778,12 @@ class RecognizerService:
                      if tracer is not None and any(trace_ids) else 0)
         t0 = now_mono = time.monotonic()
         # ``dispatch`` runs from here to the step's enqueue, through three
-        # exits: it cannot be one ``with`` block, so its id is drawn now
-        # for the leaves under it to name as ``parent``.
+        # exits and, for a batch opened ahead, around the step before it:
+        # it cannot be one ``with`` block, so its id is drawn now for the
+        # leaves under it to name as ``parent``.
         disp_id = self._dispatch_span = (tracer.new_span_id()
                                          if batch_tid else 0)
-        if batch_tid and t_pop is not None:
+        if batch_tid:
             # Before ``dispatch``, a root of the same trace: the
             # ``get_batch`` call that returned this batch.
             tracer.emit(batch_tid, "pop_wait", topic=tracing.BATCH_TOPIC,
@@ -1690,17 +1805,18 @@ class RecognizerService:
         # small fast device call; the trimmed (newest) frames are shed
         # with an explicit reason, not silently truncated.
         cap = self._brownout_bucket_cap()
-        if cap is not None and count > cap:
+        if cap is not None and batch.count > cap:
+            count = batch.count
             self.metrics.incr(mn.FRAMES_DROPPED_BROWNOUT, count - cap)
             self._trace_settle(trace_ids[cap:count],
                                mn.FRAMES_DROPPED_BROWNOUT,
                                "dispatch.brownout_trim", batch=batch_tid)
             self._journal_drop("brownout", self._drop_entries(
-                metas[cap:count], batch.enqueue_ts[cap:count],
+                batch.metas[cap:count], batch.enqueue_ts[cap:count],
                 trace_ids[cap:count], "dispatch.brownout_trim"),
                 level=self._brownout_level)
-            count = cap
-        accounted = False
+            batch = batch._replace(count=cap)
+        held = _Held(batch, batch_tid, disp_id, t0)
         try:
             # Track-cache gate (ISSUE 17), BEFORE the cascade: a lookup
             # is pure host work, cheaper than the stage-1 device pass, so
@@ -1709,131 +1825,185 @@ class RecognizerService:
             # never dispatched); the survivors compact toward the staging
             # buffer's front exactly like the cascade's, so the rungs
             # below dispatch only what actually needs device work.
-            if count and self.tracker is not None:
-                with self._leaf("track_cache", batch_tid,
-                                frames=count) as span:
-                    stretch = self._track_reverify_stretch()
-                    track_ver = getattr(self.pipeline.gallery,
-                                        "embedder_version", None)
-                    if track_ver is not None:
-                        track_ver = int(track_ver)
-                    # Full registry stamp when the registry is wired: a
-                    # detector/cascade cutover invalidates cached verdicts
-                    # exactly like an embedder cutover (opaque equality).
-                    track_ver = self._model_stamp(track_ver)
-                    cached = []
-                    keep_list = []
-                    for i in range(count):
-                        hit = self._track_lookup(metas[i], frames[i],
-                                                 track_ver, stretch)
-                        if hit is not None:
-                            cached.append((metas[i], batch.enqueue_ts[i],
-                                           trace_ids[i], batch.priorities[i],
-                                           hit))
-                        else:
-                            keep_list.append(i)
-                    span.attrs["hits"] = len(cached)
-                if cached:
-                    with self._leaf("compact", batch_tid,
-                                    kept=len(keep_list)):
-                        keep_idx = np.asarray(keep_list, dtype=np.intp)
-                        kept = len(keep_idx)
-                        if kept:
-                            frames[:kept] = frames[keep_idx]
-                        metas = ([metas[i] for i in keep_list]
-                                 + [None] * (len(metas) - kept))
-                        batch = batch._replace(
-                            metas=metas, count=kept,
-                            enqueue_ts=[batch.enqueue_ts[i]
-                                        for i in keep_list],
-                            trace_ids=[trace_ids[i] for i in keep_list],
-                            priorities=[batch.priorities[i]
-                                        for i in keep_list])
-                        trace_ids = batch.trace_ids
-                        count = kept
-                    with self._leaf("settle_early", batch_tid,
-                                    exit="cache", frames=len(cached)):
-                        self._complete_cached(cached, batch_tid)
-                    if not count:
-                        # Whole batch answered from the cache: no device
-                        # work at all this iteration.
-                        self.metrics.incr(mn.TRACK_BATCH_EXITS)
-                        if batch_tid:
-                            tracer.emit(batch_tid, "dispatch",
-                                        topic=tracing.BATCH_TOPIC, t0=t0,
-                                        dur=time.monotonic() - t0,
-                                        span_id=disp_id,
-                                        bucket=0, frames=0,
-                                        exit="track_cache",
-                                        brownout=self._brownout_level)
-                        accounted = True
-                        self._mark_completed()
-                        self.batcher.recycle(frames)
-                        self.batcher.report_service_time(
-                            time.monotonic() - t0)
-                        return
-            # Stage-1 cascade gate (ISSUE 13): score the whole batch at
-            # its ladder rung, settle face-free frames as
-            # ``completed_empty`` (published with an empty face list,
-            # never dispatched to detect->crop->embed->match), and
-            # compact survivors toward the staging buffer's front so the
-            # bucket slice below dispatches the smallest rung that fits
-            # what is left. Settlement ordering keeps the crash handler
-            # exact: ``count`` shrinks to the survivors BEFORE the
-            # rejected frames settle, so a crash anywhere after still
-            # settles every frame exactly once.
-            if count and self._cascade_active:
-                keep = self._cascade_keep_mask(frames, count, batch_tid)
+            if batch.count and self.tracker is not None:
+                self._track_lookups(held)
+            # Stage-1 cascade gate (ISSUE 13): score what the cache left,
+            # at its ladder rung.
+            if held.batch.count and self._cascade_active:
+                self._gate_enqueue(batch.frames, held.batch.count, batch_tid)
+        except BaseException:
+            self._settle_crashed(held)
+            raise
+        return held
+
+    def _track_lookups(self, held: _Held) -> None:
+        """Consult the track cache for every frame of the batch; hits go
+        to ``held.cached`` and leave the staging buffer's front."""
+        batch, batch_tid = held.batch, held.batch_tid
+        frames, metas = batch.frames, batch.metas
+        held.tracked = any(self._track_stream_key(metas[i]) is not None
+                           for i in range(batch.count))
+        with self._leaf("track_cache", batch_tid,
+                        frames=batch.count) as span:
+            stretch = self._track_reverify_stretch()
+            track_ver = getattr(self.pipeline.gallery,
+                                "embedder_version", None)
+            if track_ver is not None:
+                track_ver = int(track_ver)
+            # Full registry stamp when the registry is wired: a
+            # detector/cascade cutover invalidates cached verdicts
+            # exactly like an embedder cutover (opaque equality).
+            track_ver = self._model_stamp(track_ver)
+            cached, keep_list = [], []
+            for i in range(batch.count):
+                hit = self._track_lookup(metas[i], frames[i],
+                                         track_ver, stretch)
+                if hit is not None:
+                    cached.append((metas[i], batch.enqueue_ts[i],
+                                   batch.trace_ids[i], batch.priorities[i],
+                                   hit))
+                else:
+                    keep_list.append(i)
+            span.attrs["hits"] = len(cached)
+        if cached:
+            # The hits leave ``count`` and join ``held.cached`` together:
+            # a crash on either side settles each frame exactly once.
+            with self._leaf("compact", batch_tid, kept=len(keep_list)):
+                self._compact(held, np.asarray(keep_list, dtype=np.intp))
+                held.cached = cached
+
+    @staticmethod
+    def _compact(held: _Held, keep_idx) -> None:
+        """Survivors to the staging buffer's front, in place, and the
+        batch's lists cut to them: the bucket slice at dispatch then
+        takes the smallest rung that fits what is left."""
+        batch = held.batch
+        frames, kept = batch.frames, len(keep_idx)
+        if kept:
+            # Fancy-index gather copies survivors out before the front
+            # rows are overwritten: safe in-place compaction of the
+            # pooled staging buffer.
+            frames[:kept] = frames[keep_idx]
+        held.batch = batch._replace(
+            metas=([batch.metas[i] for i in keep_idx]
+                   + [None] * (len(batch.metas) - kept)),
+            count=kept,
+            enqueue_ts=[batch.enqueue_ts[i] for i in keep_idx],
+            trace_ids=[batch.trace_ids[i] for i in keep_idx],
+            priorities=[batch.priorities[i] for i in keep_idx])
+
+    def _settle_early(self, held: _Held) -> int:
+        """Publish the early exits the batch gathered (track-cache hits,
+        then gate rejections), each kind under a ``settle_early`` leaf;
+        returns how many. The rows leave ``held`` first and are settled
+        here whatever happens: a crash inside one kind's publish settles
+        that kind's remainder itself (``_complete_cached`` /
+        ``_complete_empty``), and the kind not yet tried lands in the
+        crash bucket here."""
+        kinds = [(self._complete_cached, "cache", held.cached),
+                 (self._complete_empty, "gate", held.rejected)]
+        held.cached, held.rejected = [], []
+        settled = 0
+        try:
+            while kinds:
+                complete, exit_name, rows = kinds.pop(0)
+                if rows:
+                    with self._leaf("settle_early", held.batch_tid,
+                                    exit=exit_name, frames=len(rows)):
+                        complete(rows, held.batch_tid)
+                    settled += len(rows)
+        except BaseException:
+            for _complete, _exit_name, rows in kinds:
+                self.metrics.incr(mn.FRAMES_DROPPED_CRASHED, len(rows))
+                self._trace_settle([r[2] for r in rows],
+                                   mn.FRAMES_DROPPED_CRASHED,
+                                   "settle_early.crashed",
+                                   batch=held.batch_tid)
+            raise
+        return settled
+
+    def _settle_crashed(self, held: _Held) -> None:
+        """The held batch dies with a crash of the loop; settle it so
+        drain()'s delivered==completed stays solvable after the
+        supervisor restarts the loop — its survivors and the early exits
+        it had not published yet land in the ledger's crash bucket, not
+        in limbo. The staging buffer is forfeited, not recycled: the
+        crash may have left an async H2D read of it pending."""
+        batch = held.batch
+        lost = (list(batch.trace_ids[:batch.count])
+                + [r[2] for r in held.cached]
+                + [r[2] for r in held.rejected])
+        held.cached, held.rejected = [], []
+        self.metrics.incr(mn.FRAMES_DROPPED_CRASHED, len(lost))
+        self._trace_settle(lost, mn.FRAMES_DROPPED_CRASHED,
+                           "dispatch.crashed", batch=held.batch_tid)
+        self.batcher.forfeit(batch.frames)
+        self._mark_completed()
+
+    def _serve_one(self, held: _Held) -> None:
+        """Feed, then settle. Feed is all the chip waits on: the gate's
+        scores read, survivors compacted, the next closed batch's gate
+        put on the device if one is already waiting, then this batch's
+        upload and step. Settle is what it does not wait on: the publish
+        of the batch's early exits (track-cache hits, gate rejections),
+        which follows the step's enqueue — or stands in for it when no
+        frame survives."""
+        tracer = self.tracer
+        batch_tid, disp_id, t0 = held.batch_tid, held.disp_id, held.t0
+        self._dispatch_span = disp_id
+        frames = held.batch.frames
+        accounted = False
+        try:
+            # Where a batch without survivors left: the cache, unless the
+            # gate turns away the rest.
+            exit_stage = "track_cache"
+            # Settlement ordering keeps the crash handler exact:
+            # ``count`` shrinks to the survivors and the rejected rows
+            # join ``held`` together, BEFORE anything else can fail, so
+            # a crash anywhere after still settles every frame exactly
+            # once.
+            if held.batch.count and self._cascade_active:
+                keep = self._cascade_keep_mask(frames, held.batch.count,
+                                               batch_tid)
                 if keep is not None and not keep.all():
                     with self._leaf("compact", batch_tid,
                                     kept=int(keep.sum())):
-                        keep_idx = np.flatnonzero(keep)
-                        rejected = [(metas[i], batch.enqueue_ts[i],
-                                     trace_ids[i], batch.priorities[i])
-                                    for i in np.flatnonzero(~keep)]
-                        kept = len(keep_idx)
-                        if kept:
-                            # Fancy-index gather copies survivors out
-                            # before the front rows are overwritten: safe
-                            # in-place compaction of the pooled staging
-                            # buffer.
-                            frames[:kept] = frames[keep_idx]
-                        metas = ([metas[i] for i in keep_idx]
-                                 + [None] * (len(metas) - kept))
-                        batch = batch._replace(
-                            metas=metas, count=kept,
-                            enqueue_ts=[batch.enqueue_ts[i]
-                                        for i in keep_idx],
-                            trace_ids=[trace_ids[i] for i in keep_idx],
-                            priorities=[batch.priorities[i]
-                                        for i in keep_idx])
-                        trace_ids = batch.trace_ids
-                        count = kept
-                    with self._leaf("settle_early", batch_tid,
-                                    exit="gate", frames=len(rejected)):
-                        self._complete_empty(rejected, batch_tid)
-                    if not count:
-                        # Zero survivors: the whole batch exits at stage
-                        # 1 — no stage-2 dispatch at all, THE early-exit
-                        # win. The dispatch span records the exit stage
-                        # so PR 8 attribution stays honest.
-                        self.metrics.incr(mn.CASCADE_BATCH_EXITS)
-                        if batch_tid:
-                            tracer.emit(batch_tid, "dispatch",
-                                        topic=tracing.BATCH_TOPIC, t0=t0,
-                                        dur=time.monotonic() - t0,
-                                        span_id=disp_id,
-                                        bucket=0, frames=0,
-                                        exit="cascade",
-                                        brownout=self._brownout_level)
-                        accounted = True
-                        self._mark_completed()
-                        # The stage-1 scores readback completed, which
-                        # fences the buffer's H2D read: safe to recycle.
-                        self.batcher.recycle(frames)
-                        self.batcher.report_service_time(
-                            time.monotonic() - t0)
-                        return
+                        batch = held.batch
+                        rejected = [
+                            (batch.metas[i], batch.enqueue_ts[i],
+                             batch.trace_ids[i], batch.priorities[i])
+                            for i in np.flatnonzero(~keep)]
+                        self._compact(held, np.flatnonzero(keep))
+                        held.rejected = rejected
+                    exit_stage = "cascade"
+                    if held.tracked:
+                        self._note_gate_misses(held.rejected, batch_tid)
+            batch = held.batch
+            metas, count, trace_ids = batch.metas, batch.count, batch.trace_ids
+            if not count:
+                # No survivor (every frame answered from the cache, or
+                # turned away at stage 1): no stage-2 dispatch at all, THE
+                # early-exit win, and nothing to put ahead of the settle.
+                # The dispatch span records the exit stage so PR 8
+                # attribution stays honest.
+                self._settle_early(held)
+                self.metrics.incr(mn.CASCADE_BATCH_EXITS
+                                  if exit_stage == "cascade"
+                                  else mn.TRACK_BATCH_EXITS)
+                if batch_tid:
+                    tracer.emit(batch_tid, "dispatch",
+                                topic=tracing.BATCH_TOPIC, t0=t0,
+                                dur=time.monotonic() - t0, span_id=disp_id,
+                                bucket=0, frames=0, exit=exit_stage,
+                                brownout=self._brownout_level)
+                accounted = True
+                self._mark_completed()
+                # Stage 1 never saw the buffer, or its scores readback
+                # completed, which fences the buffer's H2D read: safe to
+                # recycle.
+                self.batcher.recycle(frames)
+                self.batcher.report_service_time(time.monotonic() - t0)
+                return
             # Bucketed dispatch: slice the padded staging array down to the
             # smallest warmed ladder size that fits the real frames — a
             # view, not a copy, so steady state allocates nothing.
@@ -1846,6 +2016,28 @@ class RecognizerService:
                 tracer.emit(batch_tid, "stage", topic=tracing.BATCH_TOPIC,
                             parent=disp_id, rung=len(frames), bucket=bucket,
                             frames=count)
+            # Gate n+1 ahead of step n: a closed batch already waiting
+            # has its stage 1 put on the device's queue before this step,
+            # so its scores come back while the step runs and the next
+            # step is enqueued behind this one — the chip never waits for
+            # a readback. Never waited for: with nothing closed, the step
+            # goes to the chip at once. Only while the chip is the slower
+            # party (``CHIP_BOUND_SHARE``): where the loop is, the chip is
+            # idle by now and waits for this very step, and the next
+            # gate's enqueue would only stand in its way. Off for a batch
+            # the tracker was consulted for: the lookups of batch n+1
+            # have to precede its gate (hits leave before stage 1 scores
+            # the rest) and to follow this batch's publishes, which
+            # follow the step.
+            if (self._running and self._cascade_active
+                    and not held.tracked
+                    and self._chip_wait_s
+                    >= CHIP_BOUND_SHARE * self._iteration_s):
+                nxt, t_pop, t_popped = self._pop(block=False)
+                if nxt is not None:
+                    self._ahead = self._open_batch(nxt, t_pop, t_popped)
+                    self.metrics.incr(mn.BATCHES_GATED_AHEAD)
+                    self._dispatch_span = disp_id
             # Embedder-version stamp captured AT DISPATCH: the batch's
             # scores are computed against the gallery data this dispatch
             # reads, so its published results carry the version serving
@@ -1868,7 +2060,9 @@ class RecognizerService:
                 # Retries exhausted or the error was permanent (poisoned
                 # batch): abandoned, not published — but still completed
                 # for drain() accounting (and an explicit per-frame drop
-                # in the admission ledger + journal).
+                # in the admission ledger + journal). Its early exits
+                # have their answers all the same, and get them first.
+                self._settle_early(held)
                 self.metrics.incr(mn.FRAMES_FAILED, count)
                 self._trace_settle(trace_ids[:count], mn.FRAMES_FAILED,
                                    "dispatch.abandoned", batch=batch_tid)
@@ -1899,18 +2093,7 @@ class RecognizerService:
                 self._inflight_cv.notify_all()
         except BaseException:
             if not accounted:
-                # The popped batch dies with this crash; settle it so
-                # drain()'s delivered==completed stays solvable after the
-                # supervisor restarts the loop — and its frames land in
-                # the ledger's crash bucket, not in limbo. The staging
-                # buffer is forfeited, not recycled: the crash may have
-                # left an async H2D read of it pending.
-                self.metrics.incr(mn.FRAMES_DROPPED_CRASHED, count)
-                self._trace_settle(trace_ids[:count],
-                                   mn.FRAMES_DROPPED_CRASHED,
-                                   "dispatch.crashed", batch=batch_tid)
-                self.batcher.forfeit(frames)
-                self._mark_completed()
+                self._settle_crashed(held)
             raise
         self.metrics.incr(mn.BATCHES_DISPATCHED)
         self.metrics.incr(mn.FRAMES_PROCESSED, count)
@@ -1931,7 +2114,7 @@ class RecognizerService:
                         cache_hit=info.get("cache_hit"),
                         mode=info.get("mode"), exit="full",
                         brownout=self._brownout_level)
-        # What follows lies after ``dispatch``: a root of the batch trace.
+        # What follows lies after ``dispatch``: roots of the batch trace.
         self._dispatch_span = 0
         if self._warmed and info.get("cache_hit") is False:
             # Recompile watchdog (see _note_recompile): a serving
@@ -1940,6 +2123,11 @@ class RecognizerService:
             self._note_recompile(bucket, count, info.get("mode"))
         if bucket < self.batcher.batch_size:
             self.metrics.incr(mn.BATCHES_BUCKETED)
+        # The step is on the device's queue and the batch on ``_inflight``:
+        # now the host work the chip does not wait on.
+        deferred = self._settle_early(held)
+        if deferred:
+            self.metrics.incr(mn.EARLY_EXITS_DEFERRED, deferred)
         if self._use_worker:
             # Backpressure: beyond inflight_depth undrained batches, wait
             # for the readback worker to free a slot (it notifies the cv on

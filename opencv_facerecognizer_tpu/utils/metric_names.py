@@ -55,11 +55,22 @@ DEGRADED_RECOVERIES = "degraded_recoveries"
 #: block), and ``loop_s_unnamed``: the iteration's wall time under no leaf.
 LOOP_S_PREFIX = "loop_s_"
 LOOP_LEAVES = ("pop_wait", "track_cache", "gate_enqueue", "gate_wait",
-               "compact", "settle_early", "upload", "step_enqueue",
-               "inflight_wait")
+               "compact", "track_miss", "settle_early", "upload",
+               "step_enqueue", "inflight_wait")
 #: iterations of the serving loop that popped a batch (the denominator of
 #: every ``loop_s_*`` per-batch quotient).
 LOOP_BATCHES = "loop_batches"
+#: batches whose stage-1 gate went onto the device's queue ahead of the
+#: step of the batch before (a closed batch was already waiting when that
+#: step was about to be enqueued); over ``batches_dispatched``, the share
+#: of steps the chip had queued before it finished the one before.
+BATCHES_GATED_AHEAD = "batches_gated_ahead"
+#: early-exit frames (track-cache hits and gate rejections) published
+#: after their batch's step was enqueued, not ahead of it; over
+#: ``frames_completed_empty`` + ``frames_completed_cached``, the share of
+#: early exits whose publish the chip did not wait on (the rest belong to
+#: batches with no survivor, which enqueue no step).
+EARLY_EXITS_DEFERRED = "early_exits_deferred"
 #: the readback worker's ``_publish`` as a whole, beside its count
 #: ``frames_completed``; of it, the ``tracker.update`` calls and their count.
 PUBLISH_S = "publish_s"

@@ -346,11 +346,13 @@ def test_fold_attribution_sets_registered_gauges():
 # ---- leaf spans, parents, profiler annotations, busy-time counters ----
 
 #: children of ``dispatch`` in the order the loop passes them (README
-#: "Observability", the table of leaves); ``cascade`` holds the two gate
-#: leaves, ``stage`` is the instant ingest-provenance span.
-DISPATCH_CHILD_RANK = {"track_cache": 0, "cascade": 1, "compact": 2,
-                       "settle_early": 2, "stage": 3, "upload": 4,
-                       "step_enqueue": 5}
+#: "Observability", the table of leaves); ``cascade`` holds the gate's
+#: readback, ``stage`` is the instant ingest-provenance span.
+#: ``settle_early`` is a child only of a ``dispatch`` that enqueued no
+#: step; behind a step it is a root that follows ``dispatch``.
+DISPATCH_CHILD_RANK = {"track_cache": 0, "gate_enqueue": 1, "cascade": 2,
+                       "compact": 3, "track_miss": 4, "settle_early": 5,
+                       "stage": 5, "upload": 6, "step_enqueue": 7}
 VIDEO_HW = (32, 32)
 
 
@@ -470,7 +472,7 @@ def _assert_disjoint_in_order(spans):
 def test_dispatch_children_name_it_lie_inside_and_tile_in_order(video_run):
     by_trace = video_run["by_trace"]
     assert len(by_trace) >= 10
-    stages_seen, exits = set(), set()
+    stages_seen, exits, deferred = set(), set(), 0
     for spans in by_trace.values():
         dispatch = [s for s in spans if s["stage"] == "dispatch"]
         assert len(dispatch) == 1
@@ -485,21 +487,31 @@ def test_dispatch_children_name_it_lie_inside_and_tile_in_order(video_run):
         assert all(c["span"] > dispatch["span"] for c in children)
         ordered = _assert_disjoint_in_order(children)
         ranks = [DISPATCH_CHILD_RANK[c["stage"]] for c in ordered]
-        # table B's order; the cache's compact + settle_early (rank 2)
-        # come before the gate, hence the one allowed step down
+        # table B's order; the cache's compact (rank 3) comes before the
+        # gate, hence the one allowed step down
         assert ranks == sorted(ranks) or [
-            r for r in ranks if r != 2] == sorted(r for r in ranks if r != 2)
+            r for r in ranks if r != 3] == sorted(r for r in ranks if r != 3)
         stages_seen.update(c["stage"] for c in children)
         cascade = [c for c in children if c["stage"] == "cascade"]
         for gate in cascade:
-            leaves = _assert_disjoint_in_order(
-                [s for s in spans if s["parent"] == gate["span"]])
-            assert [s["stage"] for s in leaves] == ["gate_enqueue", "gate_wait"]
+            leaves = [s for s in spans if s["parent"] == gate["span"]]
+            assert [s["stage"] for s in leaves] == ["gate_wait"]
             assert all(_inside(s, gate) for s in leaves)
+        # early exits are published behind the step where there is one
+        # (roots after ``dispatch``), in its place where there is none
+        settles = [s for s in spans if s["stage"] == "settle_early"]
+        for settle in settles:
+            if dispatch["exit"] == "full":
+                assert settle["parent"] == 0
+                assert settle["t0"] >= dispatch["t0"] + dispatch["dur"] - 1e-9
+                deferred += settle["frames"]
+            else:
+                assert settle["parent"] == dispatch["span"]
         if dispatch["exit"] == "full":
             assert {"upload", "step_enqueue"} <= {c["stage"] for c in children}
     assert exits >= {"full"} and len(exits) >= 2
     assert stages_seen == set(DISPATCH_CHILD_RANK)
+    assert deferred == video_run["metrics"].counter("early_exits_deferred") > 0
 
 
 def test_pop_wait_and_inflight_wait_are_roots_around_dispatch(video_run):
@@ -547,8 +559,8 @@ def test_annotations_are_ocvf_stage_on_the_emitting_thread(video_run):
     for note in notes:
         by_name.setdefault(note.name[len("ocvf:"):], set()).add(note.entered)
     on_loop = {"pop_wait", "track_cache", "cascade", "gate_enqueue",
-               "gate_wait", "compact", "settle_early", "upload",
-               "step_enqueue", "inflight_wait"}
+               "gate_wait", "compact", "track_miss", "settle_early",
+               "upload", "step_enqueue", "inflight_wait"}
     assert set(by_name) == on_loop | {"publish", "track_update", "intake"}
     for stage in on_loop:
         assert by_name[stage] == {threads["loop"]}, stage
