@@ -406,21 +406,6 @@ def main():
         )
         return jax.lax.top_k(sims, 1)
 
-    # OCVF_FUSED_EMBEDDER=1 runs the embed stage on the fused pallas
-    # schedule (ops.pallas_sepblock; equivalence pinned in tests) so the
-    # measurement queue can re-measure the headline under the alternative
-    # schedule right after scripts/bench_sepblock.py's A/B, without a code
-    # edit. The committed default stays the flax graph until the A/B
-    # measures a win.
-    fused_embedder = os.environ.get("OCVF_FUSED_EMBEDDER", "") not in ("", "0")
-    if fused_embedder:
-        from opencv_facerecognizer_tpu.models.embedder import fused_forward
-
-        _log("embed stage: fused pallas schedule (OCVF_FUSED_EMBEDDER)")
-        embed_apply = lambda p, x: fused_forward(net, p, x)  # noqa: E731
-    else:
-        embed_apply = lambda p, x: net.apply({"params": p}, x)  # noqa: E731
-
     def make_step(batch, matcher=xla_matcher):
         def step(det_params, emb_params, gallery, labels, frames):
             outputs = det.net.apply({"params": det_params}, frames)
@@ -429,7 +414,8 @@ def main():
             )
             crops = image_ops.batched_crop_resize(frames, boxes, face_size)
             flat = crops.reshape((batch * max_faces, *face_size))
-            emb = embed_apply(emb_params, normalize_faces(flat, face_size))
+            emb = net.apply({"params": emb_params},
+                            normalize_faces(flat, face_size))
             top_sims, top_idx = matcher(emb, gallery)
             return boxes, valid, jnp.take(labels, top_idx), top_sims
 
@@ -467,7 +453,6 @@ def main():
         "min_delta_s": MIN_DELTA_S, "h2d_iters": H2D_ITERS,
         "bf16_peak_tflops": peak_tflops,
         "timing_method": "chained differencing (see bench.py module docstring)",
-        "fused_embedder": fused_embedder,
     }, "sweep": {}}
     headline = None
 
@@ -495,7 +480,7 @@ def main():
         # pre-allocated recycled uint8 staging buffer per batch size,
         # copied into and uploaded — the serving ingest path's exact
         # staging discipline, timed next to the fresh-allocation arms so
-        # the old-vs-new p99 story (the --transfer-uint8 118 ms tail came
+        # the old-vs-new p99 story (the first uint8 path's 118 ms tail came
         # from unpinned per-batch staging allocations) is a committed
         # artifact, not a claim.
         ring_stage = np.zeros((batch, height, width), np.uint8)
@@ -610,7 +595,7 @@ def main():
                 "note": "device compute + H2D transfer, serialized; the "
                         "serving runtime overlaps these, so this is an "
                         "upper bound per batch. uint8 variant = the "
-                        "--transfer-uint8 serving path (cast on device)",
+                        "--ingest-mode uint8 serving path (cast on device)",
                 "ms_per_batch": round((mean_s + h2d_mean_s) * 1e3, 3),
                 "valid_face_throughput_per_s": round(
                     batch * max_faces * valid_frac / (mean_s + h2d_mean_s), 1
@@ -651,11 +636,8 @@ def main():
                 flat = crops.reshape((batch * max_faces, *face_size))
                 out = out + jnp.sum(flat) * 1e-6
             if upto in ("embed", "full"):
-                # embed_apply, not net.apply: the stage attribution must
-                # measure the SAME schedule as the headline (a fused-
-                # schedule re-run with flax attribution would silently
-                # label the wrong graph's costs).
-                emb = embed_apply(emb_params, normalize_faces(flat, face_size))
+                emb = net.apply({"params": emb_params},
+                                normalize_faces(flat, face_size))
                 out = out + jnp.sum(emb)
             if upto == "full":
                 top_sims, top_idx = xla_matcher(emb, gallery)

@@ -16,10 +16,10 @@ current code with the locally attached chip (ROADMAP Speed 0 rebuilds this
 benchmark); without ``--smoke`` the script refuses to run unless the default
 backend is a TPU.
 
-The artifact now also carries an ``overlap_comparison`` section — the same
-offered-load ladder driven through the legacy inline-poll serving loop and
-through the overlapped pipeline (readback worker + continuous batching +
-bucketed dispatch) — and ``--smoke`` runs a deterministic fake-backend
+The artifact now also carries an ``overlap_comparison`` section — an
+offered-load ladder driven through the serving loop with the adaptive
+batching deadline set (readback worker + continuous batching + bucketed
+dispatch) — and ``--smoke`` runs a deterministic fake-backend
 variant (``run_smoke``) that emulates a backend whose ``is_ready`` poll
 costs a fixed ~100 ms floor, on CPU, and writes BENCH_SERVING_smoke.json (also invokable as
 ``scripts/bench_serving.py --smoke``), now with an ``overload_sweep``
@@ -91,8 +91,7 @@ def build_pipeline(frame_hw=(256, 256), gallery_size=1024):
 
 
 def make_service(pipeline, frame_hw, batch_size, flush_ms, inflight_depth,
-                 readback_worker=True, target_latency_ms=None,
-                 bucket_sizes=None):
+                 target_latency_ms=None, bucket_sizes=None):
     from opencv_facerecognizer_tpu.runtime.connector import FakeConnector
     from opencv_facerecognizer_tpu.runtime.recognizer import (
         DEFAULT_BUCKET_SIZES, RecognizerService,
@@ -104,7 +103,6 @@ def make_service(pipeline, frame_hw, batch_size, flush_ms, inflight_depth,
         pipeline, connector, batch_size=batch_size, frame_shape=frame_hw,
         flush_timeout=flush_ms / 1e3, inflight_depth=inflight_depth,
         similarity_threshold=0.0, metrics=Metrics(),
-        readback_worker=readback_worker,
         target_latency_s=(None if target_latency_ms is None
                           else target_latency_ms / 1e3),
         bucket_sizes=(DEFAULT_BUCKET_SIZES if bucket_sizes is None
@@ -186,15 +184,14 @@ def drive_rate(service, connector, frames, rate_hz: float, duration_s: float):
 
 
 def run_mode(pipeline, frames, frame_hw, *, name, batch_size, flush_ms,
-             inflight_depth, rates, duration_s, readback_worker=True,
-             target_latency_ms=None, bucket_sizes=None):
+             inflight_depth, rates, duration_s, target_latency_ms=None,
+             bucket_sizes=None):
     """Drive one serving configuration over the offered rates; fresh
     metrics per rate so each row's decomposition covers that rate only."""
     from opencv_facerecognizer_tpu.utils.metrics import Metrics
 
     service, connector = make_service(pipeline, frame_hw, batch_size,
                                       flush_ms, inflight_depth,
-                                      readback_worker=readback_worker,
                                       target_latency_ms=target_latency_ms,
                                       bucket_sizes=bucket_sizes)
     service.start(warmup=True)
@@ -214,7 +211,6 @@ def run_mode(pipeline, frames, frame_hw, *, name, batch_size, flush_ms,
         "config": {"batch_size": batch_size, "flush_ms": flush_ms,
                    "inflight_depth": inflight_depth,
                    "frame": list(frame_hw), "duration_s": duration_s,
-                   "readback_worker": readback_worker,
                    "target_latency_ms": target_latency_ms},
         "rates": rows,
     }
@@ -225,18 +221,18 @@ def run_mode(pipeline, frames, frame_hw, *, name, batch_size, flush_ms,
 
 def run_smoke(out_path="BENCH_SERVING_smoke.json", frames_n=160,
               rate_hz=200.0, batch_size=8, frame_hw=(64, 64),
-              sync_poll_floor_s=0.1, compute_s=0.002,
-              modes=("overlapped", "legacy_poll"), write=True):
+              sync_poll_floor_s=0.1, compute_s=0.002, write=True):
     """Fast, deterministic serving-loop perf check over the fake instant
     backend (``runtime.fakes.InstantPipeline``): the "device" completes a
     batch in ``compute_s`` but charges ``sync_poll_floor_s`` on every
     ``is_ready`` call — a backend whose readiness poll has a fixed cost,
-    reproduced on CPU. The legacy inline-drain path pays that floor
-    on the serving thread; the overlapped readback worker blocks on the
-    array instead and never polls a healthy readback, so its ``ready_wait``
-    p50 must sit far below the floor with zero drops (the tier-1 perf-smoke
-    assertion, tests/test_serving_perf.py). Writes a machine-readable
-    artifact to ``out_path``.
+    reproduced on CPU. A loop that polled readiness on the serving thread
+    would pay that floor; the readback worker blocks on the array instead
+    and never polls a healthy readback, so its ``ready_wait`` p50 must sit
+    far below the floor with zero drops (the tier-1 perf-smoke assertion,
+    tests/test_serving_perf.py). Writes a machine-readable artifact to
+    ``out_path`` (one row, ``modes.overlapped``: the name
+    scripts/bench_compare.py reads).
     """
     from opencv_facerecognizer_tpu.runtime.connector import FakeConnector
     from opencv_facerecognizer_tpu.runtime.fakes import InstantPipeline
@@ -245,39 +241,33 @@ def run_smoke(out_path="BENCH_SERVING_smoke.json", frames_n=160,
 
     frames = [np.zeros(frame_hw, np.float32)]
     duration_s = frames_n / rate_hz
-    results = {}
-    for mode in modes:
-        worker = mode == "overlapped"
-        pipeline = InstantPipeline(frame_hw, compute_s=compute_s,
-                                   sync_poll_floor_s=sync_poll_floor_s)
-        connector = FakeConnector()
-        service = RecognizerService(
-            pipeline, connector, batch_size=batch_size, frame_shape=frame_hw,
-            flush_timeout=0.05, inflight_depth=4, similarity_threshold=0.0,
-            metrics=Metrics(), readback_worker=worker,
-            target_latency_s=0.03 if worker else None,
-        )
-        service.start(warmup=False)  # the fake backend has nothing to compile
-        try:
-            stats = drive_rate(service, connector, frames, rate_hz, duration_s)
-        finally:
-            service.drain(timeout=60.0)
-            service.stop()
-        stats["batches"] = int(service.metrics.counter("batches_dispatched"))
-        results[mode] = stats
+    pipeline = InstantPipeline(frame_hw, compute_s=compute_s,
+                               sync_poll_floor_s=sync_poll_floor_s)
+    connector = FakeConnector()
+    service = RecognizerService(
+        pipeline, connector, batch_size=batch_size, frame_shape=frame_hw,
+        flush_timeout=0.05, inflight_depth=4, similarity_threshold=0.0,
+        metrics=Metrics(), target_latency_s=0.03,
+    )
+    service.start(warmup=False)  # the fake backend has nothing to compile
+    try:
+        stats = drive_rate(service, connector, frames, rate_hz, duration_s)
+    finally:
+        service.drain(timeout=60.0)
+        service.stop()
+    stats["batches"] = int(service.metrics.counter("batches_dispatched"))
     artifact = {
         "note": ("fake instant backend (runtime.fakes.InstantPipeline): "
                  f"compute {compute_s * 1e3:g} ms/batch, is_ready sync-poll "
                  f"cost {sync_poll_floor_s * 1e3:g} ms — a fixed "
                  "readiness-poll floor emulated on CPU. 'overlapped' = readback "
-                 "worker (event-driven block) + continuous batching; "
-                 "'legacy_poll' = the pre-worker inline is_ready drain. "
-                 "ready_wait_p50_ms carries the floor in legacy mode only."),
+                 "worker (event-driven block) + continuous batching: "
+                 "ready_wait_p50_ms stays off the floor."),
         "config": {"frames": frames_n, "offered_hz": rate_hz,
                    "batch_size": batch_size, "frame": list(frame_hw),
                    "sync_poll_floor_ms": sync_poll_floor_s * 1e3,
                    "compute_ms": compute_s * 1e3},
-        "modes": results,
+        "modes": {"overlapped": stats},
     }
     if write:
         with open(out_path, "w") as fh:
@@ -324,7 +314,7 @@ def run_tracing_overhead(frames_n=240, rate_hz=200.0, batch_size=8,
         service = RecognizerService(
             pipeline, connector, batch_size=batch_size, frame_shape=frame_hw,
             flush_timeout=0.05, inflight_depth=4, similarity_threshold=0.0,
-            metrics=Metrics(), readback_worker=True, target_latency_s=0.03,
+            metrics=Metrics(), target_latency_s=0.03,
             tracer=tracer,
         )
         service.start(warmup=False)
@@ -393,7 +383,7 @@ def run_ingest_smoke(rungs=(8, 32, 128), frame_hw=(64, 64), h2d_iters=160,
     **h2d** — per dispatch-bucket rung, staging + H2D transfer latency of
     three paths: ``f32_fresh`` (the legacy float path: a fresh f32
     staging allocation per batch, 4x the bytes), ``uint8_unpinned`` (the
-    OLD --transfer-uint8 shortcut: 1x bytes but still a fresh allocation
+    first uint8 shortcut: 1x bytes but still a fresh allocation
     per batch — the page-fault/allocator churn behind its measured
     118 ms p99 under load), and ``uint8_ring`` (the new path: one
     pre-allocated recycled StagingRing buffer, copied into and uploaded).
@@ -1835,8 +1825,8 @@ def main(argv=None):
     parser.add_argument("--skip-latency-mode", action="store_true")
     parser.add_argument("--compare-rates", type=float, nargs="+",
                         default=[25.0],
-                        help="offered rates for the legacy-vs-overlapped "
-                             "before/after section")
+                        help="offered rates for the adaptive-deadline "
+                             "(overlap_comparison) section")
     parser.add_argument("--skip-compare", action="store_true")
     parser.add_argument("--smoke", action="store_true",
                         help="deterministic serving-loop smoke over the fake "
@@ -1866,7 +1856,6 @@ def main(argv=None):
         with open("BENCH_SERVING_smoke.json", "w") as fh:
             json.dump(artifact, fh, indent=2)
         print("wrote BENCH_SERVING_smoke.json", file=sys.stderr)
-        legacy = artifact["modes"].get("legacy_poll", {})
         overlap = artifact["modes"].get("overlapped", {})
         sweep_4x = next((r for r in artifact["overload_sweep"]["rows"]
                          if r["offered_multiplier"] == 4.0), {})
@@ -1881,7 +1870,6 @@ def main(argv=None):
             "ingest_bytes_ratio_b32": ingest["uplift"]
             .get("b32", {}).get("bytes_ratio"),
             "ingest_ok": ingest["ingest_ok"],
-            "legacy_e2e_p50_ms": legacy.get("e2e_p50_ms"),
             "overlapped_e2e_p50_ms": overlap.get("e2e_p50_ms"),
             "overlapped_ready_wait_p50_ms": overlap.get(
                 "decomposition_ms", {}).get("ready_wait_p50_ms"),
@@ -1990,58 +1978,27 @@ def main(argv=None):
         inflight_depth=4, rates=args.rates, duration_s=args.duration,
     )
     if not args.skip_compare:
-        # Before/after on the SAME offered-load ladder: "legacy" is the
-        # pre-worker serving loop (inline is_ready drain on the serving
-        # thread, fixed flush window, no dispatch buckets); "overlapped"
-        # is the event-driven readback worker + continuous batching
-        # (adaptive deadline against a 50 ms target) + the bucket ladder.
-        # queue_wait + ready_wait in each row's decomposition_ms show
-        # where the difference lands.
-        legacy = run_mode(
-            pipeline, frames, frame_hw, name="compare/legacy",
-            batch_size=args.batch_size, flush_ms=args.flush_ms,
-            inflight_depth=4, rates=args.compare_rates,
-            duration_s=args.duration, readback_worker=False,
-            bucket_sizes=(),
-        )
+        # The serving loop with the adaptive batching deadline (50 ms
+        # target) on its own offered-load ladder: queue_wait + ready_wait
+        # in each row's decomposition_ms show where the time lands.
         overlapped = run_mode(
             pipeline, frames, frame_hw, name="compare/overlapped",
             batch_size=args.batch_size, flush_ms=args.flush_ms,
             inflight_depth=4, rates=args.compare_rates,
-            duration_s=args.duration, readback_worker=True,
-            target_latency_ms=50.0,
+            duration_s=args.duration, target_latency_ms=50.0,
         )
-        speedups = {}
-        for before, after in zip(legacy["rates"], overlapped["rates"]):
-            b, a = before.get("e2e_p50_ms"), after.get("e2e_p50_ms")
-            if b and a:
-                speedups[str(before["offered_hz"])] = round(b / a, 2)
         sections["overlap_comparison"] = {
-            "note": ("same offered-load ladder; legacy = inline poll drain "
-                     "+ fixed flush, overlapped = readback worker + "
-                     "adaptive-deadline continuous batching + bucketed "
-                     "dispatch. Caveat for CPU-backend runs: the device "
-                     "itself saturates (ready_wait is real compute), so "
-                     "e2e stays compute-bound for BOTH modes and the win "
-                     "shows up as completed-frame throughput and "
-                     "queue_wait instead; the overlap_comparison_smoke "
-                     "section isolates the serving-loop overheads "
-                     "deterministically with a ~100 ms readiness-poll "
-                     "floor emulated."),
-            "legacy_poll": legacy,
+            "note": ("overlapped = readback worker + adaptive-deadline "
+                     "continuous batching + bucketed dispatch; the "
+                     "overlap_comparison_smoke section isolates the "
+                     "serving-loop overheads deterministically with a "
+                     "~100 ms readiness-poll floor emulated."),
             "overlapped": overlapped,
-            "e2e_p50_speedup": speedups,
         }
-        # The deterministic loop-overhead comparison (fake instant backend
-        # with a fixed readiness-poll floor emulated): same artifact, so
-        # the before/after verdict travels with the hardware rows.
-        smoke = run_smoke(write=True)
-        s_legacy = smoke["modes"].get("legacy_poll", {})
-        s_over = smoke["modes"].get("overlapped", {})
-        if s_legacy.get("e2e_p50_ms") and s_over.get("e2e_p50_ms"):
-            smoke["e2e_p50_speedup"] = round(
-                s_legacy["e2e_p50_ms"] / s_over["e2e_p50_ms"], 2)
-        sections["overlap_comparison_smoke"] = smoke
+        # The deterministic loop-overhead check (fake instant backend with
+        # a fixed readiness-poll floor emulated): same artifact, so it
+        # travels with the hardware rows.
+        sections["overlap_comparison_smoke"] = run_smoke(write=True)
     if not args.skip_latency_mode:
         # Latency mode (VERDICT round-2 item #3): small batches, short
         # flush, shallow in-flight queue — the configuration an operator

@@ -52,11 +52,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "pp: two-stage pipeline parallelism — detector on "
                         "half the devices, embedder+gallery on the other "
                         "half (needs an even device count >= 2)")
-    p.add_argument("--fused-embedder", action="store_true",
-                   help="run the embed stage on the fused pallas schedule "
-                        "(ops.pallas_sepblock; single-device mesh only — "
-                        "flip after scripts/bench_sepblock.py measures a "
-                        "win on your chip)")
     p.add_argument("--batch-size", type=int, default=8)
     p.add_argument("--flush-ms", type=float, default=30.0,
                    help="max age of the oldest buffered frame before a "
@@ -76,22 +71,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "to the smallest bucket >= its real frame count "
                         "(every bucket is compiled at warmup, so partial "
                         "batches never recompile); 0 disables slicing")
-    p.add_argument("--no-readback-worker", action="store_true",
-                   help="fall back to the pre-worker serving loop that "
-                        "drains readbacks inline with is_ready polling "
-                        "(the two --*-poll-ms knobs) instead of the "
-                        "event-driven readback worker thread")
-    p.add_argument("--readback-poll-ms", type=float, default=5.0,
-                   help="fallback-path poll interval while waiting out an "
-                        "over-depth/forced readback (only used with "
-                        "--no-readback-worker, or for a proxy readback "
-                        "that cannot be blocked on)")
-    p.add_argument("--drain-poll-ms", type=float, default=50.0,
-                   help="completion-wait tick: how often drain() and the "
-                        "fallback path re-check for finished work")
     # ---- ingest pipeline (runtime.ingest; README "Ingest pipeline") ----
     p.add_argument("--ingest-mode", choices=["f32", "uint8", "jpeg"],
-                   default=None,
+                   default="f32",
                    help="ingest transfer mode. f32 (default): legacy "
                         "float staging. uint8: frames stage and cross "
                         "host->device as uint8 through the pre-allocated "
@@ -114,13 +96,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "(corrupt payloads dead-letter with reason "
                         "decode_error; depth/latency on the metrics "
                         "surface)")
-    p.add_argument("--transfer-uint8", action="store_true",
-                   help="DEPRECATED (one release): alias for "
-                        "--ingest-mode uint8. The old unpinned-staging "
-                        "uint8 path (batch-8 p99 measured ~109-118 ms "
-                        "under load) is gone — this flag now routes "
-                        "through the pre-allocated staging ring, which "
-                        "keeps the 4x byte win without the p99 pathology")
     # ---- cascade early-exit detection (models.cascade; README) ----
     p.add_argument("--cascade", metavar="PATH",
                    help="stage-1 FaceGate checkpoint (models.cascade."
@@ -491,9 +466,6 @@ def _load_stack(args, mesh=None):
 
     # Pure argument validation FIRST — before checkpoint loads and the
     # full gallery embedding pass, which can take minutes.
-    if args.fused_embedder and args.parallel == "pp":
-        raise SystemExit("--fused-embedder applies to --parallel fused only "
-                         "(stage-B meshes aren't single-device)")
     if args.match_mode == "ivf" and args.parallel == "pp":
         raise SystemExit("--match-mode ivf applies to --parallel fused only "
                          "(the two-stage path is single-device, like the "
@@ -507,10 +479,6 @@ def _load_stack(args, mesh=None):
     if not isinstance(feature, (CNNEmbedding, IResNetEmbedding)):
         raise SystemExit("--model must be a cnn checkpoint (ocvf-train "
                          "--model cnn) or an IResNetEmbedding one")
-    if args.fused_embedder and not isinstance(feature, CNNEmbedding):
-        raise SystemExit("--fused-embedder covers the separable "
-                         "FaceEmbedNet only; this checkpoint holds "
-                         f"{type(feature.net).__name__}")
     detector = CNNFaceDetector.load(args.detector)
     face_gate = None
     if args.cascade:
@@ -592,23 +560,17 @@ def _load_stack(args, mesh=None):
             face_size=feature.input_size,
         )
     else:
-        from opencv_facerecognizer_tpu.runtime.ingest import (
-            resolve_ingest_mode,
-        )
-
         import jax
 
         # Buffer donation through the bucketed ladder: only when the
         # ingest uploader feeds each dispatch a fresh device array AND
         # the backend implements input donation (CPU ignores it with a
         # warning per compiled step — noise, not a win).
-        donate = (resolve_ingest_mode(args.ingest_mode, args.transfer_uint8,
-                                      warn=False) != "f32"
+        donate = (args.ingest_mode != "f32"
                   and jax.devices()[0].platform in ("tpu", "gpu"))
         pipeline = RecognitionPipeline(
             detector, feature.net, feature._params["net"], gallery,
             face_size=feature.input_size,
-            fused_embedder=args.fused_embedder,
             donate_frames=donate,
             cascade=face_gate,
         )
@@ -639,18 +601,14 @@ def build_service(args, pipeline, names, connector, metrics, *,
     parsed flags (tracker, cascade, ingest, ladder, resilience policy) —
     one construction site, so ``chip_smoke.py`` serves through the same
     wiring the CLI does instead of a copy of it."""
-    from opencv_facerecognizer_tpu.runtime.ingest import (
-        IngestConfig, resolve_ingest_mode,
-    )
+    from opencv_facerecognizer_tpu.runtime.ingest import IngestConfig
     from opencv_facerecognizer_tpu.runtime.recognizer import RecognizerService
     from opencv_facerecognizer_tpu.runtime.resilience import (
         ResiliencePolicy, rebuild_pipeline_on_cpu,
     )
 
-    # The --transfer-uint8 deprecation warning fires HERE, once (the
-    # _load_stack probe resolves silently).
     ingest_cfg = IngestConfig(
-        mode=resolve_ingest_mode(args.ingest_mode, args.transfer_uint8),
+        mode=args.ingest_mode,
         ring_depth=args.ingest_ring_depth or None,
         decode_workers=args.ingest_decode_workers)
 
@@ -680,9 +638,6 @@ def build_service(args, pipeline, names, connector, metrics, *,
         # The ingest config owns the transfer dtype now (uint8/jpeg stage
         # as uint8 through the ring; f32 keeps the legacy dtype).
         ingest=ingest_cfg,
-        readback_worker=not args.no_readback_worker,
-        readback_poll_s=args.readback_poll_ms / 1e3,
-        drain_poll_s=args.drain_poll_ms / 1e3,
         bucket_sizes=tuple(b for b in args.bucket_sizes if b > 0),
         target_latency_s=(None if args.target_latency_ms is None
                           else args.target_latency_ms / 1e3),

@@ -15,7 +15,6 @@ inserts the collectives; nothing here names a wire protocol.
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, NamedTuple, Optional, Protocol, Tuple
 
 import jax
@@ -87,7 +86,6 @@ class RecognitionPipeline:
         gallery: ShardedGallery,
         face_size: Tuple[int, int] = (112, 112),
         top_k: int = 1,
-        fused_embedder: bool = False,
         donate_frames: bool = False,
         cascade=None,
     ):
@@ -113,23 +111,6 @@ class RecognitionPipeline:
         # warning) AND when every caller routes through the uploader:
         # a donated array must never be re-fed after dispatch.
         self.donate_frames = bool(donate_frames)
-        # Opt-in pallas schedule for the embed stage (ops.pallas_sepblock;
-        # same params/math, equivalence pinned in tests). Stays off by
-        # default until scripts/bench_sepblock.py measures a win on chip —
-        # the flip is then this one flag. Single-device meshes only: GSPMD
-        # cannot partition a pallas custom call over the mesh, so fail
-        # fast here instead of dying in an opaque Mosaic partition error
-        # at first dispatch.
-        if fused_embedder and gallery.mesh.size > 1:
-            raise ValueError(
-                "fused_embedder=True requires a single-device mesh "
-                f"(got {gallery.mesh.size} devices)")
-        if fused_embedder and not isinstance(embed_net,
-                                             embedder_mod.FaceEmbedNet):
-            raise ValueError(
-                "fused_embedder=True covers FaceEmbedNet only (got "
-                f"{type(embed_net).__name__})")
-        self.fused_embedder = bool(fused_embedder)
         # Chaos hook (runtime.faults.FaultInjector): checked at the device-
         # dispatch boundary of both recognize paths, so an injected
         # UNAVAILABLE surfaces exactly where the real backend's fast-fail
@@ -164,12 +145,6 @@ class RecognitionPipeline:
         face_size = self.face_size
         embed_net = self.embed_net
         max_faces = det.max_faces
-        if self.fused_embedder:
-            interpret = mesh.devices.flat[0].platform != "tpu"
-            embed_apply = functools.partial(
-                embedder_mod.fused_forward, embed_net, interpret=interpret)
-        else:
-            embed_apply = lambda p, x: embed_net.apply({"params": p}, x)  # noqa: E731
         # The gallery owns matcher selection (two-stage ivf vs pallas
         # streaming vs GSPMD global view) — the fused step inherits
         # whichever fits the mesh and capacity; _step_key re-selects if
@@ -197,10 +172,9 @@ class RecognitionPipeline:
                 crops = image_ops.batched_crop_resize(frames, boxes, face_size)
                 flat = embedder_mod.normalize_faces(
                     crops.reshape((batch * max_faces, *face_size)), face_size)
-            # 3) embed (flax graph, or the fused pallas schedule when
-            # self.fused_embedder — same params either way)
+            # 3) embed (the flax graph of either feature class)
             with jax.named_scope("ocvf_embed"):
-                emb = embed_apply(emb_params, flat)  # [B*K, E] unit-norm
+                emb = embed_net.apply({"params": emb_params}, flat)  # [B*K, E] unit-norm
             # 4) match against the gallery (selection in gallery.match_fn:
             # two-stage ivf for a ready quantizer above its threshold,
             # GSPMD global view when sharded, pallas streaming single-chip)

@@ -26,7 +26,6 @@ from opencv_facerecognizer_tpu.runtime.ingest import (
     IngestConfig,
     IngestPipeline,
     StagingRing,
-    resolve_ingest_mode,
 )
 from opencv_facerecognizer_tpu.runtime.journal import DeadLetterJournal
 from opencv_facerecognizer_tpu.runtime.recognizer import RecognizerService
@@ -120,7 +119,6 @@ __all__ = [
     "SLOMonitor",
     "ServiceSupervisor",
     "StagingRing",
-    "resolve_ingest_mode",
     "default_objectives",
     "disk_free_objective",
     "link_health_objective",

@@ -80,8 +80,8 @@ class InstantPipeline:
     ``compute_s`` — seconds after dispatch until the batch's readback is
     ready (simulated device compute + D2H). ``sync_poll_floor_s`` — cost
     charged on EVERY ``is_ready`` call, emulating a backend whose
-    readiness poll has a fixed cost: the legacy inline-drain path pays it on the
-    serving thread per check, while the readback worker's event-driven
+    readiness poll has a fixed cost: a loop that polled readiness would pay
+    it per check, while the readback worker's event-driven
     ``block_until_ready`` never does.
     """
 

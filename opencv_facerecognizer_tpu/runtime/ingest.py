@@ -4,7 +4,7 @@ dispatch ladder (ROADMAP item #1 — the serving loop is transfer-bound).
 The record this was built on (pre-PR-1 backend, source deleted in PR 21,
 not reproducible; not re-measured on the local chip): b32 H2D crossed at
 6.2 ms p50 (1.3 GB/s, f32) against ~0.64 ms of device compute — and the
-old ``--transfer-uint8`` shortcut, which cut bytes 4x, paid a catastrophic
+first uint8 shortcut, which cut bytes 4x, paid a catastrophic
 118 ms p99 because every batch staged through a freshly-allocated host
 array (page faults + allocator churn on the hot path) and synchronized
 under load. This module is the real fix, three pieces:
@@ -44,7 +44,6 @@ import base64
 import logging
 import threading
 import time
-import warnings
 from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -59,29 +58,6 @@ INGEST_MODES = ("f32", "uint8", "jpeg")
 #: wire key of a compressed-frame payload (base64 JPEG bytes) — the
 #: compressed sibling of ``connector.encode_frame``'s ``__frame__``.
 JPEG_KEY = "__jpeg__"
-
-
-def resolve_ingest_mode(ingest_mode: Optional[str],
-                        transfer_uint8: bool = False,
-                        warn: bool = True) -> str:
-    """CLI mode resolution, including the ``--transfer-uint8`` deprecation
-    alias: the old flag routes through the new uint8 ingest path (pinned
-    staging ring + fused on-device cast), so its 118 ms p99 pathology is
-    untriggerable. An explicit ``--ingest-mode`` always wins."""
-    if transfer_uint8:
-        if warn:
-            warnings.warn(
-                "--transfer-uint8 is deprecated and will be removed next "
-                "release; it now aliases --ingest-mode uint8 (the pinned "
-                "staging-ring upload path)", DeprecationWarning,
-                stacklevel=2)
-        if ingest_mode is None:
-            return "uint8"
-    mode = ingest_mode or "f32"
-    if mode not in INGEST_MODES:
-        raise ValueError(f"unknown ingest mode {mode!r} "
-                         f"(valid: {INGEST_MODES})")
-    return mode
 
 
 def encode_jpeg_message(jpeg_bytes: bytes) -> Dict[str, Any]:

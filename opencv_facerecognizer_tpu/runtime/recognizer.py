@@ -20,10 +20,7 @@ finding):
   stays bounded by the per-batch deadline) and runs the publish path.
   Dispatch, D2H, and publish overlap; ``inflight_depth`` slots actually
   pipeline. What the overlap buys on the locally attached chip: not
-  measured. The pre-worker inline path survives as
-  ``readback_worker=False`` (the fallback non-threaded mode) with its
-  two poll sleeps promoted to the named knobs ``readback_poll_s`` /
-  ``drain_poll_s``.
+  measured.
 - **Feed the chip, then settle.** The one readback the loop does make
   is the stage-1 gate's scores, and everything the loop does between a
   step's end and the next step's enqueue is time the chip sits out. So
@@ -152,16 +149,16 @@ STATUS_TOPIC = "ocvfacerec/status"
 LINK_PING_TOPIC = "ocvfacerec/link/ping"
 LINK_PONG_TOPIC = "ocvfacerec/link/pong"
 
-#: Fallback-path readback poll: with ``readback_worker=False`` the inline
-#: drain waits for an over-depth/forced head batch by sleeping this long
-#: between ``is_ready`` checks (the threaded worker never polls a healthy
-#: readback — it blocks on the array). Also the worker's bounded-poll
-#: interval for a proxy that refuses to block (injected stuck readback).
-FALLBACK_READBACK_POLL_S = 0.005
-#: Completion-wait tick: ``drain()``'s condition re-check interval, and the
-#: upper bound between liveness re-checks of the worker's condition waits.
-#: Only the fallback non-threaded path actually sleeps this blindly.
-FALLBACK_DRAIN_POLL_S = 0.05
+#: ``_await_ready``'s bounded ``is_ready`` poll interval, for a readback
+#: that refuses to block (a proxy whose ``block_until_ready`` raises, such
+#: as an injected stuck readback). The worker never polls a healthy
+#: readback: it blocks on the array.
+READY_POLL_S = 0.005
+#: Liveness tick of the condition waits: ``drain()``'s re-check interval,
+#: and the upper bound between re-checks of ``_running`` / the crash flag
+#: in the loop's slot wait and the worker's work wait. Each of them is
+#: woken by ``notify_all`` when there is something to see.
+LIVENESS_TICK_S = 0.05
 #: Dispatch bucket ladder (capped at ``batch_size``, filtered to the mesh's
 #: dp divisibility): a partial batch is sliced to the smallest bucket >= its
 #: real frame count, so light traffic pays small-batch compute without ever
@@ -331,16 +328,6 @@ class RecognizerService:
         # pipeline on host devices) so a dead accelerator degrades the
         # job instead of wedging it.
         cpu_fallback: Optional[Callable[["RecognizerService"], None]] = None,
-        # False selects the pre-worker inline drain (poll-based) path: the
-        # serving loop itself materializes readbacks between dispatches,
-        # sleeping on the two named knobs below. Kept as the fallback for
-        # backends/hosts where a second Python thread is unwanted, and as
-        # the measurable "before" of bench_serving.py's comparison.
-        readback_worker: bool = True,
-        # Fallback-path poll knobs (module docstring; exposed as
-        # ``ocvf-recognize --readback-poll-ms / --drain-poll-ms``).
-        readback_poll_s: float = FALLBACK_READBACK_POLL_S,
-        drain_poll_s: float = FALLBACK_DRAIN_POLL_S,
         # Dispatch bucket ladder (None/() disables slicing: every batch
         # dispatches at the full padded batch_size, the old behavior).
         bucket_sizes: Optional[Sequence[int]] = DEFAULT_BUCKET_SIZES,
@@ -436,9 +423,6 @@ class RecognizerService:
         self._faults = fault_injector
         self._backend_probe_fn = backend_probe_fn
         self._cpu_fallback = cpu_fallback
-        self._use_worker = bool(readback_worker)
-        self._readback_poll_s = float(readback_poll_s)
-        self._drain_poll_s = float(drain_poll_s)
         if frame_shape is None:
             raise ValueError("frame_shape (H, W) is required (static device shapes)")
         self.admission = admission
@@ -1510,12 +1494,11 @@ class RecognizerService:
             dur = getattr(self.state, "durability", None)
             if dur is not None:
                 dur.start()
-        if self._use_worker:
-            self._blocker = _ReadbackBlocker()
-            self._worker = threading.Thread(target=self._readback_thread,
-                                            daemon=True,
-                                            name="ocvf-readback-worker")
-            self._worker.start()
+        self._blocker = _ReadbackBlocker()
+        self._worker = threading.Thread(target=self._readback_thread,
+                                        daemon=True,
+                                        name="ocvf-readback-worker")
+        self._worker.start()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="ocvf-serve-loop")
         self._thread.start()
@@ -1570,7 +1553,7 @@ class RecognizerService:
                         and self.batcher.pending == 0
                         and self.batcher.delivered_batches == self._completed_batches):
                     return True
-                self._inflight_cv.wait(timeout=self._drain_poll_s)
+                self._inflight_cv.wait(timeout=LIVENESS_TICK_S)
         return False
 
     def stop(self) -> None:
@@ -1597,12 +1580,6 @@ class RecognizerService:
             # on a deadline and will finish its own drain.
             worker.join(timeout=5.0)
             self._worker = None
-        if (not self._use_worker
-                and (thread is None or not thread.is_alive())):
-            # Fallback path: final materialize only once the loop thread is
-            # truly gone — two threads force-draining the same deque could
-            # pair one batch's results with another's metadata.
-            self._drain(force=True)
         if self._faults is not None and getattr(
                 self.pipeline, "fault_injector", None) is self._faults:
             self.pipeline.fault_injector = None
@@ -1638,8 +1615,7 @@ class RecognizerService:
             return False
         if self._thread is not None and not self._thread.is_alive():
             return True
-        return (self._use_worker and self._worker is not None
-                and not self._worker.is_alive())
+        return self._worker is not None and not self._worker.is_alive()
 
     def restart_loop(self) -> None:
         """Restart crashed serving-side threads (supervisor path): whichever
@@ -1650,7 +1626,7 @@ class RecognizerService:
         if not self._running or self._thread is None:
             return
         serve_dead = not self._thread.is_alive()
-        worker_dead = (self._use_worker and self._worker is not None
+        worker_dead = (self._worker is not None
                        and not self._worker.is_alive())
         if not serve_dead and not worker_dead:
             return  # not actually crashed
@@ -1749,16 +1725,12 @@ class RecognizerService:
                     # ended mid-aggregation-window.
                     self._note_queue_wait(0.0)
                     self._flush_rejections()
-                    if not self._use_worker:
-                        self._drain()
                     continue
                 held = self._open_batch(batch, t_pop, t_popped)
             self._serve_one(held)
             t_end = time.monotonic()
             self._flush_loop_busy(t_end - t_iter)
             t_iter = t_end
-        if not self._use_worker:
-            self._drain(force=True)
 
     def _open_batch(self, batch, t_pop: float, t_popped: float) -> _Held:
         """A popped batch's way through the loop up to its gate's
@@ -2128,23 +2100,20 @@ class RecognizerService:
         deferred = self._settle_early(held)
         if deferred:
             self.metrics.incr(mn.EARLY_EXITS_DEFERRED, deferred)
-        if self._use_worker:
-            # Backpressure: beyond inflight_depth undrained batches, wait
-            # for the readback worker to free a slot (it notifies the cv on
-            # every pop) before popping more frames. The timeout only
-            # bounds liveness re-checks (stop), never paces a healthy
-            # pipeline. Deliberately NOT escaped on a worker crash: parking
-            # here keeps the in-flight queue bounded until the supervisor
-            # respawns the worker (or stop() clears _running).
-            # The leaf closes (and its span is emitted) once the
-            # condition's lock is released.
-            with self._leaf("inflight_wait", batch_tid):
-                with self._inflight_cv:
-                    while (self._running
-                           and len(self._inflight) > self.inflight_depth):
-                        self._inflight_cv.wait(timeout=self._drain_poll_s)
-        else:
-            self._drain()
+        # Backpressure: beyond inflight_depth undrained batches, wait for
+        # the readback worker to free a slot (it notifies the cv on every
+        # pop) before popping more frames. The timeout only bounds
+        # liveness re-checks (stop), never paces a healthy pipeline.
+        # Deliberately NOT escaped on a worker crash: parking here keeps
+        # the in-flight queue bounded until the supervisor respawns the
+        # worker (or stop() clears _running).
+        # The leaf closes (and its span is emitted) once the condition's
+        # lock is released.
+        with self._leaf("inflight_wait", batch_tid):
+            with self._inflight_cv:
+                while (self._running
+                       and len(self._inflight) > self.inflight_depth):
+                    self._inflight_cv.wait(timeout=LIVENESS_TICK_S)
 
     def _mark_completed(self, n: int = 1) -> None:
         with self._inflight_cv:
@@ -2210,14 +2179,10 @@ class RecognizerService:
             return packed
 
     def _backoff_wait(self, seconds: float) -> None:
-        """Sleep in small slices, bailing promptly on stop(). On the
-        fallback path this also drains in-flight readbacks (a retry storm
-        must not let completed batches rot past their result consumers);
-        with the worker the drain happens concurrently anyway."""
+        """Sleep in small slices, bailing promptly on stop(). The readback
+        worker keeps draining in-flight batches meanwhile."""
         deadline = time.monotonic() + seconds
         while self._running and time.monotonic() < deadline:
-            if not self._use_worker:
-                self._drain()
             time.sleep(min(0.01, max(0.0, deadline - time.monotonic())))
 
     # ---- degraded mode ----
@@ -2336,7 +2301,7 @@ class RecognizerService:
         except Exception:  # ocvf-lint: disable=swallowed-exception -- deliberate defer: reporting ready makes materialize re-raise on the classifying path, where _complete_head dead-letters with full accounting
             return True
 
-    # ---- the readback worker (threaded path) ----
+    # ---- the readback worker ----
 
     def _readback_thread(self) -> None:
         try:
@@ -2357,7 +2322,7 @@ class RecognizerService:
         while True:
             with self._inflight_cv:
                 while self._running and not self._inflight:
-                    self._inflight_cv.wait(timeout=self._drain_poll_s)
+                    self._inflight_cv.wait(timeout=LIVENESS_TICK_S)
                 if not self._inflight:
                     if not self._running:
                         return
@@ -2425,74 +2390,29 @@ class RecognizerService:
         while self._running and time.monotonic() < deadline:
             if self._is_ready(packed):
                 return True
-            time.sleep(self._readback_poll_s)
+            time.sleep(READY_POLL_S)
         return self._is_ready(packed)
-
-    # ---- the inline drain (fallback non-threaded path) ----
-
-    def _drain(self, force: bool = False) -> None:
-        """Materialize finished batches inline (``readback_worker=False``).
-        A not-ready head batch past its readback deadline is dead-lettered;
-        when over depth (or forced) the wait for the head is a bounded
-        ``is_ready`` poll (tick: ``readback_poll_s``) capped by that same
-        deadline — never an unbounded blocking readback a hang-mode outage
-        could wedge."""
-        while self._inflight:
-            packed, frames, metas, count, enqueue_ts, t0, t_disp, deadline, \
-                trace_ids, batch_tid, priorities, gallery_ver \
-                = self._inflight[0]
-            ready = self._is_ready(packed)
-            if not ready:
-                if time.monotonic() >= deadline:
-                    # No recycle: the incomplete round-trip may still hold
-                    # an async read on this staging buffer (see the worker
-                    # path's dead-letter note). Forfeit so a ring heals.
-                    self._pop_inflight_head()
-                    self.batcher.forfeit(frames)
-                    self._dead_letter(count, metas, enqueue_ts, trace_ids,
-                                      batch_tid)
-                    continue
-                if not (force or len(self._inflight) > self.inflight_depth):
-                    break
-                # Over depth / forced: poll until ready or deadline. The
-                # poll IS the readback wait — it lands in ready_wait below.
-                while not ready and time.monotonic() < deadline:
-                    time.sleep(self._readback_poll_s)
-                    ready = self._is_ready(packed)
-                if not ready:
-                    self._pop_inflight_head()
-                    self.batcher.forfeit(frames)  # no recycle; ring heals
-                    self._dead_letter(count, metas, enqueue_ts, trace_ids,
-                                      batch_tid)
-                    continue
-            self._pop_inflight_head()
-            self._complete_head(packed, frames, metas, count, enqueue_ts,
-                                t0, t_disp, trace_ids, batch_tid, priorities,
-                                gallery_ver)
 
     def _complete_head(self, packed, frames, metas, count, enqueue_ts,
                        t0, t_disp, trace_ids=(), batch_tid=0,
                        priorities=(), gallery_ver=None) -> None:
         """Materialize + publish one POPPED batch and settle its accounting
-        — the shared tail of the readback worker and the fallback drain
-        (the two paths must stay behaviorally identical apart from
-        scheduling; bench_serving's overlap_comparison relies on it).
+        (the readback worker's tail).
 
-        Three invariants live here, once:
+        Three invariants live here:
         - a materialize failure (an outage error riding the result array)
           dead-letters the batch (``readback_errors``) instead of crashing
           the thread — the readback-side mirror of the dispatch retry
           classification;
-        - ``ready_wait`` ends AFTER ``np.asarray``: on the blocking
-          (over-depth/forced) fallback path the conversion IS the readback
-          (device compute + D2H land in this term), and it must never
-          leak into 'publish';
+        - ``ready_wait`` ends AFTER ``np.asarray``: whatever of the
+          readback the conversion still does lands in this term and must
+          never leak into 'publish';
         - a crash escaping the publish path still settles
           ``_completed_batches`` first, so drain() stays solvable after
           the supervisor restarts the thread.
         """
         try:
-            arr = np.asarray(packed)  # ocvf-lint: boundary=host-sync -- THE one per-batch materialize (PR 2's packed single-readback design); runs on the readback worker / post-is_ready drain, never ahead of readiness
+            arr = np.asarray(packed)  # ocvf-lint: boundary=host-sync -- THE one per-batch materialize (PR 2's packed single-readback design); runs on the readback worker, never ahead of readiness
         except Exception:  # noqa: BLE001 — outage error carried by the array
             logging.getLogger(__name__).exception(
                 "readback materialize failed")
@@ -2542,11 +2462,6 @@ class RecognizerService:
         # realized downstream time (pop -> published).
         self.batcher.report_service_time(now - t0)
         self.batcher.recycle(frames)
-
-    def _pop_inflight_head(self) -> None:
-        with self._inflight_cv:
-            self._inflight.popleft()
-            self._inflight_cv.notify_all()
 
     def _publish(self, packed, frames, metas, count, trace_ids=(),
                  batch_tid=0, gallery_ver=None, publish_span=0) -> None:

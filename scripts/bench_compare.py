@@ -133,7 +133,7 @@ METRICS: Dict[str, Tuple[Callable[[dict], Any], str, float, float]] = {
         .get("swap_window_completed_ratio"),
         "ratio_min", 0.80, 0.0),
     # Ingest pipeline (ISSUE 12): the staging-ring uint8 H2D tail at the
-    # b32 rung (the old --transfer-uint8 path's 118 ms p99 pathology must
+    # b32 rung (the first uint8 path's 118 ms p99 pathology must
     # never creep back — ratio + absolute slack, same reasoning as the
     # other microsecond-scale latency gates) and the end-to-end
     # completed-frames uplift of uint8 mode over the f32 baseline against

@@ -12,6 +12,140 @@ from opencv_facerecognizer_tpu.apps import train as train_app
 from opencv_facerecognizer_tpu.utils.dataset import make_synthetic_faces, make_synthetic_scenes
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _options(parser):
+    """``ocvf-recognize``'s own options (``-h`` is argparse's)."""
+    return [a for a in parser._actions
+            if a.option_strings and a.dest != "help"]
+
+
+#: the flags PR 32 deleted, each with an abbreviation no living flag owns
+REMOVED_FLAGS = {
+    "--no-readback-worker": "--no-readback",
+    "--readback-poll-ms": "--readback-p",
+    "--drain-poll-ms": "--drain",
+    "--fused-embedder": "--fused",
+    "--transfer-uint8": "--transfer",
+}
+
+
+@pytest.mark.parametrize("flag", list(REMOVED_FLAGS))
+def test_removed_flags_are_refused(flag, capsys):
+    """The five flags PR 32 deleted are argument errors, abbreviated too:
+    argparse's prefix matching must not land one on a neighbour
+    (``--readback-deadline``, ``--no-cascade``, ``--no-track-cache``)."""
+    required = ["--model", "m", "--detector", "d", "--gallery", "g"]
+    for spelling in (flag, REMOVED_FLAGS[flag]):
+        argv = required + [spelling] + (["5"] if flag.endswith("-ms") else [])
+        with pytest.raises(SystemExit) as refused:
+            recognize_app.build_parser().parse_args(argv)
+        assert refused.value.code == 2, spelling
+        assert "unrecognized arguments" in capsys.readouterr().err, spelling
+
+
+@pytest.mark.parametrize("what, pin", [
+    ("parser", 76), ("service", 30), ("pipeline", 8)],
+    ids=["parser", "service", "pipeline"])
+def test_option_census(what, pin):
+    """ROADMAP Design 7, as a test: how many values a user can set."""
+    import inspect
+
+    from opencv_facerecognizer_tpu.parallel.pipeline import RecognitionPipeline
+    from opencv_facerecognizer_tpu.runtime.recognizer import RecognizerService
+
+    if what == "parser":
+        count = len(_options(recognize_app.build_parser()))
+    else:
+        init = (RecognizerService if what == "service"
+                else RecognitionPipeline).__init__
+        count = len(inspect.signature(init).parameters) - 1  # self
+    assert count <= pin, (
+        f"{what}: {count} options, pinned at {pin}: lower the pin when you "
+        "delete one; raising it needs two callers that exist, see "
+        "simplicity-review Options")
+
+
+def _is_default(default, stated):
+    """A parser's or constructor's default against the value a
+    configuration file writes for it ("none", a number, a choice)."""
+    if stated == "none" or default is None:
+        return stated == "none" and default is None
+    try:
+        return float(default) == float(stated)
+    except (TypeError, ValueError):
+        return str(default) == stated
+
+
+@pytest.mark.parametrize("name", ["watchlist8m", "watchlist4m-r50"])
+def test_benchmark_configurations_parse(name):
+    """What a benchmark configuration says of ``ocvf-recognize`` holds for
+    the parser in the tree: today only a run on the chip finds a
+    configuration that names a deleted flag or a default that moved."""
+    import inspect
+    import re
+
+    from opencv_facerecognizer_tpu.runtime.batcher import FrameBatcher
+    from opencv_facerecognizer_tpu.runtime.recognizer import RecognizerService
+
+    with open(os.path.join(REPO, "benchmark", "configs", name + ".json")) as fh:
+        config = json.load(fh)
+    parser = recognize_app.build_parser()
+    by_flag = {flag: a for a in _options(parser) for flag in a.option_strings}
+
+    # the way benchmark/stacks/recognize.py::recognize_argv flattens them
+    argv = ["--model", "m", "--detector", "d", "--gallery", "g"]
+    for flag, value in config["recognize_args"].items():
+        assert not isinstance(value, bool), flag
+        argv += [flag, *[str(v) for v in (
+            value if isinstance(value, list) else [value])]]
+    args = parser.parse_args(argv)
+    for flag, value in config["recognize_args"].items():
+        assert flag in by_flag, flag
+        got = getattr(args, by_flag[flag].dest)
+        assert (list(got) if isinstance(value, list) else got) == value, flag
+
+    for key in config["departures_from_ocvf_recognize_defaults"]:
+        flag = key.split()[0]
+        assert flag in by_flag, key
+        stated = re.search(r"\(default ([^,)]+)", key)
+        if stated:
+            assert _is_default(by_flag[flag].default, stated.group(1)), key
+
+    # "flush-ms 30, target-latency-ms none, max_pending 256 (...), ...":
+    # a hyphenated name is a flag, an underscored one a constructor
+    # parameter of the service or its batcher; the rest is prose
+    params = {**inspect.signature(FrameBatcher.__init__).parameters,
+              **inspect.signature(RecognizerService.__init__).parameters}
+    checked = 0
+    for item in config["kept_at_default"].split(". ")[0].split(", "):
+        word, value = item.split()[:2]
+        if "-" in word:
+            assert "--" + word in by_flag, item
+            assert _is_default(by_flag["--" + word].default, value), item
+        elif "_" in word:
+            assert word in params, item
+            assert _is_default(params[word].default, value), item
+        else:
+            continue
+        checked += 1
+    assert checked >= 6
+
+    # The committed nets are named by a hash over the training recipe AND
+    # the bytes of the program's training sources (models/embedder.py
+    # among them): an edit there orphans them, and every run then trains
+    # its nets in set-up (57 s on the chip; PERF.md section 5).
+    from benchmark.stacks import recognize as stack
+
+    recipe = config["nets"].get("gate_and_detector", config["nets"])
+    found, tag = stack.find_nets({**config, "nets": recipe})
+    assert found is not None and found.startswith(
+        os.path.join(REPO, "benchmark", "nets")), (
+        f"no committed nets under benchmark/nets/{tag}: one of "
+        f"{stack.RECIPE_SOURCES} changed")
+
+
 def _write_dataset(root, images, labels, names):
     import cv2
 

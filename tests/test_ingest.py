@@ -2,16 +2,14 @@
 steady-state allocations, exhaustion backpressure), uint8 end-to-end
 staging with the recompile watchdog green, compressed-frame intake through
 the off-thread decode pool (corrupt payloads dead-letter with exact ledger
-settlement), the ``decode: slow``/``decode: corrupt`` chaos pair, the
-``--transfer-uint8`` deprecation alias, and the bench_compare tracking of
-the ingest gate's numbers.
+settlement), the ``decode: slow``/``decode: corrupt`` chaos pair, and the
+bench_compare tracking of the ingest gate's numbers.
 
 Everything runs over ``runtime.fakes.InstantPipeline`` — the ingest layer
 is host-side control flow; nothing here needs hardware.
 """
 
 import time
-import warnings
 
 import numpy as np
 import pytest
@@ -24,7 +22,6 @@ from opencv_facerecognizer_tpu.runtime import (
     RecognizerService,
     ResiliencePolicy,
     StagingRing,
-    resolve_ingest_mode,
 )
 from opencv_facerecognizer_tpu.runtime import ingest as ingest_mod
 from opencv_facerecognizer_tpu.runtime.fakes import (
@@ -478,35 +475,6 @@ def test_ring_exhaustion_floods_backpressure_through_admission():
     _assert_settled(service)
 
 
-# ---------- --transfer-uint8 deprecation alias ----------
-
-
-def test_transfer_uint8_flag_aliases_to_uint8_ingest_mode():
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        assert resolve_ingest_mode(None, transfer_uint8=True) == "uint8"
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-    # An explicit --ingest-mode always wins over the legacy alias.
-    assert resolve_ingest_mode("jpeg", transfer_uint8=True,
-                               warn=False) == "jpeg"
-    assert resolve_ingest_mode(None, transfer_uint8=False) == "f32"
-    with pytest.raises(ValueError):
-        resolve_ingest_mode("bf16")
-    # The CLI wires the alias through build_parser -> IngestConfig.
-    from opencv_facerecognizer_tpu.apps.recognize import build_parser
-
-    args = build_parser().parse_args(
-        ["--model", "m", "--detector", "d", "--gallery", "g",
-         "--transfer-uint8"])
-    assert args.ingest_mode is None and args.transfer_uint8
-    mode = resolve_ingest_mode(args.ingest_mode, args.transfer_uint8,
-                               warn=False)
-    cfg = IngestConfig(mode=mode, ring_depth=args.ingest_ring_depth or None,
-                       decode_workers=args.ingest_decode_workers)
-    assert cfg.transfer_dtype == np.uint8
-    assert cfg.ring_depth is None  # CLI default 0 = auto-size
-
-
 def test_ring_depth_auto_sizes_to_cover_pipeline_overlap():
     """The default (auto) ring depth must never cap overlap below the
     in-flight window: every overlapped batch holds a buffer, plus the
@@ -541,31 +509,6 @@ def test_exhaustion_counts_episodes_not_polls():
     for _ in range(10):  # the parked consumer's re-checks: quiet
         assert ring.acquire(4, quiet=True) is None
     assert metrics.counter(mn.INGEST_STAGING_EXHAUSTED) == 1
-
-
-def test_transfer_uint8_alias_routes_through_the_staging_ring():
-    """The regression pin: the old flag's path IS the new path — uint8
-    staging rides the pre-allocated ring (the fresh-allocation staging
-    behind the 118 ms p99 is structurally unreachable), and the batcher
-    never allocates a batch array once the ring is warm."""
-    cfg = IngestConfig(mode=resolve_ingest_mode(None, transfer_uint8=True,
-                                                warn=False))
-    metrics = Metrics()
-    pipeline, service, connector = _service(metrics=metrics, ingest=cfg)
-    assert service.batcher._ring is service.ingest.staging
-    assert service.batcher.dtype == np.uint8
-    service.start(warmup=False)
-    try:
-        for i in range(24):
-            connector.inject(FRAME_TOPIC, {"frame": _frame(),
-                                           "meta": {"seq": i}})
-        assert service.drain(timeout=20.0)
-    finally:
-        service.stop()
-    c = metrics.counters()
-    assert c[mn.FRAMES_COMPLETED] == 24
-    assert c[mn.INGEST_STAGING_ALLOCS] == service.ingest.staging.preallocated
-    _assert_settled(service)
 
 
 # ---------- registry / wiring / bench plumbing ----------
@@ -625,8 +568,9 @@ def test_bench_compare_tracks_ingest_metrics():
 @needs_jpeg
 def test_ingest_smoke_section_shape():
     """A miniature run of the smoke's ingest section: structure + the
-    load-bearing verdicts exist (the full-size gate runs in
-    ``bench_serving.py --smoke``; this keeps tier-1 fast and unflaky)."""
+    verdicts that are counts (the timed gates, uplift among them, run
+    full-size in ``bench_serving.py --smoke``: a CPU's timing under six
+    test workers decides nothing here)."""
     import bench_serving
 
     out = bench_serving.run_ingest_smoke(
@@ -635,13 +579,11 @@ def test_ingest_smoke_section_shape():
         uplift_h2d_gb_s=0.005, jpeg_frames=8)
     for rung in ("4", "8"):
         row = out["h2d"][rung]
-        for arm in ("f32_fresh", "uint8_unpinned", "uint8_ring"):
-            assert row[arm]["p50_ms"] > 0
+        assert set(row) >= {"f32_fresh", "uint8_unpinned", "uint8_ring"}
         assert row["f32_fresh"]["bytes_per_frame"] == (
             4 * row["uint8_ring"]["bytes_per_frame"])
     b8 = out["uplift"]["b8"]
     assert b8["uint8"]["completed"] > 0 and b8["f32"]["completed"] > 0
-    assert b8["uplift"] is not None and b8["uplift"] > 1.0
     assert b8["zero_steady_state_allocs"]
     assert out["jpeg"]["completed"] == out["jpeg"]["offered"] == 8
     assert isinstance(out["ingest_ok"], bool)

@@ -25,8 +25,7 @@ from opencv_facerecognizer_tpu.models.embedder import CNNEmbedding, normalize_fa
 from opencv_facerecognizer_tpu.models.model import PredictableModel
 from opencv_facerecognizer_tpu.ops.distance import CosineDistance
 from opencv_facerecognizer_tpu.ops.pallas_match import streaming_match_topk
-from opencv_facerecognizer_tpu.parallel import ShardedGallery, make_mesh
-from opencv_facerecognizer_tpu.parallel.pipeline import RecognitionPipeline
+from opencv_facerecognizer_tpu.parallel import make_mesh
 from opencv_facerecognizer_tpu.runtime import FakeConnector, RecognizerService
 from opencv_facerecognizer_tpu.runtime.recognizer import FRAME_TOPIC, RESULT_TOPIC
 from opencv_facerecognizer_tpu.utils import serialization
@@ -197,17 +196,6 @@ def test_load_stack_serves_either_feature_class(artifacts, model, net_class, dim
     # boxes, valid, label, similarity (an empty slot's score is -inf)
     assert packed.shape == (2, 2, 8) and np.isfinite(packed[..., [0, 1, 2, 3, 5, 6, 7]]).all()
     assert pipeline.last_dispatch_info["embed_slots"] == 2 * 2
-
-
-def test_fused_embedder_with_an_iresnet_is_an_argument_error(artifacts, small):
-    with pytest.raises(SystemExit, match="fused-embedder covers the separable"):
-        recognize_app._load_stack(_args(artifacts, "embedder.ckpt", "--fused-embedder"))
-    feature, _ = small
-    gallery = ShardedGallery(capacity=8, dim=32, mesh=make_mesh(devices=jax.devices()[:1]))
-    with pytest.raises(ValueError, match="FaceEmbedNet only"):
-        RecognitionPipeline(CNNFaceDetector(max_faces=2), feature.net,
-                            feature._params["net"], gallery, face_size=FACE,
-                            fused_embedder=True)
 
 
 def test_a_checkpoint_of_neither_class_is_refused(artifacts, tmp_path):

@@ -545,6 +545,34 @@ def test_baseline_ratchet_enforced_at_head():
     assert proc.returncode == 0, proc.stdout + proc.stderr
 
 
+def test_wiring_names_live_code():
+    """ROADMAP Design 13, "every deletion also deletes its hints": each
+    class ``wiring.ATTR_HINTS`` dispatches to is defined in the package,
+    and each module a ``*_SUFFIXES`` scope names exists. A stale entry
+    makes a checker silently skip what it was pointed at."""
+    import ast
+
+    from tools.ocvf_lint import wiring
+
+    package = os.path.join(REPO_ROOT, "opencv_facerecognizer_tpu")
+    defined = set()
+    for folder, _dirs, files in os.walk(package):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(folder, name)) as fh:
+                    defined |= {node.name for node in ast.walk(ast.parse(fh.read()))
+                                if isinstance(node, ast.ClassDef)}
+    stale = {attr: cls for attr, cls in wiring.ATTR_HINTS.items()
+             if cls not in defined}
+    assert not stale, stale
+    scopes = [name for name in dir(wiring) if name.endswith("_SUFFIXES")]
+    assert "HOT_PATH_SUFFIXES" in scopes
+    missing = [(scope, suffix) for scope in scopes
+               for suffix in getattr(wiring, scope)
+               if not os.path.isfile(os.path.join(package, suffix))]
+    assert not missing, missing
+
+
 def test_real_lock_graph_is_nonempty_and_acyclic():
     """The static inter-module lock graph over the real runtime must keep
     seeing the known edges (StateLifecycle -> WAL/journal/gallery/metrics)

@@ -11,6 +11,7 @@ from opencv_facerecognizer_tpu.models.embedder import (
     normalize_faces,
     train_embedder,
 )
+from opencv_facerecognizer_tpu.models.iresnet import IResNetEmbedding
 from opencv_facerecognizer_tpu.ops import image as image_ops
 from opencv_facerecognizer_tpu.parallel import ShardedGallery, make_mesh
 from opencv_facerecognizer_tpu.parallel.pipeline import RecognitionPipeline
@@ -46,6 +47,22 @@ def pipeline_setup():
     return det, net, params, scenes, boxes, counts, crops, labels
 
 
+@pytest.fixture(scope="module")
+def embed_nets(pipeline_setup):
+    """(net, its parameters) of either feature class the one embed stage
+    serves, both 32-d on 32x32 crops: the trained separable net, and the
+    tiny IResNet of tests/test_iresnet.py with seeded, calibrated
+    parameters."""
+    _det, net, params, *_rest, crops, _labels = pipeline_setup
+    feature = IResNetEmbedding(input_size=FACE, seed=5, embed_dim=32,
+                               stem_features=8,
+                               stage_features=(8, 16, 32, 64),
+                               stage_blocks=(1, 1, 2, 1))
+    feature.compute(np.asarray(crops, np.float32))
+    return {"separable": (net, params["net"]),
+            "iresnet": (feature.net, feature._params["net"])}
+
+
 @pytest.mark.parametrize("dp,tp", [(2, 4), (1, 8)])
 def test_fused_pipeline_runs_sharded(pipeline_setup, dp, tp):
     det, net, params, scenes, boxes, counts, crops, labels = pipeline_setup
@@ -76,17 +93,21 @@ def test_fused_pipeline_runs_sharded(pipeline_setup, dp, tp):
     assert np.all(sims <= 1.0 + 1e-3)
 
 
-def test_pipeline_uint8_transfer_matches_f32(pipeline_setup):
+@pytest.mark.parametrize("feature_class", ["separable", "iresnet"])
+def test_pipeline_uint8_transfer_matches_f32(pipeline_setup, embed_nets,
+                                             feature_class):
     """The uint8 fast-transfer path (frames ride H2D as uint8, cast to f32
     in-graph) must produce the same result as sending the same pixel
-    values as f32 — it is a transfer-format choice, not a model change."""
-    det, net, params, scenes, boxes, counts, crops, labels = pipeline_setup
+    values as f32 — it is a transfer-format choice, not a model change,
+    for either feature class the embed stage serves."""
+    det, _net, _params, scenes, boxes, counts, crops, labels = pipeline_setup
+    net, net_params = embed_nets[feature_class]
     mesh = make_mesh(tp=8)
     gallery = ShardedGallery(capacity=64, dim=32, mesh=mesh)
-    emb = np.asarray(net.apply({"params": params["net"]},
+    emb = np.asarray(net.apply({"params": net_params},
                                normalize_faces(crops, FACE)))
     gallery.add(emb, labels)
-    pipe = RecognitionPipeline(det, net, params["net"], gallery,
+    pipe = RecognitionPipeline(det, net, net_params, gallery,
                                face_size=FACE, top_k=1)
     u8 = np.clip(scenes[:8], 0, 255).astype(np.uint8)
     r_u8 = pipe.recognize_batch(u8)
@@ -103,51 +124,17 @@ def test_pipeline_uint8_transfer_matches_f32(pipeline_setup):
     assert len(pipe._step_cache) == 2
 
 
-def test_pipeline_batch_caching(pipeline_setup):
-    det, net, params, scenes, *_ = pipeline_setup
+@pytest.mark.parametrize("feature_class", ["separable", "iresnet"])
+def test_pipeline_batch_caching(pipeline_setup, embed_nets, feature_class):
+    det, _net, _params, scenes, *_ = pipeline_setup
+    net, net_params = embed_nets[feature_class]
     mesh = make_mesh(tp=8)
     gallery = ShardedGallery(capacity=16, dim=32, mesh=mesh)
     gallery.add(np.eye(16, 32, dtype=np.float32), np.arange(16, dtype=np.int32))
-    pipe = RecognitionPipeline(det, net, params["net"], gallery, face_size=FACE)
+    pipe = RecognitionPipeline(det, net, net_params, gallery, face_size=FACE)
     r1 = pipe.recognize_batch(scenes[:8])
     assert len(pipe._step_cache) == 1
     r2 = pipe.recognize_batch(scenes[8:16])
     assert len(pipe._step_cache) == 1  # same shape -> no recompile
     pipe.recognize_batch(scenes[:16])
     assert len(pipe._step_cache) == 2
-
-
-def test_pipeline_fused_embedder_matches_flax(pipeline_setup):
-    """fused_embedder=True swaps the embed stage onto the pallas schedule
-    (interpret mode off-TPU) without changing results — the one-flag flip
-    the on-chip A/B (scripts/bench_sepblock.py) decides."""
-    import jax
-    from jax.sharding import Mesh
-
-    from opencv_facerecognizer_tpu.parallel.mesh import DP_AXIS, TP_AXIS
-
-    det, net, params, scenes, boxes, counts, crops, labels = pipeline_setup
-    # single-device mesh: pallas custom calls don't partition over tp
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
-                (DP_AXIS, TP_AXIS))
-    gallery = ShardedGallery(capacity=64, dim=32, mesh=mesh)
-    emb = np.asarray(net.apply({"params": params["net"]},
-                               normalize_faces(crops, FACE)))
-    gallery.add(emb, labels)
-    outs = {}
-    for fused in (False, True):
-        pipe = RecognitionPipeline(det, net, params["net"], gallery,
-                                   face_size=FACE, top_k=1,
-                                   fused_embedder=fused)
-        outs[fused] = pipe.recognize_batch(scenes[:4])
-    np.testing.assert_array_equal(np.asarray(outs[False].valid),
-                                  np.asarray(outs[True].valid))
-    np.testing.assert_allclose(np.asarray(outs[False].boxes),
-                               np.asarray(outs[True].boxes), atol=1e-4)
-    # embeddings differ only by bf16 rounding -> near-identical sims; label
-    # flips are possible only at exact ties, which the synthetic gallery
-    # doesn't produce
-    np.testing.assert_array_equal(np.asarray(outs[False].labels),
-                                  np.asarray(outs[True].labels))
-    np.testing.assert_allclose(np.asarray(outs[False].similarities),
-                               np.asarray(outs[True].similarities), atol=2e-2)

@@ -292,29 +292,6 @@ def test_service_continuous_batching_stats_and_zero_drops():
     assert c["frames_processed"] == n
 
 
-def test_service_fallback_inline_drain_still_serves():
-    """readback_worker=False selects the pre-worker inline poll path (the
-    named fallback knobs) — it must still serve end to end."""
-    _, service, connector = _instant_service(
-        batch_size=4, readback_worker=False, readback_poll_s=0.001,
-        drain_poll_s=0.01)
-    service.start(warmup=False)
-    try:
-        for i in range(8):
-            connector.inject(FRAME_TOPIC,
-                             {"frame": np.zeros((16, 16), np.float32),
-                              "meta": {"i": i}})
-        deadline = time.monotonic() + 10
-        while (len(connector.messages(RESULT_TOPIC)) < 8
-               and time.monotonic() < deadline):
-            time.sleep(0.01)
-    finally:
-        assert service.drain(timeout=10.0)
-        service.stop()
-    assert len(connector.messages(RESULT_TOPIC)) == 8
-    assert service._worker is None  # no readback worker thread was spawned
-
-
 # ---------- connectors ----------
 
 

@@ -21,8 +21,10 @@ from opencv_facerecognizer_tpu.runtime.fakes import InstantPipeline
 from opencv_facerecognizer_tpu.runtime.ingest import IngestConfig
 from opencv_facerecognizer_tpu.runtime.recognizer import (
     FRAME_TOPIC,
+    READY_POLL_S,
     RESULT_TOPIC,
     RecognizerService,
+    _ReadbackBlocker,
 )
 from opencv_facerecognizer_tpu.runtime.resilience import ResiliencePolicy
 from opencv_facerecognizer_tpu.utils import metric_names as mn
@@ -524,11 +526,10 @@ def test_brownout_trim_of_a_looked_ahead_batch_sheds_each_frame_once():
     _assert_settled_once(service, connector, [0, 1, 4, 5])
 
 
-@pytest.mark.parametrize("readback_worker", [True, False])
-def test_drain_balances_with_batches_ahead_and_early_exits(readback_worker):
+def test_drain_balances_with_batches_ahead_and_early_exits():
     frames = [(seq, seq % 3 != 1) for seq in range(20)]  # 5 closed batches
     service, _p, connector, metrics, events = _stack(
-        frames, readback_worker=readback_worker, compute_s=0.002)
+        frames, compute_s=0.002)
     service.start(warmup=False)
     try:
         assert service.drain(timeout=10.0)
@@ -567,3 +568,75 @@ def test_a_looked_ahead_batchs_buffer_fits_the_staging_ring():
     assert metrics.counter(mn.BATCHES_GATED_AHEAD) >= 1
     assert metrics.counter(mn.BATCHES_DEAD_LETTERED) == 0
     _assert_settled_once(service, connector, range(32))
+
+
+# ---- the readback worker's bounded wait ------------------------------------
+
+
+class _Readback:
+    """A device array as ``_await_ready`` sees it. ``block`` is what
+    ``block_until_ready`` does: "returns", "hangs" (a wedged chip: until
+    ``release`` is set) or "raises" (a proxy that refuses to block);
+    ``ready_after`` is how many ``is_ready`` polls answer False first
+    (None: never ready). Every poll's instant is kept."""
+
+    def __init__(self, block, ready_after=0):
+        self.block, self.ready_after = block, ready_after
+        self.release = threading.Event()
+        self.polls = []
+
+    def block_until_ready(self):
+        if self.block == "hangs":
+            self.release.wait(30.0)
+        elif self.block == "raises":
+            raise RuntimeError("this readback cannot be blocked on")
+        return self
+
+    def is_ready(self):
+        self.polls.append(time.monotonic())
+        return (self.ready_after is not None
+                and len(self.polls) > self.ready_after)
+
+
+@pytest.mark.parametrize("block, ready_after, ready", [
+    ("returns", 0, True),
+    ("hangs", None, False),
+    ("raises", 4, True),
+    ("raises", None, False),
+], ids=["ready", "hangs", "raises_then_ready", "raises_never_ready"])
+def test_await_ready_outcomes(block, ready_after, ready):
+    service, *_ = _stack([])
+    service.start(warmup=False)
+    packed = _Readback(block, ready_after)
+    window = 0.5
+    try:
+        blocker = service._blocker
+        t0 = time.monotonic()
+        assert service._await_ready(packed, t0 + window) is ready
+        waited = time.monotonic() - t0
+        if block == "hangs":
+            # the deadline won: the wedged helper is abandoned, and the
+            # next batch blocks on a fresh one
+            assert window <= waited < window + 1.0
+            assert not packed.polls
+            assert isinstance(service._blocker, _ReadbackBlocker)
+            assert service._blocker is not blocker
+            assert service._await_ready(_Readback("returns"),
+                                        time.monotonic() + 5.0)
+        else:
+            assert service._blocker is blocker
+        if block == "returns":
+            assert waited < window and not packed.polls
+        if block == "raises":
+            # polled at the constant's interval, until ready or the deadline
+            gaps = np.diff(packed.polls)
+            assert (gaps >= READY_POLL_S * 0.9).all(), gaps
+            if ready:
+                assert len(packed.polls) == ready_after + 1
+                assert waited < window
+            else:
+                assert window <= waited < window + 1.0
+                assert 3 <= len(packed.polls) <= window / READY_POLL_S + 2
+    finally:
+        packed.release.set()
+        service.stop()

@@ -7,9 +7,8 @@ Drives ``bench_serving.run_smoke`` over the fake instant backend
 **zero drops** and keep ``ready_wait`` p50 far below that poll floor — the
 regression tripwire for the event-driven readback design: if anything on
 the serving path starts polling readbacks again, ready_wait snaps to the
-floor and this fails. The legacy-vs-overlapped comparison artifact is
-written by ``python bench_serving.py --smoke`` (BENCH_SERVING_smoke.json);
-this test runs only the overlapped mode to stay fast.
+floor and this fails. ``python bench_serving.py --smoke`` writes the same
+row to BENCH_SERVING_smoke.json.
 """
 
 import importlib.util
@@ -32,7 +31,7 @@ def test_perf_smoke_overlapped_readback_off_the_poll_floor():
     artifact = bench_serving.run_smoke(
         frames_n=FRAMES, rate_hz=200.0, batch_size=BATCH,
         sync_poll_floor_s=POLL_FLOOR_MS / 1e3, compute_s=0.002,
-        modes=("overlapped",), write=False,
+        write=False,
     )
     row = artifact["modes"]["overlapped"]
     # Sustained: every offered frame completed, none dropped, and the loop
