@@ -105,7 +105,8 @@ def describe_environment(cache_dir: str) -> None:
     entries = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
     say(f"compile cache: {cache_dir} ({entries} entries at start)")
     say(f"native loader (g++ build of native/ocvf_loader.cpp): "
-        f"available={native.available()}")
+        f"available={native.available()} "
+        f"base64_entry_point={native.b64_available()}")
 
 
 # ---- 2. timing basis ----

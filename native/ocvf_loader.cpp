@@ -10,6 +10,10 @@
 // fall back to PIL in utils/native.py (libjpeg/libpng linkage isn't worth
 // it when the fallback already covers them).
 //
+// It also decodes the connectors' wire form of a frame (base64 text,
+// runtime/connector.py): ocvf_b64_decode, called through ctypes with the
+// interpreter's lock released.
+//
 // Build: g++ -O3 -shared -fPIC -o libocvf_loader.so ocvf_loader.cpp
 // (utils/native.py does this on demand and caches the .so).
 
@@ -232,6 +236,34 @@ int load_file(const char* path, std::vector<uint8_t>& buf) {
   return got == (size_t)sz ? 0 : kErrRead;
 }
 
+// ---- base64 (the connectors' wire form of a frame) ----
+
+// Four tables, one per position in a quad, each holding its six bits where
+// they land in a word whose low three bytes are the output's, first byte
+// lowest; every
+// character outside the alphabet reads kB64Bad, a bit no valid value has,
+// so a whole payload is or-ed along and tested once.
+constexpr uint32_t kB64Bad = 0x01000000u;
+
+struct B64Tables {
+  uint32_t t[4][256];
+  B64Tables() {
+    static const char kAlphabet[] =
+        "ABCDEFGHIJKLMNOPQRSTUVWXYZabcdefghijklmnopqrstuvwxyz0123456789+/";
+    for (int p = 0; p < 4; p++)
+      for (int c = 0; c < 256; c++) t[p][c] = kB64Bad;
+    for (uint32_t v = 0; v < 64; v++) {
+      uint8_t c = (uint8_t)kAlphabet[v];
+      t[0][c] = v << 2;                                  // b0[7:2]
+      t[1][c] = (v >> 4) | ((v & 0xFu) << 12);           // b0[1:0] b1[7:4]
+      t[2][c] = ((v >> 2) << 8) | ((v & 0x3u) << 22);    // b1[3:0] b2[7:6]
+      t[3][c] = v << 16;                                 // b2[5:0]
+    }
+  }
+};
+
+const B64Tables kB64;
+
 }  // namespace
 
 extern "C" {
@@ -280,6 +312,44 @@ int ocvf_load_batch(const char* const* paths, int count, int out_h, int out_w,
     if (status[i] == 0) ok++;
   }
   return ok;
+}
+
+// Decode canonical base64 (RFC 4648 alphabet, '=' padding, no line breaks)
+// into a caller-provided buffer. Returns the byte count written, or a
+// negative code and an undefined buffer: kErrFormat for a length that is
+// no multiple of four, a character outside the alphabet or padding
+// anywhere but the last one or two places; kErrBounds when the decoded
+// size exceeds ``capacity``. The caller falls back to the standard
+// library's lenient decoder on any negative code.
+int64_t ocvf_b64_decode(const uint8_t* text, int64_t n, uint8_t* out,
+                        int64_t capacity) {
+  if (n < 0 || (n & 3) != 0) return kErrFormat;
+  if (n == 0) return 0;
+  int pad = 0;
+  if (text[n - 1] == '=') pad = text[n - 2] == '=' ? 2 : 1;
+  const int64_t size = n / 4 * 3 - pad;
+  if (size > capacity) return kErrBounds;
+  const uint8_t* s = text;
+  const uint8_t* last = text + n - 4;  // the one quad that may hold padding
+  uint8_t* o = out;
+  uint32_t bad = 0;
+  for (; s < last; s += 4, o += 3) {
+    uint32_t x = kB64.t[0][s[0]] | kB64.t[1][s[1]] | kB64.t[2][s[2]] |
+                 kB64.t[3][s[3]];
+    bad |= x;
+    o[0] = (uint8_t)x;
+    o[1] = (uint8_t)(x >> 8);
+    o[2] = (uint8_t)(x >> 16);
+  }
+  uint32_t x = kB64.t[0][s[0]] | kB64.t[1][s[1]];
+  if (pad < 2) x |= kB64.t[2][s[2]];
+  if (pad < 1) x |= kB64.t[3][s[3]];
+  bad |= x;
+  if (bad & kB64Bad) return kErrFormat;
+  o[0] = (uint8_t)x;
+  if (pad < 2) o[1] = (uint8_t)(x >> 8);
+  if (pad < 1) o[2] = (uint8_t)(x >> 16);
+  return size;
 }
 
 }  // extern "C"

@@ -29,16 +29,18 @@ from __future__ import annotations
 import base64
 import io
 import json
+import math
 import os
 import random
 import select
 import socket
 import threading
 import time
-from typing import Any, Callable, Dict, IO, List, Optional
+from typing import Any, Callable, Dict, IO, List, Optional, Tuple
 
 import numpy as np
 from opencv_facerecognizer_tpu.utils import metric_names as mn
+from opencv_facerecognizer_tpu.utils import native
 
 Handler = Callable[[str, Dict[str, Any]], None]
 
@@ -58,9 +60,50 @@ def encode_frame(frame: np.ndarray) -> Dict[str, Any]:
     }
 
 
-def decode_frame(obj: Dict[str, Any]) -> np.ndarray:
+def _decode_frame_native(obj: Dict[str, Any]) -> Optional[np.ndarray]:
+    """The frame of a clean message, decoded by ``utils.native`` straight
+    into a new array with the interpreter's lock released; None, never an
+    exception, for anything else. Clean: ASCII text whose length is a
+    multiple of four and whose decoded size is what a plain numeric
+    ``dtype`` and a ``shape`` of non-negative ints say (and, checked by
+    the decoder as it goes, pure alphabet plus ``=`` padding). Everything
+    else — line breaks, stray characters, a wrong size, a shape with -1,
+    a missing key — is the standard decoder's to return or raise for."""
+    text, shape = obj["__frame__"], obj.get("shape")
+    if not (native.b64_available()
+            and isinstance(text, str) and text.isascii() and len(text) % 4 == 0
+            and isinstance(shape, (list, tuple))
+            and all(type(d) is int and d >= 0 for d in shape)):
+        return None
+    try:
+        dtype = np.dtype(obj["dtype"])
+    except Exception:  # ocvf-lint: disable=swallowed-exception -- not swallowed: None sends the message to the standard decoder, which raises for the same missing or unknown dtype in its own order (after the text's own errors)
+        return None
+    size = len(text) // 4 * 3 - (text.endswith("=") + text.endswith("=="))
+    if (dtype.kind not in "biufc"
+            or size != math.prod(shape) * dtype.itemsize):
+        return None
+    out = np.empty(shape, dtype)
+    return out if native.b64_decode_into(text.encode("ascii"), out) else None
+
+
+def decode_frame_counted(obj: Dict[str, Any]) -> Tuple[np.ndarray, bool]:
+    """``decode_frame`` and whether the native decoder produced the array
+    (the service counts it: ``frames_decoded_native``)."""
+    frame = _decode_frame_native(obj)
+    if frame is not None:
+        return frame, True
     raw = base64.b64decode(obj["__frame__"])
-    return np.frombuffer(raw, dtype=np.dtype(obj["dtype"])).reshape(obj["shape"]).copy()
+    return np.frombuffer(raw, dtype=np.dtype(obj["dtype"])).reshape(
+        obj["shape"]).copy(), False
+
+
+def decode_frame(obj: Dict[str, Any]) -> np.ndarray:
+    """The array ``encode_frame`` was given: new, writable, C-contiguous.
+    One result whichever decoder ran — what ``base64.b64decode`` and
+    ``np.frombuffer`` return or raise for a message, this returns or
+    raises."""
+    return decode_frame_counted(obj)[0]
 
 
 class MiddlewareConnector:

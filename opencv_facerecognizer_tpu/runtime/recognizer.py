@@ -111,6 +111,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from opencv_facerecognizer_tpu.utils import metric_names as mn
+from opencv_facerecognizer_tpu.utils import native
 from opencv_facerecognizer_tpu.parallel.pipeline import RecognitionPipeline
 from opencv_facerecognizer_tpu.runtime.admission import (
     PRIORITY_INTERACTIVE,
@@ -120,7 +121,7 @@ from opencv_facerecognizer_tpu.runtime.admission import (
 from opencv_facerecognizer_tpu.runtime.batcher import FrameBatcher
 from opencv_facerecognizer_tpu.runtime.connector import (
     MiddlewareConnector,
-    decode_frame,
+    decode_frame_counted,
 )
 from opencv_facerecognizer_tpu.runtime.ingest import (
     JPEG_KEY,
@@ -1312,9 +1313,12 @@ class RecognizerService:
         # decode below fails and the frame counts malformed — the
         # operator forgot --ingest-mode jpeg, loudly.
         try:
-            frame = decode_frame(msg) if "__frame__" in msg else np.asarray(
-                msg["frame"]
-            )
+            if "__frame__" in msg:
+                frame, native_decoded = decode_frame_counted(msg)
+                if native_decoded:
+                    self.metrics.incr(mn.FRAMES_DECODED_NATIVE)
+            else:
+                frame = np.asarray(msg["frame"])
         except Exception:
             self.metrics.incr(mn.FRAMES_MALFORMED)
             self._trace_settle([tid], mn.FRAMES_MALFORMED, "decode")
@@ -1478,6 +1482,9 @@ class RecognizerService:
         # injector into the next service built on it.
         if self._faults is not None:
             self.pipeline.fault_injector = self._faults
+        # The wire decoder's library loads (in a checkout's first run,
+        # builds) here, never on the first frame of a window.
+        native.b64_available()
         self._running = True
         self._crashed = False
         self._loop_progress_t = None

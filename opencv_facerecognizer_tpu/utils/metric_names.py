@@ -80,6 +80,13 @@ TRACK_UPDATES = "track_updates"
 #: ``batcher.put`` (on the JPEG path plus the decode worker's hand-over),
 #: admitted frames only: beside ``frames_admitted``.
 INTAKE_S = "intake_s"
+#: admitted wire-form frames (``__frame__``: base64) that the native
+#: decoder turned into their array, the interpreter's lock released; over
+#: ``frames_admitted``, 100 % where every frame arrives in wire form and
+#: clean, 0 where the library is missing (no compiler) and the standard
+#: decoder serves. A frame the native decoder declines (line breaks, a
+#: wrong size, corrupt text) goes to the standard one and is not counted.
+FRAMES_DECODED_NATIVE = "frames_decoded_native"
 
 # ---- serving loop: latency windows (observe) ------------------------------
 WARMUP = "warmup"

@@ -10,6 +10,10 @@ The shared library is compiled on demand with g++ (one time, cached next
 to the source as ``native/libocvf_loader.so``); pybind11 is not available
 in this environment, so the boundary is a flat ``extern "C"`` API over
 preallocated numpy buffers — zero copies on the Python side.
+
+The same library decodes the connectors' wire form of a frame
+(``b64_decode_into``; ``runtime.connector.decode_frame``): ctypes releases
+the interpreter's lock for the call, which ``binascii`` does not.
 """
 
 from __future__ import annotations
@@ -31,6 +35,7 @@ _SO = os.path.join(_REPO, "native", "libocvf_loader.so")
 _lock = threading.Lock()
 _lib_handle = None
 _lib_failed = False
+_b64 = None  # ocvf_b64_decode, where the loaded library has it
 
 
 def _build() -> bool:
@@ -56,7 +61,7 @@ def _build() -> bool:
 
 def _lib() -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native library; None if unavailable."""
-    global _lib_handle, _lib_failed
+    global _lib_handle, _lib_failed, _b64
     if _lib_handle is not None or _lib_failed:
         return _lib_handle
     with _lock:
@@ -92,6 +97,20 @@ def _lib() -> Optional[ctypes.CDLL]:
                 ctypes.c_int, ctypes.c_int,
                 ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_int),
             ]
+            try:
+                b64 = lib.ocvf_b64_decode
+            except AttributeError:
+                # A library built from an older source (its mtime newer
+                # than the source's all the same): images load, frames
+                # take the standard decoder, chip_smoke.py says so.
+                b64 = None
+            else:
+                b64.restype = ctypes.c_int64
+                b64.argtypes = [
+                    ctypes.c_char_p, ctypes.c_int64,
+                    ctypes.c_void_p, ctypes.c_int64,
+                ]
+            _b64 = b64
             _lib_handle = lib
         except OSError:
             _lib_failed = True
@@ -100,6 +119,26 @@ def _lib() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _lib() is not None
+
+
+def b64_available() -> bool:
+    """Is the base64 entry point bound? Loads (and in a checkout's first
+    run builds) the library: call it before serving starts, so that no
+    frame of a window pays for the compiler."""
+    return _lib() is not None and _b64 is not None
+
+
+def b64_decode_into(text: bytes, out: np.ndarray) -> bool:
+    """Decode canonical base64 ``text`` (alphabet and ``=`` padding only)
+    into ``out``, a writable C-contiguous array, with the interpreter's
+    lock released for the call. True when exactly ``out.nbytes`` bytes
+    were written; False — ``out`` then undefined — when the library is
+    unavailable or the text is anything else (the caller falls back to
+    ``base64.b64decode``, which is lenient where this is strict)."""
+    if not b64_available() or not (out.flags.c_contiguous
+                                   and out.flags.writeable):
+        return False
+    return _b64(text, len(text), out.ctypes.data, out.nbytes) == out.nbytes
 
 
 _MAGIC = (b"P2", b"P3", b"P5", b"P6", b"BM")
