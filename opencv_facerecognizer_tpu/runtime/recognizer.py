@@ -1002,16 +1002,16 @@ class RecognizerService:
         told when the verdict is read — ahead of the step's enqueue, so
         ahead of the ``tracker.update`` calls of that step's results, and
         of every later lookup; the publish (``_complete_empty``) follows
-        the enqueue. The calls wait for the tracker's lock, which the
-        readback thread's ``tracker.update`` holds: leaf ``track_miss``."""
+        the enqueue. One acquisition of the tracker's lock a batch (the
+        readback thread holds it for bookkeeping only): ``track_miss``."""
         with self._leaf("track_miss", batch_tid, frames=len(rejected)):
-            for meta, _ts, _tid, _pri in rejected:
-                key = self._track_stream_key(meta)
-                if key is not None:
-                    try:
-                        self.tracker.note_miss(key)
-                    except Exception:  # noqa: BLE001 — observation only
-                        self.metrics.incr(mn.TRACK_ERRORS)
+            keys = [key for key in (self._track_stream_key(row[0])
+                                    for row in rejected) if key is not None]
+            if keys:
+                try:
+                    self.tracker.note_misses(keys)
+                except Exception:  # noqa: BLE001 — observation only
+                    self.metrics.incr(mn.TRACK_ERRORS)
 
     def _complete_empty(self, rejected, batch_tid: int) -> None:
         """Settle cascade-rejected frames as ``completed_empty``: each

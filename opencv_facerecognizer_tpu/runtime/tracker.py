@@ -36,16 +36,27 @@ surface). The poisoning guarantees, each enforced structurally:
   by one service — PR 10's rendezvous routing pins a topic to one
   replica, so nothing replicates and failover simply cold-starts.
 
-Thread model: ``lookup`` runs on the dispatch thread, ``update`` /
-``note_miss`` on the readback worker — one lock guards the registry;
-every operation is a handful of tiny NumPy reductions.
+Thread model: ``lookup`` and ``note_misses`` (a gated batch's rejected
+frames, at the verdict) run on the serving loop's thread, ``update`` on
+the readback worker. One lock guards the registry and is held for
+bookkeeping only: ``update`` pools its frame's appearance signatures
+BEFORE it takes the lock (they depend on the frame and the faces' own
+boxes, never on the registry), and a batch's misses go in under one
+acquisition — so the loop, which the chip waits on, never sits out
+another thread's arithmetic. ``lookup`` pools under the lock (its boxes
+are the tracks', read there) and only on the all-confirmed, none-due
+path. What callers waited for the lock is counted:
+``tracker_lock_wait_s`` over ``tracker_lock_acquires``.
 """
 
 from __future__ import annotations
 
+import functools
+import math
 import threading
+import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterable, List, Optional
 
 import numpy as np
 
@@ -150,6 +161,51 @@ def _centroid(box: np.ndarray) -> tuple:
     return (float(box[0] + box[2]) * 0.5, float(box[1] + box[3]) * 0.5)
 
 
+@functools.lru_cache(maxsize=4096)
+def _pool_bins(height: int, width: int, pool: int):  # ocvf-lint: boundary-block=host-sync -- host integers only (a patch's height and width): bin edges and areas in NumPy, no device value in reach
+    """The pool x pool grid over a ``height`` x ``width`` patch: each
+    axis's first row / column of every cell, and the cells' areas. Cell k
+    spans ``linspace(0, n, pool + 1).astype(int)[k:k + 2]``; where the
+    patch is smaller than the grid a cell that would be empty takes the
+    one pixel it starts at — which is what ``np.add.reduceat`` returns
+    for a start not below the next. Kept per patch shape (read-only,
+    shared by every caller) so no call draws the edges again."""
+    def axis(n):
+        edges = np.linspace(0, n, pool + 1).astype(int)
+        starts = edges[:-1]
+        return starts, np.maximum(edges[1:], starts + 1) - starts
+    rows, row_counts = axis(height)
+    cols, col_counts = axis(width)
+    areas = np.outer(row_counts, col_counts)
+    for a in (rows, cols, areas):
+        a.setflags(write=False)
+    return rows, cols, areas
+
+
+class _CountedLock:
+    """The tracker's one lock, as ``with self._lock:`` — counting, per
+    acquisition, the seconds the caller waited for it (one clock pair
+    round the acquire): ``tracker_lock_wait_s`` over
+    ``tracker_lock_acquires`` says how long the serving loop and the
+    readback worker stand in each other's way."""
+
+    def __init__(self, metrics):
+        self._lock = threading.Lock()
+        self._metrics = metrics
+
+    def __enter__(self):
+        t0 = time.perf_counter()
+        self._lock.acquire()
+        waited = time.perf_counter() - t0
+        if self._metrics is not None:
+            self._metrics.incr(mn.TRACKER_LOCK_WAIT_S, waited)
+            self._metrics.incr(mn.TRACKER_LOCK_ACQUIRES)
+        return self
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
 class IdentityTracker:
     """The track -> identity cache (module docstring). One instance per
     service replica; the service consults ``lookup`` before the cascade
@@ -160,45 +216,41 @@ class IdentityTracker:
                  metrics=None):
         self.config = config or TrackerConfig()
         self.metrics = metrics
-        self._lock = threading.Lock()
+        self._lock = _CountedLock(metrics)
         self._streams: Dict[Any, _Stream] = {}
+        #: tracks over every stream, kept as they come and go (the
+        #: ``tracks_live`` gauge without a sum over streams per call).
+        self._live = 0
         self._next_id = 1
         self._lookups = 0
         self._hits = 0
 
     # ---- host-side appearance signature ----
 
-    def _signature(self, frame: np.ndarray, box: np.ndarray) -> np.ndarray:  # ocvf-lint: boundary-block=host-sync -- pure host-NumPy by design (module docstring): ``frame`` is the intake host array, never a device value; the integral-image pooling is the tracker's budgeted ~60us of dispatch-thread work
+    def _signature(self, frame: np.ndarray, box: np.ndarray) -> np.ndarray:  # ocvf-lint: boundary-block=host-sync -- pure host-NumPy by design (module docstring): ``frame`` is the intake host array, never a device value; the block-sum pooling is the tracker's budgeted ~15us a face
         """Mean-pooled patch at ``box`` (clipped to the frame): a
         sig_pool x sig_pool float32 appearance fingerprint. Pooling
         softens box-edge motion (a 1-2 px drift moves a couple of edge
         cells by a few levels) while an in-place content change (identity
-        swap, vacated box) moves most cells by the full fill delta."""
-        pool = self.config.sig_pool
+        swap, vacated box) moves most cells by the full fill delta.
+
+        Block sums along rows, then columns, accumulated in float64:
+        exact for pixel values (integers, and any float32 a frame
+        holds in practice), so the same number as an integral image's
+        four-corner difference gives, without building one. Reads the
+        frame and the box only — no tracker state, so ``update`` calls
+        it before it takes the lock."""
         h, w = frame.shape[:2]
-        y0 = min(max(int(box[0]), 0), max(0, h - 1))
-        x0 = min(max(int(box[1]), 0), max(0, w - 1))
-        y1 = min(max(int(np.ceil(box[2])), y0 + 1), h)
-        x1 = min(max(int(np.ceil(box[3])), x0 + 1), w)
+        b0, b1, b2, b3 = box.tolist()
+        y0 = min(max(int(b0), 0), max(0, h - 1))
+        x0 = min(max(int(b1), 0), max(0, w - 1))
+        y1 = min(max(math.ceil(b2), y0 + 1), h)
+        x1 = min(max(math.ceil(b3), x0 + 1), w)
         patch = np.asarray(frame[y0:y1, x0:x1], dtype=np.float32)
-        ys = np.linspace(0, patch.shape[0], pool + 1).astype(int)
-        xs = np.linspace(0, patch.shape[1], pool + 1).astype(int)
-        # Degenerate-bin guard for patches smaller than the pool grid:
-        # every cell spans at least one pixel (clamped to the edge).
-        r1s = np.minimum(np.maximum(ys[1:], ys[:-1] + 1), patch.shape[0])
-        r0s = np.minimum(ys[:-1], r1s - 1)
-        c1s = np.minimum(np.maximum(xs[1:], xs[:-1] + 1), patch.shape[1])
-        c0s = np.minimum(xs[:-1], c1s - 1)
-        # Integral image gives every cell's block SUM in one vectorized
-        # gather — this runs per track per lookup on the dispatch
-        # thread, so a Python cell loop here would tax the very latency
-        # the cache exists to protect.
-        ii = np.zeros((patch.shape[0] + 1, patch.shape[1] + 1), np.float64)
-        np.cumsum(patch, axis=0, out=ii[1:, 1:])
-        np.cumsum(ii[1:, 1:], axis=1, out=ii[1:, 1:])
-        sums = (ii[np.ix_(r1s, c1s)] - ii[np.ix_(r0s, c1s)]
-                - ii[np.ix_(r1s, c0s)] + ii[np.ix_(r0s, c0s)])
-        areas = np.outer(r1s - r0s, c1s - c0s)
+        rows, cols, areas = _pool_bins(y1 - y0, x1 - x0, self.config.sig_pool)
+        sums = np.add.reduceat(
+            np.add.reduceat(patch, rows, axis=0, dtype=np.float64),
+            cols, axis=1)
         return (sums / areas).astype(np.float32)
 
     # ---- metrics plumbing (all under self._lock) ----
@@ -211,13 +263,15 @@ class IdentityTracker:
     def _flush(self, stream: _Stream, track: _Track, reason: str) -> None:
         if track in stream.tracks:
             stream.tracks.remove(track)
+            self._live -= 1
         self._incr(mn.TRACK_FLUSHES_PREFIX + reason)
 
     def _set_gauges(self) -> None:
+        """Once a call that can have moved them (``update``,
+        ``note_misses``, a cache hit, a flush), not once a lookup."""
         if self.metrics is None:
             return
-        live = sum(len(s.tracks) for s in self._streams.values())
-        self.metrics.set_gauge(mn.TRACKS_LIVE, live)
+        self.metrics.set_gauge(mn.TRACKS_LIVE, self._live)
         self.metrics.set_gauge(
             mn.TRACK_CACHE_HIT_RATE, self._hits / max(1, self._lookups))
 
@@ -239,7 +293,6 @@ class IdentityTracker:
             self._incr(mn.TRACK_LOOKUPS)
             st = self._streams.get(stream_key)
             if st is None or not st.tracks:
-                self._set_gauges()
                 return None
             st.lookups += 1
             # Embedder-version fence: entries verified under another
@@ -324,12 +377,17 @@ class IdentityTracker:
         pairwise ambiguity sweep. ``faces`` are publish-path dicts
         (x-first ``box``, ``label`` -1 when unknown)."""
         cfg = self.config
+        # Outside the lock: each face's box and the signature pooled at
+        # it (all of the frame's faces: which of them a track will take is
+        # the registry's to say, and nearly every one is taken). They
+        # depend on this frame and these faces alone.
+        boxes = []
+        for f in faces:
+            x0, y0, x1, y1 = (float(v) for v in f["box"])
+            boxes.append(np.asarray([y0, x0, y1, x1], np.float32))
+        sigs = [self._signature(frame, b) for b in boxes]
         with self._lock:
             st = self._streams.setdefault(stream_key, _Stream())
-            boxes = []
-            for f in faces:
-                x0, y0, x1, y1 = (float(v) for v in f["box"])
-                boxes.append(np.asarray([y0, x0, y1, x1], np.float32))
             # Greedy best-IoU association, then a centroid pass for
             # leftovers (fast small faces whose boxes slipped past the
             # IoU floor between verifies).
@@ -390,7 +448,7 @@ class IdentityTracker:
                         face_used.discard(fi)
                     continue
                 t.box = boxes[fi]
-                t.signature = self._signature(frame, t.box)
+                t.signature = sigs[fi]
                 t.misses = 0
                 t.frames_since_verify = 0
                 t.pending_verify = False
@@ -430,7 +488,7 @@ class IdentityTracker:
                 face_used.add(fi)
                 matched.add(t)
                 t.box = boxes[fi]
-                t.signature = self._signature(frame, t.box)
+                t.signature = sigs[fi]
                 t.misses = 0
                 t.frames_since_verify = 0
                 t.pending_verify = False
@@ -470,8 +528,9 @@ class IdentityTracker:
                     name=str(f.get("name", str(label))),
                     similarity=float(f.get("similarity", 0.0)),
                     detection_score=float(f.get("detection_score", 0.0)),
-                    signature=self._signature(frame, boxes[fi]),
+                    signature=sigs[fi],
                     embedder_version=embedder_version))
+                self._live += 1
                 self._incr(mn.TRACKS_CREATED)
             # Ambiguity ceiling: two live tracks overlapping this hard
             # could swap each other's association next frame — flush
@@ -490,25 +549,36 @@ class IdentityTracker:
                 self._flush(st, st.tracks[0], FLUSH_LOST)
             self._set_gauges()
 
-    def note_miss(self, stream_key: Any) -> None:
-        """A full pass saw this stream with NO faces (cascade early exit
-        or an empty detection): every live track takes a miss; past the
-        TTL it flushes ``lost`` — a vanished subject stops being served
-        within ``miss_ttl`` full frames."""
+    def note_misses(self, stream_keys: Iterable[Any]) -> None:
+        """A full pass saw each of these streams with NO faces (cascade
+        early exit or an empty detection), one key a frame, in order:
+        every live track of the stream takes a miss; past the TTL it
+        flushes ``lost`` — a vanished subject stops being served within
+        ``miss_ttl`` full frames. A gated batch's rejected frames go in
+        together, under one acquisition of the lock."""
         cfg = self.config
         with self._lock:
-            st = self._streams.get(stream_key)
-            if st is None:
-                return
-            for t in list(st.tracks):
-                t.misses += 1
-                # A missed track must re-associate on a full frame before
-                # it may serve again — the flag parks it out of the cache
-                # without burning a flush it may not deserve (occlusion).
-                t.pending_verify = True
-                if t.misses > cfg.miss_ttl:
-                    self._flush(st, t, FLUSH_LOST)
-            self._set_gauges()
+            seen = False
+            for stream_key in stream_keys:
+                st = self._streams.get(stream_key)
+                if st is None:
+                    continue
+                seen = True
+                for t in list(st.tracks):
+                    t.misses += 1
+                    # A missed track must re-associate on a full frame
+                    # before it may serve again — the flag parks it out of
+                    # the cache without burning a flush it may not deserve
+                    # (occlusion).
+                    t.pending_verify = True
+                    if t.misses > cfg.miss_ttl:
+                        self._flush(st, t, FLUSH_LOST)
+            if seen:
+                self._set_gauges()
+
+    def note_miss(self, stream_key: Any) -> None:
+        """``note_misses`` for one frame of one stream."""
+        self.note_misses((stream_key,))
 
     def flush_all(self, reason: str = FLUSH_RESET) -> int:
         """Cold start (gallery reload / explicit reset): every live track
@@ -521,6 +591,7 @@ class IdentityTracker:
                     self._incr(mn.TRACK_FLUSHES_PREFIX + reason)
                 st.tracks.clear()
             self._streams.clear()
+            self._live = 0
             self._set_gauges()
             return n
 

@@ -149,6 +149,12 @@ TRACK_FLUSHES_PREFIX = "track_flushes_"
 #: tracker call failures (fail OPEN: the frame takes the full path).
 TRACK_BATCH_EXITS = "track_batch_exits"
 TRACK_ERRORS = "track_errors"
+#: seconds callers (the serving loop's ``lookup`` / ``note_misses``, the
+#: readback worker's ``update``) spent acquiring the tracker's one lock,
+#: and the acquisitions: the lock is held for registry bookkeeping only,
+#: so the quotient is what one thread's bookkeeping costs the other.
+TRACKER_LOCK_WAIT_S = "tracker_lock_wait_s"
+TRACKER_LOCK_ACQUIRES = "tracker_lock_acquires"
 
 # ---- admission / brownout (overload layer) --------------------------------
 #: per-reason rejection family: ``frames_rejected_<reason>``

@@ -27,6 +27,7 @@ from opencv_facerecognizer_tpu.runtime.recognizer import (
     _ReadbackBlocker,
 )
 from opencv_facerecognizer_tpu.runtime.resilience import ResiliencePolicy
+from opencv_facerecognizer_tpu.runtime.tracker import IdentityTracker
 from opencv_facerecognizer_tpu.utils import metric_names as mn
 from opencv_facerecognizer_tpu.utils.metrics import Metrics
 
@@ -175,8 +176,9 @@ class FakeTracker:
                     "embedder_version": embedder_version}
         return None
 
-    def note_miss(self, key):
-        self.events.add("note_miss", key)
+    def note_misses(self, keys):
+        for key in keys:
+            self.events.add("note_miss", key)
 
     def update(self, key, faces, frame, embedder_version=None):
         self.events.add("update", int(frame[0, 0]) - 1)
@@ -189,11 +191,13 @@ class FakeTracker:
 
 
 def _stack(frames, events=None, tracker_hits=None, crash_seq=None,
-           flush_timeout=5.0, inflight_depth=2, streams=True, **kw):
+           flush_timeout=5.0, inflight_depth=2, streams=True,
+           make_tracker=None, **kw):
     """A service with ``frames`` (pairs of seq, face) already queued:
     every batch they fill is closed before the loop starts. With
     ``tracker_hits`` given it has a track cache, consulted for the frames
-    that name a stream (all, or none with ``streams`` off)."""
+    that name a stream (all, or none with ``streams`` off);
+    ``make_tracker(events, metrics)`` puts another in the fake's place."""
     events = events or Events()
     pipe_kw = {k: kw.pop(k) for k in ("hold_step", "step_fault", "gate_fault",
                                       "compute_s", "chip_s", "cascade_score_s")
@@ -203,6 +207,8 @@ def _stack(frames, events=None, tracker_hits=None, crash_seq=None,
     metrics = Metrics()
     tracker = (FakeTracker(events, tracker_hits)
                if tracker_hits is not None else None)
+    if make_tracker is not None:
+        tracker = make_tracker(events, metrics)
     service = RecognizerService(
         pipeline, connector, batch_size=BATCH, frame_shape=HW,
         flush_timeout=flush_timeout, inflight_depth=inflight_depth,
@@ -383,6 +389,53 @@ def test_note_miss_keeps_its_place_among_the_loops_tracker_calls(tracked_run):
     assert first_gate[1][:3] == (1, 2, 3)  # the hit left the buffer's front
     assert (events.index(*first_gate) < events.index("note_miss", "cam1")
             < events.index("step", (2, 3)) < events.index("update", 2))
+
+
+class _SlowMisses(IdentityTracker):
+    """The real tracker, whose ``note_misses`` is recorded and takes
+    ``SECONDS`` (so the leaf it runs under can be told by its time)."""
+
+    SECONDS = 0.05
+
+    def __init__(self, events, metrics):
+        super().__init__(metrics=metrics)
+        self.events = events
+
+    def note_misses(self, stream_keys):
+        stream_keys = list(stream_keys)
+        self.events.add("note_misses", tuple(stream_keys))
+        time.sleep(self.SECONDS)
+        super().note_misses(stream_keys)
+
+
+def test_a_batchs_gate_misses_reach_the_tracker_once_under_track_miss():
+    # one gated batch: frames 0, 1 and 3 are face-free, on three streams
+    frames = [(0, False), (1, False), (2, True), (3, False)]
+    service, _p, connector, metrics, events = _stack(
+        frames, make_tracker=_SlowMisses)
+    service.start(warmup=False)
+    try:
+        assert service.drain(timeout=10.0)
+    finally:
+        service.stop()
+    # every rejected frame's stream, in the batch's order, in ONE call
+    assert events.kinds("note_misses") == [
+        ("note_misses", ("cam0", "cam1", "cam3"))]
+    # at the verdict: after the gate, ahead of the survivor's step
+    (step,) = events.kinds("step")  # frame 2, and the rung's padding
+    assert step[1][0] == 2
+    assert (events.index("gate", (0, 1, 2, 3))
+            < events.index("note_misses", ("cam0", "cam1", "cam3"))
+            < events.index(*step))
+    # inside the leaf ``track_miss``: its seconds hold the call's
+    counters = metrics.counters()
+    assert counters[mn.LOOP_S_PREFIX + "track_miss"] >= _SlowMisses.SECONDS
+    # what the callers waited for the tracker's lock, and how often they
+    # took it: 4 lookups, the batch's misses, the survivor's update
+    assert counters[mn.TRACKER_LOCK_ACQUIRES] == 6
+    assert 0.0 <= counters[mn.TRACKER_LOCK_WAIT_S] < 1.0
+    assert metrics.counter(mn.TRACK_ERRORS) == 0
+    _assert_settled_once(service, connector, range(4))
 
 
 def test_a_tracker_that_no_frame_consults_does_not_hold_the_gate_back():

@@ -8,8 +8,10 @@ chaos-video variant, and the registry/bench plumbing."""
 import importlib.util
 import json
 import os
+import threading
 
 import numpy as np
+import pytest
 
 from opencv_facerecognizer_tpu.runtime.connector import FakeConnector
 from opencv_facerecognizer_tpu.runtime.fakes import (
@@ -190,6 +192,345 @@ def test_flush_all_cold_starts():
     assert tracker.metrics.counter(mn.TRACK_FLUSHES_PREFIX + "reset") == 1
 
 
+# ---- the lock is for bookkeeping (ISSUE 36) ---------------------------------
+
+
+def _integral_image_signature(frame, box, pool=8):
+    """The signature as it was pooled until ISSUE 36 (an integral image
+    and a four-corner gather per cell): the reference ``_signature`` has
+    to equal bit for bit."""
+    h, w = frame.shape[:2]
+    y0 = min(max(int(box[0]), 0), max(0, h - 1))
+    x0 = min(max(int(box[1]), 0), max(0, w - 1))
+    y1 = min(max(int(np.ceil(box[2])), y0 + 1), h)
+    x1 = min(max(int(np.ceil(box[3])), x0 + 1), w)
+    patch = np.asarray(frame[y0:y1, x0:x1], dtype=np.float32)
+    ys = np.linspace(0, patch.shape[0], pool + 1).astype(int)
+    xs = np.linspace(0, patch.shape[1], pool + 1).astype(int)
+    r1s = np.minimum(np.maximum(ys[1:], ys[:-1] + 1), patch.shape[0])
+    r0s = np.minimum(ys[:-1], r1s - 1)
+    c1s = np.minimum(np.maximum(xs[1:], xs[:-1] + 1), patch.shape[1])
+    c0s = np.minimum(xs[:-1], c1s - 1)
+    ii = np.zeros((patch.shape[0] + 1, patch.shape[1] + 1), np.float64)
+    np.cumsum(patch, axis=0, out=ii[1:, 1:])
+    np.cumsum(ii[1:, 1:], axis=1, out=ii[1:, 1:])
+    sums = (ii[np.ix_(r1s, c1s)] - ii[np.ix_(r0s, c1s)]
+            - ii[np.ix_(r1s, c0s)] + ii[np.ix_(r0s, c0s)])
+    areas = np.outer(r1s - r0s, c1s - c0s)
+    return (sums / areas).astype(np.float32)
+
+
+#: (y0, x0, y1, x1) on a 64 x 48 frame.
+SIGNATURE_BOXES = {
+    "inside": (10, 8, 38, 36),
+    "inside_uneven_bins": (5, 7, 34, 30),
+    "clipped_top": (-6, 10, 20, 30),
+    "clipped_left": (12, -9.5, 40, 17),
+    "clipped_bottom": (50, 10, 80, 40),
+    "clipped_right": (8, 30, 30, 70),
+    "clipped_every_edge": (-20, -20, 90, 90),
+    "outside_the_frame": (70, 55, 90, 80),
+    "fractional_corners": (10.3, 8.7, 37.9, 35.2),
+    "fractional_under_one_pixel": (20.2, 20.4, 20.6, 20.9),
+    "short_of_the_pool_in_rows": (10, 8, 15, 36),
+    "short_of_the_pool_in_columns": (10, 8, 38, 11),
+    "short_of_the_pool_in_both": (10, 8, 13, 14),
+    "one_row": (10, 8, 11, 36),
+    "one_by_one": (30, 30, 31, 31),
+    "corner_pixel": (63, 47, 64, 48),
+}
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.float32])
+@pytest.mark.parametrize("name", sorted(SIGNATURE_BOXES))
+def test_signature_equals_the_integral_image_reference(name, dtype):
+    frame = np.random.default_rng(36).integers(
+        0, 256, size=(64, 48)).astype(dtype)
+    box = np.asarray(SIGNATURE_BOXES[name], np.float32)
+    for pool in (8, 5):
+        got = _tracker(sig_pool=pool)._signature(frame, box)
+        want = _integral_image_signature(frame, box, pool)
+        assert got.dtype == np.float32 and got.shape == (pool, pool)
+        assert np.array_equal(got, want), name
+
+
+def test_signature_equals_the_reference_on_random_boxes_and_fractions():
+    rng = np.random.default_rng(3600)
+    tracker = _tracker()
+    frames = [rng.integers(0, 256, size=(96, 80)).astype(np.uint8),
+              rng.uniform(0, 255, size=(96, 80)).astype(np.float32)]
+    for _ in range(400):
+        centre = rng.uniform(-8, 104, size=2)
+        half = rng.uniform(0.2, 30, size=2)
+        box = np.concatenate([centre - half, centre + half]).astype(np.float32)
+        for frame in frames:
+            assert np.array_equal(tracker._signature(frame, box),
+                                  _integral_image_signature(frame, box))
+
+
+def _busy_registry(metrics):
+    """Three streams: two confirmed tracks, one tentative, one at the
+    edge of its miss budget."""
+    tracker = _tracker(metrics=metrics, reverify_frames=100)
+    for cam, box in (("a", (10, 8, 26, 24)), ("b", (30, 30, 50, 50))):
+        for _ in range(2):
+            tracker.update(cam, [_face(box, 1)], _frame(box))
+    tracker.update("c", [_face((4, 4, 20, 20), 2)], _frame((4, 4, 20, 20)))
+    tracker.note_miss("b")
+    tracker.note_miss("b")
+    assert tracker.lookup("a", _frame((10, 8, 26, 24))) is not None
+    return tracker
+
+
+def _observable(tracker):
+    metrics = tracker.metrics
+    counters = {k: v for k, v in metrics.counters().items()
+                if not k.startswith("tracker_lock_")}
+    return (tracker.registry(), tracker.stats(), counters,
+            metrics.gauge(mn.TRACKS_LIVE),
+            metrics.gauge(mn.TRACK_CACHE_HIT_RATE))
+
+
+@pytest.mark.parametrize("keys", [
+    ("a", "b", "c"),
+    ("b", "nobody", "b", "a", "a", "a", "b"),   # repeats run past the TTL
+    ("nobody", "nowhere"),
+    (),
+], ids=["each_once", "repeated_and_unknown", "unknown_only", "none"])
+def test_note_misses_is_note_miss_key_by_key(keys):
+    one_by_one, together = _busy_registry(Metrics()), _busy_registry(Metrics())
+    for key in keys:
+        one_by_one.note_miss(key)
+    together.note_misses(keys)
+    assert _observable(together) == _observable(one_by_one)
+    # the batch took the lock once, whatever it named
+    before = together.metrics.counter(mn.TRACKER_LOCK_ACQUIRES)
+    together.note_misses(iter(keys))
+    assert together.metrics.counter(mn.TRACKER_LOCK_ACQUIRES) == before + 1
+    assert together.metrics.gauge(mn.TRACKS_LIVE) == \
+        together.stats()["tracks_live"]
+
+
+def test_the_lock_is_free_while_update_pools_a_signature():
+    tracker = _busy_registry(Metrics())
+    pooling, release = threading.Event(), threading.Event()
+    pool_signature = tracker._signature
+
+    def held_up(frame, box):
+        pooling.set()
+        assert release.wait(10.0)
+        return pool_signature(frame, box)
+
+    tracker._signature = held_up
+    box = (10, 8, 26, 24)
+    updater = threading.Thread(
+        target=tracker.update, args=("a", [_face(box, 1)], _frame(box)))
+    updater.start()
+    try:
+        assert pooling.wait(10.0)      # update is inside _signature now
+        replies = []
+        others = [
+            threading.Thread(target=tracker.note_misses, args=(["b", "c"],)),
+            threading.Thread(target=lambda: replies.append(
+                tracker.lookup("c", _frame((4, 4, 20, 20))))),
+        ]
+        for thread in others:
+            thread.start()
+        for thread in others:
+            thread.join(5.0)
+            assert not thread.is_alive()   # neither waited for the pooling
+        assert replies == [None]           # "c" is tentative, and now parked
+        assert updater.is_alive()          # still held up, lock not taken
+    finally:
+        release.set()
+        updater.join(10.0)
+    assert not updater.is_alive()
+    by_stream = {}
+    for row in tracker.registry():
+        by_stream.setdefault(row["stream"], []).append(row)
+    assert by_stream["a"][0]["hits"] == 3 and "b" not in by_stream
+    assert by_stream["c"][0]["misses"] == 1
+
+
+def test_threads_on_every_entry_point_keep_the_registry_and_its_count():
+    """More threads than cores on ``update`` / ``lookup`` / ``note_misses``
+    / ``flush_all`` with the interpreter switching every 10 us: nothing
+    raises, and the running count behind ``tracks_live`` is the registry's
+    (a lost update to it, or a track appended outside the lock, shows)."""
+    import sys
+
+    metrics = Metrics()
+    tracker = _tracker(metrics, reverify_frames=3, max_tracks_per_stream=3)
+    cams = ["cam%d" % i for i in range(4)]
+    boxes = [(4 + 14 * i, 6 + 12 * i, 18 + 14 * i, 20 + 12 * i)
+             for i in range(4)]
+    frame = _frame(boxes[0])
+    errors, stop = [], threading.Event()
+
+    def run(body):
+        rng = np.random.default_rng(threading.get_ident() % 2**32)
+        try:
+            while not stop.is_set():
+                body(rng)
+        except Exception as exc:  # noqa: BLE001 - reported below
+            errors.append(exc)
+
+    def update(rng):
+        picked = rng.permutation(4)[:int(rng.integers(0, 4))]
+        tracker.update(cams[int(rng.integers(4))],
+                       [_face(boxes[i], int(rng.integers(-1, 3)))
+                        for i in picked], frame)
+
+    bodies = [update] * 6 + [
+        lambda rng: tracker.lookup(cams[int(rng.integers(4))], frame),
+        lambda rng: tracker.lookup(cams[int(rng.integers(4))], frame),
+        lambda rng: tracker.note_misses(
+            [cams[int(i)] for i in rng.integers(0, 4, size=5)]),
+        lambda rng: tracker.note_misses(["nobody", cams[0]]),
+        lambda rng: tracker.flush_all() if rng.random() < 0.01 else None,
+        lambda rng: (tracker.registry(), tracker.stats()),
+    ]
+    threads = [threading.Thread(target=run, args=(body,)) for body in bodies]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for thread in threads:
+            thread.start()
+        stop.wait(1.5)
+    finally:
+        stop.set()
+        for thread in threads:
+            thread.join(10.0)
+        sys.setswitchinterval(interval)
+    assert not any(thread.is_alive() for thread in threads)
+    assert errors == []
+    live = tracker.stats()["tracks_live"]
+    assert tracker._live == live == len(tracker.registry())
+    tracker.note_misses(cams)                # a call that sets the gauges
+    assert metrics.gauge(mn.TRACKS_LIVE) == tracker.stats()["tracks_live"]
+    counters = metrics.counters()
+    flushed = sum(v for k, v in counters.items()
+                  if k.startswith(mn.TRACK_FLUSHES_PREFIX))
+    assert counters[mn.TRACKS_CREATED] - flushed == tracker._live
+    assert counters[mn.TRACKER_LOCK_ACQUIRES] > len(threads)
+
+
+def _recorded_sequence(tracker):
+    """``lookup`` / ``update`` / ``note_miss`` over three streams, through
+    association, identity flush, re-acquisition, ambiguity, the miss TTL
+    and the registry bound. Returns every cache reply and the registry
+    after every step."""
+    a, b, far = (10, 8, 26, 24), (30, 30, 50, 50), (40, 4, 56, 20)
+    replies, registries = [], []
+
+    def scene(*blobs):
+        frame = _frame(None, seed=7)
+        for (y0, x0, y1, x1), value in blobs:
+            frame[y0:y1, x0:x1] = value
+        return frame
+
+    def step(kind, cam, *args, **kw):
+        if kind == "lookup":
+            hit = tracker.lookup(cam, *args, **kw)
+            replies.append(None if hit is None else
+                           (hit["track_id"], hit["embedder_version"],
+                            [(f["track_id"], f["label"], f["name"], f["box"])
+                             for f in hit["faces"]]))
+        else:
+            getattr(tracker, kind)(cam, *args, **kw)
+        registries.append([
+            (r["stream"], r["track_id"], r["box"], r["label"], r["confirmed"],
+             r["hits"], r["misses"], r["frames_since_verify"],
+             r["embedder_version"]) for r in tracker.registry()])
+
+    two = scene((a, 160.0), (b, 184.0))
+    # cam0: two subjects associate and confirm, then serve from the cache
+    for _ in range(2):
+        step("update", "cam0", [_face(a, 0), _face(b, 1, "id1")], two,
+             embedder_version=1)
+    for _ in range(4):                      # interval 4: three cached
+        step("lookup", "cam0", two, embedder_version=1)
+    # a slides by centroid; b's box now holds another identity: flush + seed
+    a2 = (13, 11, 29, 27)
+    swapped = scene((a2, 160.0), (b, 232.0))
+    step("update", "cam0", [_face(a2, 0), _face(b, 3, "id3")], swapped,
+         embedder_version=1)
+    step("lookup", "cam0", swapped, embedder_version=1)   # id3 tentative
+    step("update", "cam0", [_face(a2, 0), _face(b, 3, "id3")], swapped,
+         embedder_version=1)
+    step("lookup", "cam0", swapped, embedder_version=1)
+    step("lookup", "cam0", scene((a2, 232.0), (b, 232.0)),
+         embedder_version=1)                 # a repainted in place: drift
+    # cam1: teleport re-acquisition, then the gate's misses to the TTL
+    for _ in range(2):
+        step("update", "cam1", [_face(a, 5, "id5")], scene((a, 200.0)))
+    step("update", "cam1", [_face(far, 5, "id5")], scene((far, 200.0)))
+    step("lookup", "cam1", scene((far, 200.0)))
+    step("note_miss", "cam1")
+    step("lookup", "cam1", scene((far, 200.0)))           # parked
+    step("note_miss", "cam1")
+    step("note_miss", "cam1")                             # past miss_ttl
+    step("note_miss", "nobody")
+    # cam2: an unknown face seeds nothing; nested boxes flush both tracks;
+    # five known faces overflow a registry bound of four
+    step("update", "cam2", [_face(a, -1, "unknown")], scene((a, 160.0)))
+    big, nested = (10, 4, 34, 28), (12, 6, 32, 26)
+    for _ in range(2):
+        step("update", "cam2", [_face(big, 0), _face(b, 1, "id1")], two)
+    step("update", "cam2", [_face(big, 0), _face(nested, 1, "id1")], two)
+    row = [(2, 2 + 12 * i, 12, 12 + 12 * i) for i in range(5)]
+    step("update", "cam2",
+         [_face(box, 10 + i, "id%d" % (10 + i)) for i, box in enumerate(row)],
+         scene(*((box, 150.0 + 10 * i) for i, box in enumerate(row))))
+    step("lookup", "cam0", swapped, embedder_version=2)   # version fence
+    return replies, registries
+
+
+#: What the tracker of the commit before ISSUE 36 answered and held over
+#: ``_recorded_sequence`` (recorded by running it there): the cache's
+#: replies, the registry after steps 9 and 14 and at the end, the live
+#: tracks after every step, and its counters.
+_TWO = [(2, 0, "id0", [8.0, 10.0, 24.0, 26.0]),
+        (3, 1, "id1", [30.0, 30.0, 50.0, 50.0])]
+RECORDED_REPLIES = [
+    (2, 1, _TWO), (2, 1, _TWO), (2, 1, _TWO), None, None,
+    (2, 1, [(2, 0, "id0", [11.0, 13.0, 27.0, 29.0]),
+            (4, 3, "id3", [30.0, 30.0, 50.0, 50.0])]),
+    None,
+    (5, None, [(5, 5, "id5", [4.0, 40.0, 20.0, 56.0])]),
+    None, None]
+RECORDED_REGISTRY = {
+    8: [("cam0", 2, [11.0, 13.0, 27.0, 29.0], 0, True, 4, 0, 0, 1),
+        ("cam0", 4, [30.0, 30.0, 50.0, 50.0], 3, True, 2, 0, 0, 1)],
+    13: [("cam0", 2, [11.0, 13.0, 27.0, 29.0], 0, True, 4, 0, 1, 1),
+         ("cam0", 4, [30.0, 30.0, 50.0, 50.0], 3, True, 2, 0, 1, 1),
+         ("cam1", 5, [4.0, 40.0, 20.0, 56.0], 5, True, 3, 0, 0, None)],
+    25: [("cam2", 9, [14.0, 2.0, 24.0, 12.0], 11, False, 1, 0, 0, None),
+         ("cam2", 10, [26.0, 2.0, 36.0, 12.0], 12, False, 1, 0, 0, None),
+         ("cam2", 11, [38.0, 2.0, 48.0, 12.0], 13, False, 1, 0, 0, None),
+         ("cam2", 12, [50.0, 2.0, 60.0, 12.0], 14, False, 1, 0, 0, None)]}
+RECORDED_TRACKS_LIVE = [2, 2, 2, 2, 2, 2, 2, 2, 2, 2, 2, 3, 3, 3, 3, 3, 3, 3,
+                        2, 2, 2, 4, 4, 2, 6, 4]
+RECORDED_COUNTERS = {
+    "track_cache_hits": 5.0, "track_flushes_ambiguity": 2.0,
+    "track_flushes_identity": 1.0, "track_flushes_lost": 2.0,
+    "track_flushes_version": 2.0, "track_lookups": 10.0,
+    "track_reverifies": 3.0, "tracks_confirmed": 6.0, "tracks_created": 11.0}
+
+
+def test_recorded_sequence_replies_and_registry_as_before_the_change():
+    metrics = Metrics()
+    replies, registries = _recorded_sequence(
+        _tracker(metrics, reverify_frames=4, max_tracks_per_stream=4))
+    assert replies == RECORDED_REPLIES
+    assert {at: registries[at] for at in RECORDED_REGISTRY} == \
+        RECORDED_REGISTRY
+    assert [len(r) for r in registries] == RECORDED_TRACKS_LIVE
+    counters = metrics.counters()
+    assert {k: counters.get(k, 0) for k in RECORDED_COUNTERS} == \
+        RECORDED_COUNTERS
+
+
 # ---- video generator + oracle ----------------------------------------------
 
 
@@ -339,6 +680,40 @@ def test_track_metric_names_registered():
 
     assert ATTR_HINTS["tracker"] == "IdentityTracker"
     assert any(s.endswith("runtime/tracker.py") for s in HOT_PATH_SUFFIXES)
+
+
+@pytest.mark.parametrize("metric, numerator", [
+    ("track_miss_ms_per_batch.backlog", mn.LOOP_S_PREFIX + "track_miss"),
+    ("tracker_lock_wait_ms_per_batch.backlog", mn.TRACKER_LOCK_WAIT_S),
+])
+def test_per_batch_tracker_metrics_read_registered_counters(metric,
+                                                            numerator):
+    """The benchmark's two readings of this layer are data over the reader
+    ``counter_quotient``: milliseconds a popped batch, in the replay cell
+    (the only one whose frames name a camera)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        (declared,) = [m for m in json.load(fh)["per_layer"]
+                       if m["name"] == metric]
+    assert declared["workloads"] == ["watchlist8m.replay"]
+    assert (declared["layer"], declared["source"], declared["better"],
+            declared["moves"]) == ("service loop", "program_counter",
+                                   "lower", "served_fps")
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           metric + ".json")) as fh:
+        spec = json.load(fh)
+    assert spec["reader"] == "counter_quotient" and spec["scale"] == 1000
+    assert spec["numerator"] == [numerator]
+    assert spec["denominator"] == [mn.LOOP_BATCHES]
+    assert "track_miss" in mn.LOOP_LEAVES
+    assert {mn.TRACKER_LOCK_WAIT_S, mn.LOOP_BATCHES} <= set(mn.all_names())
+    # a window of 40 batches that waited 0.1 s reads 2.5 ms a batch; a
+    # program without the counter (the parent) gives no reading
+    from benchmark.readers import counter_quotient
+    assert counter_quotient.read(
+        spec, {"counters": {numerator: 0.1, mn.LOOP_BATCHES: 40}}) == 2.5
+    assert counter_quotient.read(
+        spec, {"counters": {mn.LOOP_BATCHES: 40}}) is None
 
 
 def test_expo_tracks_endpoint_and_null_shape():
