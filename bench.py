@@ -394,7 +394,8 @@ def main():
     # (the cast just pre-pays at enrolment); measured 1.24x at 1M rows
     # (pre-PR-1 record, source deleted), ~noise at this 16k headline size.
     # Transfer f32 and cast ON DEVICE: a host-side ml_dtypes array misses
-    # PJRT's zero-copy put (gallery._put_emb documents the 25x penalty).
+    # PJRT's zero-copy put (gallery._host_cast ships bf16 as uint16 bits for
+    # that reason).
     g = jnp.asarray(gallery).astype(jnp.bfloat16)
     lab = jnp.asarray(labels)
     det_params = det.params

@@ -607,6 +607,10 @@ def build_service(args, pipeline, names, connector, metrics, *,
         ResiliencePolicy, rebuild_pipeline_on_cpu,
     )
 
+    gallery = getattr(pipeline, "gallery", None)
+    if hasattr(gallery, "attach_observability"):
+        gallery.attach_observability(metrics, tracer)
+
     ingest_cfg = IngestConfig(
         mode=args.ingest_mode,
         ring_depth=args.ingest_ring_depth or None,

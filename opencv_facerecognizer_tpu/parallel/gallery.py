@@ -14,9 +14,14 @@ Design:
 - Labels are tiny ([capacity] int32), so they stay replicated.
 - Queries are sharded over ``dp`` and replicated over ``tp``; outputs come
   back sharded over ``dp``.
-- Enrolment writes and the double-buffered atomic swap (``runtime``'s
-  model-reload-without-drop, SURVEY.md §5.3) happen host-side via
-  ``jax.device_put`` with the same shardings.
+- Enrolment and the double-buffered atomic swap (``runtime``'s
+  model-reload-without-drop, SURVEY.md §5.3): the next snapshot is built
+  beside the one being served and published with one attribute write. An
+  ``add`` of n rows moves n rows over the host->device link and splices
+  them into a copy of the served arrays ON the devices, so at most two
+  tier-sized arrays a chip are ever live and the host holds a mirror of
+  what it enrolled, never a capacity-sized array;
+  ``install_device_rows`` adopts rows that are already on the chips.
 """
 
 from __future__ import annotations
@@ -31,12 +36,29 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from opencv_facerecognizer_tpu.parallel.mesh import DP_AXIS, TP_AXIS
+from opencv_facerecognizer_tpu.utils import metric_names as mn
 
 # numpy, not jnp: a module-level jnp scalar initializes the JAX backend at
 # IMPORT time, which blocks every importer (even transport-only child
 # processes) whenever the accelerator is unreachable. jnp ops accept the
 # numpy scalar identically.
 NEG_INF = np.float32(-1e30)
+
+#: ``jax.named_scope`` of each tier's search (the kernel, or the XLA dot and
+#: its top-k) and, beside it and never inside it, of what makes one answer
+#: of the shards' candidates: the gather over tp, the final top-k, the
+#: label gather. Siblings, because the benchmark's scope reader files an
+#: operation under the FIRST ``ocvf_<stage>`` of its ``tf_op``.
+MATCH_SCOPE = "ocvf_match"
+MERGE_SCOPE = "ocvf_merge"
+
+#: Jitted makers of one tier's arrays (``ShardedGallery._tier_jit``), keyed
+#: (mesh, dim, store dtype, pad label) + ("empty", capacity) | ("grow", old,
+#: new) | ("splice", donated?): a few small programs a kind of gallery,
+#: each compiled once a process however many galleries come and go. No
+#: lock: two threads that miss at once build the same program twice and
+#: the later one stays.
+_TIER_JITS: dict = {}
 
 
 def take_labels_with_sentinel(labels, idx, labels_pad: int):
@@ -72,38 +94,41 @@ def match_global(q, g, valid, labels, *, k: int, mesh: Mesh):
     tp = mesh.shape[TP_AXIS]
     cap = g.shape[0]
     chunk = cap // tp
-    # MXU block: bf16 operands, f32 accumulation.
-    sims = jax.lax.dot_general(
-        q.astype(jnp.bfloat16),
-        g.astype(jnp.bfloat16),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [Q, C]
-    sims = jnp.where(valid[None, :], sims, NEG_INF)
-    qn = sims.shape[0]
-    if tp == 1:
-        # Singleton tp: the two-phase split is identical math but the
-        # reshape + sharding constraint break XLA's matmul->top_k fusion
-        # (measured on v5e: 2.40 vs 1.00 ms/batch for the whole fused
-        # serving step at 16k rows) — take the direct top_k.
-        top_vals, top_gidx = jax.lax.top_k(sims, min(k, cap))
-        return jnp.take(labels, top_gidx), top_vals, top_gidx
-    # Phase 1: per-chunk top-k, chunk == tp shard (the constraint pins the
-    # reshape to be shard-local).
-    s3 = sims.reshape(qn, tp, chunk)
-    s3 = jax.lax.with_sharding_constraint(
-        s3, NamedSharding(mesh, P(DP_AXIS, TP_AXIS, None))
-    )
+    qn = q.shape[0]
     local_k = min(k, chunk)
-    vals, idx = jax.lax.top_k(s3, local_k)  # [Q, tp, local_k]
-    gidx = idx + (jnp.arange(tp, dtype=jnp.int32) * chunk)[None, :, None]
-    # Phase 2: merge the tp*local_k candidates (tiny; XLA gathers these).
-    vals2 = vals.reshape(qn, tp * local_k)
-    gidx2 = gidx.reshape(qn, tp * local_k)
-    out_k = min(k, tp * local_k)
-    top_vals, pos = jax.lax.top_k(vals2, out_k)
-    top_gidx = jnp.take_along_axis(gidx2, pos, axis=1)
-    top_labels = jnp.take(labels, top_gidx)
+    with jax.named_scope(MATCH_SCOPE):
+        # MXU block: bf16 operands, f32 accumulation.
+        sims = jax.lax.dot_general(
+            q.astype(jnp.bfloat16),
+            g.astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [Q, C]
+        sims = jnp.where(valid[None, :], sims, NEG_INF)
+        if tp == 1:
+            # Singleton tp: the two-phase split is identical math but the
+            # reshape + sharding constraint break XLA's matmul->top_k fusion
+            # (measured on v5e: 2.40 vs 1.00 ms/batch for the whole fused
+            # serving step at 16k rows) — take the direct top_k.
+            top_vals, top_gidx = jax.lax.top_k(sims, min(k, cap))
+        else:
+            # Phase 1: per-chunk top-k, chunk == tp shard (the constraint
+            # pins the reshape to be shard-local).
+            s3 = sims.reshape(qn, tp, chunk)
+            s3 = jax.lax.with_sharding_constraint(
+                s3, NamedSharding(mesh, P(DP_AXIS, TP_AXIS, None))
+            )
+            vals, idx = jax.lax.top_k(s3, local_k)  # [Q, tp, local_k]
+            gidx = idx + (jnp.arange(tp, dtype=jnp.int32) * chunk)[None, :, None]
+    with jax.named_scope(MERGE_SCOPE):
+        if tp > 1:
+            # Phase 2: merge the tp*local_k candidates (tiny; XLA gathers
+            # these).
+            vals2 = vals.reshape(qn, tp * local_k)
+            gidx2 = gidx.reshape(qn, tp * local_k)
+            top_vals, pos = jax.lax.top_k(vals2, min(k, tp * local_k))
+            top_gidx = jnp.take_along_axis(gidx2, pos, axis=1)
+        top_labels = jnp.take(labels, top_gidx)
     return top_labels, top_vals, top_gidx
 
 
@@ -120,11 +145,13 @@ def match_pod_pallas(q, g, valid, labels, *, k: int, mesh: Mesh,
     pallas fast path: GSPMD cannot partition a custom call, so the shard
     decomposition is written explicitly here.
 
-    Not the serving default: it has only ever run on a CPU mesh in
-    interpret mode (tests), never on several chips — ``ShardedGallery``
-    selects the GSPMD formulation whenever ``mesh.size > 1``. On a
-    multi-chip host it would pair the kernel's HBM savings with tp
-    scaling; not measured.
+    The serving matcher of a mesh of several TPU chips once a shard holds
+    ``PALLAS_MIN_CAPACITY`` rows (``ShardedGallery._pallas_enabled``
+    selects it, ``match_fn`` returns it). It has run on four v5e chips at
+    12,582,912 rows a shard; PERF.md section 6, PR 38, holds the numbers.
+    Equal similarities break toward the lowest GLOBAL row: the kernel
+    keeps the lowest local row, and the merge's ``top_k`` the candidate
+    of the lowest shard.
 
     Shapes/shardings: q [Q, D] dp-sharded; g [C, D] tp row-sharded;
     valid [C] tp-sharded; labels [C] replicated. Returns the same
@@ -136,20 +163,25 @@ def match_pod_pallas(q, g, valid, labels, *, k: int, mesh: Mesh,
     chunk = g.shape[0] // tp
 
     def shard_body(q_l, g_l, valid_l, labels_l):
-        vals, idx = streaming_match_topk(
-            q_l, g_l, valid_l, k=min(k, chunk), interpret=interpret
-        )
-        offset = jax.lax.axis_index(TP_AXIS).astype(jnp.int32) * chunk
-        # A shard with fewer valid rows than k emits sentinel -1 indices;
-        # keep them -1 instead of offsetting into a neighbor shard's rows.
-        idx = jnp.where(idx < 0, -1, idx + offset)
-        # One tiled gather each -> [Q, tp*local_k] candidates on every chip.
-        cand_v = jax.lax.all_gather(vals, TP_AXIS, axis=1, tiled=True)
-        cand_i = jax.lax.all_gather(idx, TP_AXIS, axis=1, tiled=True)
-        out_k = min(k, cand_v.shape[1])
-        top_v, pos = jax.lax.top_k(cand_v, out_k)
-        top_i = jnp.take_along_axis(cand_i, pos, axis=1)
-        return take_labels_with_sentinel(labels_l, top_i, labels_pad), top_v, top_i
+        with jax.named_scope(MATCH_SCOPE):
+            vals, idx = streaming_match_topk(
+                q_l, g_l, valid_l, k=min(k, chunk), interpret=interpret
+            )
+        with jax.named_scope(MERGE_SCOPE):
+            offset = jax.lax.axis_index(TP_AXIS).astype(jnp.int32) * chunk
+            # A shard with fewer valid rows than k emits sentinel -1
+            # indices; keep them -1 instead of offsetting into a neighbor
+            # shard's rows.
+            idx = jnp.where(idx < 0, -1, idx + offset)
+            # One tiled gather each -> [Q, tp*local_k] candidates on every
+            # chip.
+            cand_v = jax.lax.all_gather(vals, TP_AXIS, axis=1, tiled=True)
+            cand_i = jax.lax.all_gather(idx, TP_AXIS, axis=1, tiled=True)
+            out_k = min(k, cand_v.shape[1])
+            top_v, pos = jax.lax.top_k(cand_v, out_k)
+            top_i = jnp.take_along_axis(cand_i, pos, axis=1)
+            top_l = take_labels_with_sentinel(labels_l, top_i, labels_pad)
+        return top_l, top_v, top_i
 
     mapped = jax.shard_map(
         shard_body,
@@ -262,10 +294,27 @@ class ShardedGallery:
         self._emb_sharding = NamedSharding(mesh, P(TP_AXIS, None))
         self._lab_sharding = NamedSharding(mesh, P())
         self._valid_sharding = NamedSharding(mesh, P(TP_AXIS))
-        self._host_emb = np.zeros((self.capacity, dim), np.float32)
-        self._host_lab = np.full((self.capacity,), labels_pad, np.int32)
-        self._host_val = np.zeros((self.capacity,), bool)
+        #: backends that alias a donated input; elsewhere (CPU) a donation
+        #: is ignored with a warning per compile
+        self._donate = mesh.devices.flat[0].platform in ("tpu", "gpu")
+        # Host mirror: what was enrolled THROUGH THE HOST, and no more. It
+        # covers rows [_host_base, _host_base + len) and grows with them
+        # (``_mirror_write``); no array of capacity x dim is ever made on
+        # the host at construction or on ``add``. Rows below ``_host_base``
+        # were installed on the device (``install_device_rows``) and have
+        # their truth there; ``snapshot`` reads them back when asked.
+        self._host_base = 0
+        self._host_emb = np.zeros((0, self.dim), np.float32)
+        self._host_lab = np.zeros((0,), np.int32)
+        self._host_val = np.zeros((0,), bool)
         self._write_lock = threading.Lock()
+        #: always-on tallies (``utils.metric_names``): rows that crossed
+        #: the host->device link, whole-set installs from device arrays.
+        #: ``attach_observability`` carries them into a ``Metrics``.
+        self.metrics = None
+        self.tracer = None
+        self.rows_uploaded = 0
+        self.bulk_installs = 0
         self.grow_count = 0
         # ---- async (off-the-serving-path) growth state ----
         # ``async_grow=True`` turns an overflowing add() into: stage the
@@ -297,8 +346,6 @@ class ShardedGallery:
         self._epoch = 0  # bumped by reset/swap_from to invalidate a grow
         self._warmed_capacities = set()
         self._warm_events = {}  # capacity -> Event, set when its warm ends
-        self._chunk_jit = None  # (key, zeros, update) for _chunked_emb_put
-        self._bitcast_jit = None  # u16 -> bf16 device bitcast (_put_emb)
         self.last_grow_info: dict = {}
         # ---- optional IVF coarse quantizer (parallel.quantizer) ----
         # Derived state: the gallery drives every lifecycle edge —
@@ -308,19 +355,7 @@ class ShardedGallery:
         # ready), "auto" switches at IVF_MIN_CAPACITY.
         self.quantizer = None
         self.match_mode = "exact"
-        self._data = GalleryData(
-            embeddings=jax.device_put(
-                jnp.zeros((self.capacity, dim), self.store_dtype),
-                self._emb_sharding
-            ),
-            labels=jax.device_put(
-                jnp.full((self.capacity,), labels_pad, jnp.int32), self._lab_sharding
-            ),
-            valid=jax.device_put(
-                jnp.zeros((self.capacity,), bool), self._valid_sharding
-            ),
-            size=0,
-        )
+        self._data = GalleryData(*self._empty_arrays(self.capacity), size=0)
         self._match_cache = {}
 
     # Single-attribute snapshot: the only device-state read path.
@@ -355,27 +390,236 @@ class ShardedGallery:
     def _host_cast(self, x: np.ndarray) -> np.ndarray:
         """Cast to store_dtype on the host so the H2D wire carries the
         narrow bytes (ml_dtypes' f32->bf16 astype measures ~640M el/s —
-        not a bottleneck)."""
-        if self.store_dtype == np.float32:
-            return np.asarray(x, np.float32)
-        return np.asarray(x).astype(self.store_dtype)
-
-    def _put_emb(self, emb_np: np.ndarray) -> jnp.ndarray:
-        """device_put of gallery rows (``_emb_sharding``) in store_dtype
-        width. bf16 ships as uint16 + a device-side bitcast: the same bits
+        not a bottleneck). bf16 ships as its uint16 bits, the same bytes
         as a standard numpy dtype, which every PJRT client puts through
-        its fast path, and the bitcast is a free layout op on device.
+        its fast path; ``_splice_fn`` bitcasts them back on the device,
+        inside the program that splices them (no array of its own).
         (Whether a plain ml_dtypes bf16 put is slower on the local chip:
         not measured.)"""
-        cast = self._host_cast(emb_np)
-        if self.store_dtype != jnp.bfloat16:
-            return jax.device_put(cast, self._emb_sharding)
-        if self._bitcast_jit is None:
-            self._bitcast_jit = jax.jit(
-                lambda a: jax.lax.bitcast_convert_type(a, jnp.bfloat16),
-                out_shardings=self._emb_sharding)
-        dev_u16 = jax.device_put(cast.view(np.uint16), self._emb_sharding)
-        return self._bitcast_jit(dev_u16)
+        if self.store_dtype == np.float32:
+            return np.asarray(x, np.float32)
+        cast = np.asarray(x).astype(self.store_dtype)
+        return cast.view(np.uint16) if self.store_dtype == jnp.bfloat16 else cast
+
+    # ---- one tier's device arrays: made, grown and spliced ON the devices ----
+
+    def _tier_jit(self, key, build):
+        """The jitted maker ``key`` of this gallery's kind of tier, shared
+        by every gallery of the same mesh, width, dtype and pad label in
+        the process: a replica's re-anchor or a rollout's staged gallery
+        compiles nothing a gallery before it has compiled."""
+        key = (self.mesh, self.dim, self.store_dtype, self.labels_pad, *key)
+        fn = _TIER_JITS.get(key)
+        if fn is None:
+            fn = _TIER_JITS[key] = build()
+        return fn
+
+    def _tier_shardings(self):
+        return (self._emb_sharding, self._lab_sharding, self._valid_sharding)
+
+    def _empty_arrays(self, capacity: int):
+        """(embeddings, labels, valid) of an empty tier: zero rows, pad
+        labels, nothing valid, each shard made on the chip that holds it —
+        no host array, and no single-device array of the whole tier."""
+        dim, dtype, pad = self.dim, self.store_dtype, self.labels_pad
+        make = self._tier_jit(("empty", capacity), lambda: jax.jit(
+            lambda: (jnp.zeros((capacity, dim), dtype),
+                     jnp.full((capacity,), pad, jnp.int32),
+                     jnp.zeros((capacity,), bool)),
+            out_shardings=self._tier_shardings()))
+        return make()
+
+    def _grown_arrays(self, arrays, capacity: int):
+        """``arrays`` padded out to ``capacity`` rows on the devices (a
+        tier's shard boundaries move with its capacity, so rows change
+        chips: XLA's collectives, nothing through the host). The result
+        is the caller's own; ``arrays`` stay valid for their readers."""
+        old = int(arrays[0].shape[0])
+        extra, pad = capacity - old, self.labels_pad
+        grow = self._tier_jit(("grow", old, capacity), lambda: jax.jit(
+            lambda e, l, v: (jnp.pad(e, ((0, extra), (0, 0))),
+                             jnp.pad(l, (0, extra), constant_values=pad),
+                             jnp.pad(v, (0, extra))),
+            out_shardings=self._tier_shardings()))
+        return grow(*arrays)
+
+    def _splice_fn(self, donate: bool):
+        """Jitted ``(emb, lab, val, rows, labs, vals, start, count) ->
+        (emb, lab, val)``: rows [start, start + count) replaced by the
+        first ``count`` of the piece handed in, everything else as it was.
+        ``shard_map`` over tp, so a shard writes the rows that fall in its
+        own range and drops the rest; the piece arrives replicated (its
+        own rows, never a shard's worth). Without ``donate`` the result is
+        a copy and the inputs stay valid for whoever reads them."""
+        def build():
+            store = self.store_dtype
+
+            def body(emb_l, lab, val_l, rows, labs, vals, start, count):
+                chunk, n = emb_l.shape[0], rows.shape[0]
+                if rows.dtype != store:  # bf16 travels as its uint16 bits
+                    rows = jax.lax.bitcast_convert_type(rows, store)
+                lo = jax.lax.axis_index(TP_AXIS).astype(jnp.int32) * chunk
+                at = jnp.arange(n, dtype=jnp.int32)
+                pos = start - lo + at
+                # the piece's padding, and rows outside this shard: an
+                # index past the end, dropped
+                pos = jnp.where((at < count) & (pos >= 0) & (pos < chunk),
+                                pos, chunk)
+                emb_l = emb_l.at[pos].set(rows, mode="drop")
+                val_l = val_l.at[pos].set(vals, mode="drop")
+                lab = lab.at[jnp.where(at < count, start + at,
+                                       lab.shape[0])].set(labs, mode="drop")
+                return emb_l, lab, val_l
+
+            mapped = jax.shard_map(
+                body, mesh=self.mesh,
+                in_specs=(P(TP_AXIS, None), P(), P(TP_AXIS)) + (P(),) * 5,
+                out_specs=(P(TP_AXIS, None), P(), P(TP_AXIS)),
+                check_vma=False)
+            return jax.jit(mapped, donate_argnums=(0, 1, 2) if donate else ())
+
+        return self._tier_jit(("splice", donate), build)
+
+    #: rows a piece of an upload holds, beside the most that
+    #: ``CHUNK_UPLOAD_BYTES`` allows (65,536 at 256-d bf16): a kind of
+    #: gallery compiles three splice programs, twice over, however many
+    #: rows its installs bring. A piece cut to the rows it carried met a
+    #: new compile at every new size: a replica's re-anchor of 181 rows
+    #: took 0.4 s for five of them, and the soak tests' deadlines found it
+    #: (CPU; PR 38). The price is padding on the wire, under 512 rows an
+    #: install and never over eight times the rows it carries: a rest of
+    #: up to 64 rows goes in pieces of 8, the program every enrolment of a
+    #: few images has compiled already (a replica's re-anchor of 12 rows
+    #: that met the 512-row program's first compile held its readers up
+    #: long enough to fail the registry soak under a loaded CPU).
+    PIECE_ROWS = (8, 512)
+
+    def _splice_rows(self, arrays, emb: np.ndarray, lab: np.ndarray,
+                     val: np.ndarray, start: int, *, owned: bool,
+                     paced: bool = False, cancel=None, info=None):
+        """Host rows ``emb`` [n, dim] (float32), their labels and validity
+        into the device ``arrays`` at row ``start``: these n rows and the
+        padding of the last piece are all that crosses the host->device
+        link. With ``owned`` False the arrays belong to a published
+        snapshot: the first piece copies them (readers keep theirs; two
+        tier-sized arrays a chip is the most that is ever live), later
+        pieces update that copy in place.
+
+        Whole pieces of the most rows ``CHUNK_UPLOAD_BYTES`` allows, then
+        whole pieces of 512, then the rest: up to 64 rows in pieces of 8,
+        more in ONE piece of 512, the last piece padded out on the wire
+        with rows the program drops (``PIECE_ROWS``). An enrolment of 65
+        to 512 rows is one dispatch, a smaller one at most eight.
+        ``paced`` (the grow worker) awaits each
+        piece before the next is queued, so a serving transfer never waits
+        behind more than one piece on the link; each piece gets its OWN
+        deadline (``CHUNK_PACING_TIMEOUT_S``), flagged in ``info`` when it
+        expires, and the FIRST pacing failure stops pacing for the rest:
+        under a hang-mode backend the stall is one deadline, not pieces *
+        deadline (the residency wait still gates the publish). ``cancel``
+        is sampled between pieces and inside the pacing poll, so a reset
+        aborts within one tick."""
+        import time as _time
+
+        t0 = _time.monotonic()
+        n = int(len(emb))
+        row_bytes = self.dim * self.store_dtype.itemsize
+        most = max(1, self.CHUNK_UPLOAD_BYTES // row_bytes)
+        sizes = [r for r in self.PIECE_ROWS if r < most] + [most]
+        small = sizes[0]
+        rep = self._lab_sharding  # replicated over the mesh
+        lab = np.asarray(lab, np.int32)
+        val = np.asarray(val, bool)
+        at = 0
+        while at < n:
+            if cancel is not None and cancel():
+                return arrays  # doomed snapshot; publish check discards it
+            left = n - at
+            count = next((r for r in reversed(sizes[1:] or sizes) if r <= left),
+                         small if small < left <= 8 * small else left)
+            pad = next(r for r in sizes if r >= count) - count
+            cut = (self._host_cast(emb[at:at + count]), lab[at:at + count],
+                   val[at:at + count])
+            if pad:
+                cut = tuple(np.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                            for a in cut)
+            sent = jax.device_put(cut, rep)
+            arrays = self._splice_fn(owned and self._donate)(
+                *arrays, *sent, np.int32(start + at), np.int32(count))
+            owned = True
+            at += count
+            if paced:
+                paced = self._pace_chunk(
+                    arrays[0], _time.monotonic() + self.CHUNK_PACING_TIMEOUT_S,
+                    cancel=cancel, info=info)
+        self.rows_uploaded += n
+        if self.metrics is not None:
+            self.metrics.incr(mn.GALLERY_ROWS_UPLOADED, n)
+        self._emit_install(t0, rows=n, nbytes=n * row_bytes, source="host")
+        return arrays
+
+    def _emit_install(self, t0: float, rows: int, nbytes: int,
+                      source: str) -> None:
+        """Lifecycle span ``gallery_install``: the rows and bytes one
+        install moved (``source`` host: over the link; device: adopted)."""
+        if self.tracer is not None:
+            import time as _time
+
+            from opencv_facerecognizer_tpu.utils.tracing import LIFECYCLE_TOPIC
+
+            self.tracer.emit(
+                self.tracer.new_trace(), "gallery_install",
+                topic=LIFECYCLE_TOPIC, t0=t0, dur=_time.monotonic() - t0,
+                rows=rows, bytes=nbytes, source=source)
+
+    def attach_observability(self, metrics, tracer=None) -> None:
+        """Wire the serving process's ``Metrics`` and ``Tracer`` (built
+        after the gallery is): the gauge ``gallery_shards``, and the two
+        counters brought up to what start-up already did."""
+        fresh = metrics is not None and metrics is not self.metrics
+        self.metrics, self.tracer = metrics, tracer
+        if fresh:
+            metrics.set_gauge(mn.GALLERY_SHARDS, self.mesh.shape[TP_AXIS])
+            metrics.incr(mn.GALLERY_ROWS_UPLOADED, self.rows_uploaded)
+            metrics.incr(mn.GALLERY_BULK_INSTALLS, self.bulk_installs)
+
+    def _mirror_write(self, at: int, emb: np.ndarray, lab: np.ndarray) -> None:
+        """Rows [at, at + n) into the host mirror, which grows (doubling)
+        to hold them; the caller holds the write lock."""
+        lo = at - self._host_base
+        need = lo + len(emb)
+        have = len(self._host_lab)
+        if need > have:
+            rows = max(need, min(max(2 * have, 64),
+                                 self.capacity - self._host_base))
+            grown = (np.zeros((rows, self.dim), np.float32),
+                     np.full((rows,), self.labels_pad, np.int32),
+                     np.zeros((rows,), bool))
+            for new, old in zip(grown, (self._host_emb, self._host_lab,
+                                        self._host_val)):
+                new[:have] = old
+            self._host_emb, self._host_lab, self._host_val = grown
+        self._host_emb[lo:need] = emb
+        self._host_lab[lo:need] = lab
+        self._host_val[lo:need] = True
+
+    def host_valid(self) -> np.ndarray:
+        """Validity of rows [0, mirrored end), the rows a quantizer's
+        catch-up walks; the caller holds the write lock. Rows installed on
+        the device are read back from the snapshot."""
+        if not self._host_base:
+            return self._host_val.copy()
+        return np.concatenate([
+            np.asarray(self._data.valid[:self._host_base]), self._host_val])
+
+    def host_rows(self, lo: int, hi: int) -> np.ndarray:
+        """Float32 rows [lo, hi): from the mirror, or read back from the
+        device where they were installed there. Caller holds the lock."""
+        base = self._host_base
+        if lo >= base:
+            return self._host_emb[lo - base:hi - base]
+        head = np.asarray(self._data.embeddings[lo:min(hi, base)], np.float32)
+        return np.concatenate([head, self._host_emb[:max(hi - base, 0)]])
 
     def add(self, embeddings: np.ndarray, labels: np.ndarray) -> None:
         """Append L2-normalized rows, auto-growing on overflow.
@@ -451,16 +695,18 @@ class ShardedGallery:
                     self._grow_done.clear()
                     start_worker = True
             else:
+                data = self._data
+                arrays, owned = (data.embeddings, data.labels, data.valid), False
                 if size + n > self.capacity:
                     evict_below = self.capacity  # tier being replaced
-                    self._grow_locked(size + n)
-                # Host mirrors are the source of truth for enrolment: no
-                # device readback is needed (or wanted) under the lock.
+                    self.capacity = self._next_capacity(size + n)
+                    self.grow_count += 1
+                    arrays, owned = self._grown_arrays(arrays, self.capacity), True
+                # The host mirror is the truth of what the host enrolled:
+                # no device readback is needed (or wanted) under the lock.
                 if not normalized:  # lost the branch-predict race
                     embeddings = self._normalize_rows(embeddings)
-                self._host_emb[size : size + n] = embeddings
-                self._host_lab[size : size + n] = labels
-                self._host_val[size : size + n] = True
+                self._mirror_write(size, embeddings, labels)
                 if self.quantizer is not None:
                     # Incremental IVF assignment, under the same write
                     # lock as the mirror update: the rows land in their
@@ -468,8 +714,14 @@ class ShardedGallery:
                     # publishes them as matchable, so the two-stage path
                     # never misses a row the exact path would find.
                     self.quantizer.on_rows_added(embeddings, size)
-                self._install(self._host_emb, self._host_lab, self._host_val,
-                              size + n)
+                # These n rows cross the link and are spliced into a copy
+                # of the served arrays on the devices; ONE attribute write
+                # publishes, so a reader never sees a partial install.
+                arrays = self._splice_rows(arrays, embeddings, labels,
+                                           np.ones((n,), bool), size,
+                                           owned=owned)
+                self._data = GalleryData(*arrays, size=size + n,
+                                         epoch=self._epoch)
         if evict_below is not None:
             self._evict_stale(evict_below)
         if not self._growing:
@@ -595,11 +847,12 @@ class ShardedGallery:
         return False
 
     def _grow_worker(self) -> None:
-        """Off-the-serving-path growth: compile (hooks) -> copy ->
-        normalize staged rows -> splice -> upload -> await residency ->
-        atomic publish. Serving threads keep reading the OLD snapshot
-        until the grown arrays are device-resident — publishing earlier
-        makes the first new-tier call absorb the whole H2D transfer.
+        """Off-the-serving-path growth: compile (hooks) -> copy the served
+        rows into the next tier ON the devices -> normalize staged rows ->
+        upload and splice them (paced) -> await residency -> atomic
+        publish. Serving threads keep reading the OLD snapshot until the
+        grown arrays are device-resident — publishing earlier makes the
+        first new-tier call absorb the staged rows' H2D transfer.
         ``reset``/``swap_from`` bump ``_epoch`` to invalidate an in-flight
         grow; the epoch is re-checked at splice AND at publish, so a
         reset during the residency wait wins and the stale snapshot is
@@ -623,22 +876,17 @@ class ShardedGallery:
                         self.last_grow_info = info
                         return
                     epoch = self._epoch
-                    size = self.size
+                    old = self._data  # adds stage while a grow is in
+                    # flight: only an epoch bump can replace it
+                    size = old.size
                     pending_n = self._pending_count
-                    old_emb, old_lab, old_val = (
-                        self._host_emb, self._host_lab, self._host_val,
-                    )
                     old_cap = self.capacity
                 target = self._next_capacity(size + pending_n)
                 # Compile the new tier's graphs BEFORE taking rows live.
                 self._run_prewarm_hooks(target, info)
                 t0 = _time.perf_counter()
-                emb = np.zeros((target, self.dim), np.float32)
-                lab = np.full((target,), self.labels_pad, np.int32)
-                val = np.zeros((target,), bool)
-                emb[:old_cap] = old_emb
-                lab[:old_cap] = old_lab
-                val[:old_cap] = old_val
+                grown = self._grown_arrays(
+                    (old.embeddings, old.labels, old.valid), target)
                 info["copy_s"] = round(_time.perf_counter() - t0, 3)
                 # Normalize staged rows here, not on the enrolling thread
                 # (add() stages raw). In-place entry mutation is GIL-atomic
@@ -677,20 +925,20 @@ class ShardedGallery:
                         self._pending.pop(0)
                     spliced = fits  # restored by the except path if the
                     # upload below dies before these rows publish
-                    pos = size
-                    for e_rows, l_rows, _ in fits:
-                        emb[pos : pos + len(e_rows)] = e_rows
-                        lab[pos : pos + len(e_rows)] = l_rows
-                        val[pos : pos + len(e_rows)] = True
-                        pos += len(e_rows)
+                    emb = np.concatenate(
+                        [e for e, _l, _ in fits]
+                        or [np.zeros((0, self.dim), np.float32)])
+                    lab = np.concatenate(
+                        [l for _e, l, _ in fits] or [np.zeros((0,), np.int32)])
                 # Upload OUTSIDE the lock and wait for residency while
                 # serving threads still read the old tier. A reset/swap
                 # epoch bump cancels the wait immediately.
                 t0 = _time.perf_counter()
                 new_data = self._build_snapshot(
-                    emb, lab, val, pos, chunked=True,
-                    cancel=lambda: self._epoch != epoch, info=info,
-                    epoch=epoch)
+                    emb, lab, np.ones((n_fit,), bool), size + n_fit,
+                    chunked=True, cancel=lambda: self._epoch != epoch,
+                    info=info, epoch=epoch, onto=grown, start=size)
+                del grown
                 if not self._await_residency(new_data, self.RESIDENCY_TIMEOUT_S,
                                              cancel=lambda: self._epoch != epoch,
                                              info=info):
@@ -702,7 +950,7 @@ class ShardedGallery:
                         continue  # a reset/swap during the wait wins; the
                         # spliced rows are discarded exactly as the reset
                         # discarded the rest of pending
-                    self._host_emb, self._host_lab, self._host_val = emb, lab, val
+                    self._mirror_write(size, emb, lab)
                     self.capacity = target
                     self.grow_count += 1
                     self._pending_count -= n_fit
@@ -734,24 +982,6 @@ class ShardedGallery:
                 self._grow_done.set()
                 self.last_grow_info = info
 
-    def _grow_locked(self, needed: int) -> None:
-        """Double capacity (tp-aligned) until ``needed`` rows fit; caller
-        holds the write lock."""
-        tp = self.mesh.shape[TP_AXIS]
-        new_capacity = max(self.capacity, 1)
-        while new_capacity < needed:
-            new_capacity *= 2
-        new_capacity = int(np.ceil(new_capacity / tp) * tp)
-        emb = np.zeros((new_capacity, self.dim), np.float32)
-        lab = np.full((new_capacity,), self.labels_pad, np.int32)
-        val = np.zeros((new_capacity,), bool)
-        emb[: self.capacity] = self._host_emb
-        lab[: self.capacity] = self._host_lab
-        val[: self.capacity] = self._host_val
-        self._host_emb, self._host_lab, self._host_val = emb, lab, val
-        self.capacity = new_capacity
-        self.grow_count += 1
-
     def _evict_stale(self, below_capacity: int) -> None:
         """Drop compiled executables for tiers strictly below
         ``below_capacity`` — called after a grow publishes, with the
@@ -781,18 +1011,23 @@ class ShardedGallery:
             self._pending_count = 0
             if self.quantizer is not None:
                 self.quantizer.invalidate()
-            self._host_emb = np.zeros((self.capacity, self.dim), np.float32)
-            self._host_lab = np.full((self.capacity,), self.labels_pad, np.int32)
-            self._host_val = np.zeros((self.capacity,), bool)
-            self._install(self._host_emb, self._host_lab, self._host_val, 0)
+            self._drop_mirror()
+            self._data = GalleryData(*self._empty_arrays(self.capacity),
+                                     size=0, epoch=self._epoch)
 
-    #: grow-worker uploads larger than 2x this are split into chunks of
-    #: this many bytes, PACED one at a time: a serving transfer queued
-    #: behind an un-chunked 1 GB gallery H2D waits for all of it
-    #: (queue-head blocking on the host->device link). Pacing (await each
-    #: chunk before queueing the next) bounds any concurrent serving
-    #: transfer's wait to ~one chunk. Whether the local chip's link is
-    #: slow enough for this to matter: not measured.
+    def _drop_mirror(self, base: int = 0) -> None:
+        self._host_base = int(base)
+        self._host_emb = np.zeros((0, self.dim), np.float32)
+        self._host_lab = np.zeros((0,), np.int32)
+        self._host_val = np.zeros((0,), bool)
+
+    #: no piece of an upload is larger than this many bytes
+    #: (``_splice_rows``), and the grow worker's pieces are PACED one at a
+    #: time: a serving transfer queued behind an un-chunked 1 GB gallery
+    #: H2D waits for all of it (queue-head blocking on the host->device
+    #: link). Pacing (await each piece before queueing the next) bounds
+    #: any concurrent serving transfer's wait to ~one piece. Whether the
+    #: local chip's link is slow enough for this to matter: not measured.
     CHUNK_UPLOAD_BYTES = 32 * 1024 * 1024
 
     #: per-CHUNK pacing deadline (round-5 advisor: one shared deadline
@@ -832,83 +1067,100 @@ class ShardedGallery:
                 return False
             _time.sleep(0.02)
 
-    def _chunked_emb_put(self, emb: np.ndarray, cancel=None,
-                         info=None) -> jnp.ndarray:
-        """Upload the embedding matrix in paced chunks: device-side zeros
-        (no transfer), then donated dynamic_update_slice per chunk, each
-        awaited (non-blocking is_ready poll) before the next is queued.
-        The device-side copies are HBM-bandwidth cheap; the win is that
-        the host->device link is released between chunks. Each chunk gets its
-        OWN pacing deadline (``CHUNK_PACING_TIMEOUT_S``) — a single slow
-        chunk degrades only itself, flagged in info — and ``cancel`` is
-        sampled inside the poll so a reset aborts within one poll tick.
-        The FIRST pacing failure (timeout or no ``is_ready``) stops pacing
-        for the remaining chunks: under a hang-mode backend the total
-        stall is bounded by one chunk deadline, not chunks * deadline
-        (the final residency wait still gates the publish either way)."""
-        import time as _time
-
-        cap, dim = emb.shape
-        itemsize = self.store_dtype.itemsize
-        rows = max(1, self.CHUNK_UPLOAD_BYTES // (dim * itemsize))
-        key = (cap, dim, self.store_dtype)
-        if getattr(self, "_chunk_jit", None) is None or self._chunk_jit[0] != key:
-            zeros = jax.jit(lambda: jnp.zeros((cap, dim), self.store_dtype),
-                            out_shardings=self._emb_sharding)
-            update = jax.jit(
-                lambda b, c, i: jax.lax.dynamic_update_slice(b, c, (i, 0)),
-                donate_argnums=0, out_shardings=self._emb_sharding)
-            self._chunk_jit = (key, zeros, update)
-        _, zeros, update = self._chunk_jit
-        buf = zeros()
-        pacing = True
-        for start in range(0, cap, rows):
-            if cancel is not None and cancel():
-                return buf  # doomed snapshot; publish check discards it
-            # Host-side cast BEFORE the put: the transfer itself must be
-            # store_dtype-width (an on-device cast would ship f32 bytes).
-            chunk = self._put_emb(emb[start:start + rows])
-            buf = update(buf, chunk, np.int32(start))
-            if pacing:
-                pacing = self._pace_chunk(
-                    buf, _time.monotonic() + self.CHUNK_PACING_TIMEOUT_S,
-                    cancel=cancel, info=info)
-        return buf
-
     def _build_snapshot(self, emb: np.ndarray, lab: np.ndarray,
                         val: np.ndarray, size: int,
                         chunked: bool = False, cancel=None,
-                        info=None, epoch: Optional[int] = None) -> GalleryData:
-        """Device-put the arrays WITHOUT publishing (the async grow worker
-        waits for residency between build and publish). ``chunked`` (grow
-        worker only) paces the big embedding upload so concurrent serving
-        transfers are not head-blocked behind it; labels/valid are small
-        (5 MB at 1M rows) and always go direct. Chunking is scoped to
-        single-device meshes — the serving config this was measured on,
-        and the only one where it's a pure win: with tp>1 the
-        dynamic-offset update operand cannot be proven shard-local, so
-        GSPMD replicates every chunk to all devices (~tp x the transfer
-        bytes), while the direct sharded put moves each row exactly once.
-        On multi-host pods each host also uploads only its own shards
-        over its own link."""
-        if (chunked and emb.nbytes > 2 * self.CHUNK_UPLOAD_BYTES
-                and len(self.mesh.devices.flat) == 1):
-            emb_dev = self._chunked_emb_put(emb, cancel=cancel, info=info)
-        else:
-            # Host-side cast so the wire carries store_dtype-width bytes.
-            emb_dev = self._put_emb(emb)
-        return GalleryData(
-            embeddings=emb_dev,
-            labels=jax.device_put(jnp.asarray(lab), self._lab_sharding),
-            valid=jax.device_put(jnp.asarray(val), self._valid_sharding),
-            size=size,
-            epoch=self._epoch if epoch is None else epoch,
-        )
+                        info=None, epoch: Optional[int] = None,
+                        onto=None, start: int = 0) -> GalleryData:
+        """A device snapshot WITHOUT publishing it (the async grow worker
+        waits for residency between build and publish): host rows ``emb``
+        with their labels and validity land at rows [start, start + n) of
+        ``onto``, device arrays the caller owns (the grow worker's next
+        tier, already holding the served rows), or of a fresh empty tier
+        of the current capacity (``load_snapshot``, a recast swap). Only
+        the rows handed in cross the link, cast on the host so the wire
+        carries store_dtype-width bytes, and no third tier-sized array is
+        ever live: the old snapshot and the one being built. ``chunked``
+        (grow worker only) paces the pieces so concurrent serving
+        transfers are not head-blocked behind them. On a multi-chip mesh
+        every chip receives the n rows and keeps those of its shard; on
+        multi-host pods each host uploads over its own link."""
+        if onto is None:
+            onto = self._empty_arrays(self.capacity)
+        arrays = self._splice_rows(onto, emb, lab, val, start, owned=True,
+                                   paced=chunked, cancel=cancel, info=info)
+        return GalleryData(*arrays, size=size,
+                           epoch=self._epoch if epoch is None else epoch)
 
     def _install(self, emb: np.ndarray, lab: np.ndarray, val: np.ndarray, size: int) -> None:
-        # Build the full snapshot first, publish with ONE attribute write —
+        # A whole-set install from host rows (rows 0..n of a fresh tier):
+        # build the full snapshot first, publish with ONE attribute write —
         # serving threads reading self._data never see a partial install.
         self._data = self._build_snapshot(emb, lab, val, size)
+
+    def install_device_rows(self, embeddings, labels, valid, size: int) -> None:
+        """Adopt device arrays as the next snapshot: the bulk install that
+        never touches the host (a watchlist drawn, loaded or re-embedded
+        on the chips). ``embeddings`` [C, dim] in ``store_dtype`` sharded
+        over tp by rows, ``labels`` [C] int32, ``valid`` [C] bool; rows
+        [0, size) are the enrolled ones. C becomes the capacity. Arrays
+        that already carry the gallery's shardings are adopted as they
+        are, nothing is copied and nothing crosses the link.
+
+        A whole-set install like ``load_snapshot`` and ``swap_from``, by
+        the same rules: under the write lock, the epoch bumped (an
+        in-flight async grow is dropped, a quantizer invalidated and
+        retrained in the background), one attribute write publishes. The
+        truth of these rows is on the device: a later ``add`` appends
+        after them and keeps them, and ``snapshot()`` reads them back when
+        it is called, not before. The caller must not donate or mutate
+        the arrays afterwards."""
+        import time as _time
+
+        t0 = _time.monotonic()
+        tp = self.mesh.shape[TP_AXIS]
+        rows = int(embeddings.shape[0])
+        if (embeddings.ndim != 2 or embeddings.shape[1] != self.dim
+                or rows % tp or labels.shape != (rows,)
+                or valid.shape != (rows,)):
+            raise ValueError(
+                f"install_device_rows takes [C, {self.dim}] rows with C a "
+                f"multiple of tp={tp}, and [C] labels and validity; got "
+                f"{embeddings.shape}, {labels.shape}, {valid.shape}")
+        if (embeddings.dtype != self.store_dtype or labels.dtype != jnp.int32
+                or valid.dtype != jnp.bool_):
+            raise ValueError(
+                f"install_device_rows takes {self.store_dtype.name} rows, "
+                f"int32 labels and bool validity; got {embeddings.dtype}, "
+                f"{labels.dtype}, {valid.dtype}")
+        if not 0 <= int(size) <= rows:
+            raise ValueError(f"size {size} outside [0, {rows}]")
+        arrays = tuple(
+            a if getattr(a, "sharding", None) == sh else jax.device_put(a, sh)
+            for a, sh in zip((embeddings, labels, valid),
+                             self._tier_shardings()))
+        evict_below = None
+        with self._write_lock:
+            self._epoch += 1  # invalidate any in-flight async grow
+            self._pending.clear()
+            self._pending_count = 0
+            if self.quantizer is not None:
+                self.quantizer.invalidate()
+            if rows > self.capacity:
+                evict_below = self.capacity
+            self.capacity = rows
+            self._drop_mirror(base=int(size))
+            self._data = GalleryData(*arrays, size=int(size),
+                                     epoch=self._epoch)
+            self.bulk_installs += 1
+        if self.metrics is not None:
+            self.metrics.incr(mn.GALLERY_BULK_INSTALLS)
+        self._emit_install(
+            t0, rows=int(size), source="device",
+            nbytes=int(size) * self.dim * self.store_dtype.itemsize)
+        if evict_below is not None:
+            self._evict_stale(evict_below)
+        self._poke_quantizer()
 
     #: bounded wait for the write lock in snapshot(): long enough that a
     #: normal add/grow-splice holding it finishes, short enough that a
@@ -919,19 +1171,30 @@ class ShardedGallery:
     SNAPSHOT_LOCK_TIMEOUT_S = 5.0
 
     def snapshot(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-        """Host-mirror copies (no device readback). Prefers the write lock
-        (a copy racing a grow splice must not capture a half-written row
-        set) but the acquire is BOUNDED: if a hung device_put is holding
-        the lock past ``SNAPSHOT_LOCK_TIMEOUT_S``, fall back to lock-free
-        copies — best-effort state now beats a guaranteed wedge."""
+        """Whole-capacity host copies (emb f32, labels, valid, size), made
+        when asked: the host mirror laid into fresh arrays, and rows that
+        were installed on the device (``install_device_rows``) read back
+        from it now. Rows the host enrolled need no device readback.
+        Prefers the write lock (a copy racing a grow splice must not
+        capture a half-written row set) but the acquire is BOUNDED: if a
+        hung device_put is holding the lock past
+        ``SNAPSHOT_LOCK_TIMEOUT_S``, fall back to lock-free copies —
+        best-effort state now beats a guaranteed wedge."""
         acquired = self._write_lock.acquire(timeout=self.SNAPSHOT_LOCK_TIMEOUT_S)
         try:
-            return (
-                self._host_emb.copy(),
-                self._host_lab.copy(),
-                self._host_val.copy(),
-                self.size,
-            )
+            data, base, cap = self._data, self._host_base, self.capacity
+            emb = np.zeros((cap, self.dim), np.float32)
+            lab = np.full((cap,), self.labels_pad, np.int32)
+            val = np.zeros((cap,), bool)
+            n = min(len(self._host_lab), cap - base)
+            emb[base:base + n] = self._host_emb[:n]
+            lab[base:base + n] = self._host_lab[:n]
+            val[base:base + n] = self._host_val[:n]
+            if base:
+                emb[:base] = np.asarray(data.embeddings[:base], np.float32)
+                lab[:base] = np.asarray(data.labels[:base])
+                val[:base] = np.asarray(data.valid[:base])
+            return emb, lab, val, data.size
         finally:
             if acquired:
                 self._write_lock.release()
@@ -950,10 +1213,14 @@ class ShardedGallery:
         replica's new-version re-anchor both change version and rows in
         this one atomic publish, so serving can never observe rows from
         one version stamped with another."""
-        emb = np.array(emb, np.float32, copy=True)
+        emb = np.asarray(emb, np.float32)
         if emb.ndim != 2 or emb.shape[1] != self.dim:
             raise ValueError(f"snapshot must be [capacity, {self.dim}], "
                              f"got {emb.shape}")
+        val = np.asarray(val, bool)
+        # Rows past the last one the snapshot holds are empty: neither
+        # mirrored nor uploaded. The mirror is a private copy.
+        held = max(int(size), int(np.flatnonzero(val)[-1]) + 1 if val.any() else 0)
         with self._write_lock:
             if embedder_version is not None:
                 self.embedder_version = int(embedder_version)
@@ -967,9 +1234,10 @@ class ShardedGallery:
                 # runtime.state_store); until then serving is exact.
                 self.quantizer.invalidate()
             self.capacity = emb.shape[0]
-            self._host_emb = emb
-            self._host_lab = np.array(lab, np.int32, copy=True)
-            self._host_val = np.array(val, bool, copy=True)
+            self._host_base = 0
+            self._host_emb = np.array(emb[:held], np.float32, copy=True)
+            self._host_lab = np.array(lab[:held], np.int32, copy=True)
+            self._host_val = np.array(val[:held], bool, copy=True)
             self._install(self._host_emb, self._host_lab, self._host_val,
                           int(size))
 
@@ -1014,14 +1282,25 @@ class ShardedGallery:
                 self.quantizer.invalidate()
             if other.capacity != self.capacity:
                 self.capacity = other.capacity
+            self._host_base = other._host_base
             self._host_emb = other._host_emb
             self._host_lab = other._host_lab
             self._host_val = other._host_val
-            if recast:
-                # Rebuild at our width from the (always-f32) host mirrors;
-                # _install publishes with the single _data write below.
-                self._install(self._host_emb, self._host_lab, self._host_val,
-                              other.size)
+            if recast and not other._host_base:
+                # Rebuild at our width from the (always-f32) host mirror;
+                # _install publishes with the single _data write.
+                n = min(len(self._host_lab), self.capacity)
+                self._install(self._host_emb[:n], self._host_lab[:n],
+                              self._host_val[:n], other.size)
+            elif recast:
+                # The donor's rows were installed on its devices and have
+                # no f32 truth on the host: cast them where they are.
+                donor = other._data
+                self._data = donor._replace(
+                    embeddings=jax.jit(
+                        lambda e: e.astype(self.store_dtype),
+                        out_shardings=self._emb_sharding)(donor.embeddings),
+                    epoch=self._epoch)
             else:
                 # Device-visible swap is the single _data assignment (last,
                 # so the host mirrors are already consistent when readers
@@ -1118,19 +1397,23 @@ class ShardedGallery:
     # ---- matching (device-side) ----
 
     def _pallas_enabled(self, capacity: Optional[int] = None) -> bool:
-        """Single-device large-gallery fast path: the streaming pallas
-        kernel (ops.pallas_match) never materializes [Q, capacity] in HBM.
-        Multi-chip stays on the GSPMD formulation — XLA cannot partition a
-        custom call across the tp axis. ``capacity`` overrides the current
+        """Large-gallery fast path: the streaming pallas kernel
+        (ops.pallas_match) never materializes [Q, capacity] in HBM. On a
+        mesh of TPU devices it holds once the rows A SHARD holds reach
+        ``PALLAS_MIN_CAPACITY``: one chip runs the kernel as it is, several
+        run it on every shard under ``shard_map`` with a collective merge
+        (``match_pod_pallas``; XLA cannot partition the custom call, so
+        the decomposition is written out). That form has run on four v5e
+        chips: PERF.md section 6, PR 38. Smaller shards and CPU meshes
+        stay on the GSPMD formulation. ``capacity`` overrides the current
         one so prewarm can select for a FUTURE tier."""
         if self._use_pallas_cfg is not None:
             return bool(self._use_pallas_cfg)
         dev = self.mesh.devices.flat[0]
+        capacity = self.capacity if capacity is None else capacity
         return (
-            self.mesh.size == 1
-            and dev.platform == "tpu"
-            and (self.capacity if capacity is None else capacity)
-            >= self.PALLAS_MIN_CAPACITY
+            dev.platform == "tpu"
+            and capacity // self.mesh.shape[TP_AXIS] >= self.PALLAS_MIN_CAPACITY
         )
 
     _MATCHER_LABELS = {
@@ -1167,12 +1450,15 @@ class ShardedGallery:
                 for cap in tiers),
         ]
         if self.mesh.size > 1:
+            tp = self.mesh.shape[TP_AXIS]
             lines.append(
-                f"Pallas and IVF matchers are OFF: the mesh has "
-                f"{self.mesh.size} devices and both are single-device "
-                f"kernels (GSPMD cannot partition the custom call); every "
-                f"tier runs exact XLA, tp-sharded")
-        elif platform != "tpu":
+                f"{tp} shard(s) of {self.capacity // tp} rows: the exact "
+                f"tier runs the Pallas kernel on every shard (shard_map, "
+                f"candidates gathered over tp and merged) from "
+                f"{self.PALLAS_MIN_CAPACITY} rows a shard on TPU, exact XLA "
+                f"by GSPMD below that and off TPU; the IVF matcher is OFF: "
+                f"it is single-device and the mesh has {self.mesh.size}")
+        if platform != "tpu":
             lines.append(
                 f"platform is {platform}, not tpu: the Pallas exact matcher "
                 f"is off unless forced, and an IVF rerank runs its kernel "
@@ -1201,8 +1487,13 @@ class ShardedGallery:
           PIN their choice via ``use_ivf`` so a concurrent invalidation
           between their check and this call cannot flip the arity under
           them (``None`` re-derives the selection — the legacy shape).
-        - **pallas streaming** single-chip exact.
-        - **GSPMD global view** multi-chip exact.
+        - **pallas streaming** exact: the kernel as it is on one chip,
+          on every shard with a collective merge (``match_pod_pallas``)
+          on several.
+        - **GSPMD global view** exact, for CPU meshes and small shards.
+
+        Each returned function names its own ``jax.named_scope`` pair,
+        ``MATCH_SCOPE`` round the search and ``MERGE_SCOPE`` beside it.
         """
         if self._ivf_enabled(capacity) if use_ivf is None else use_ivf:
             from opencv_facerecognizer_tpu.ops.ivf_match import ivf_match_topk
@@ -1215,9 +1506,13 @@ class ShardedGallery:
                 # ``g`` rides along unused for signature symmetry with the
                 # exact paths (XLA drops it); stage 2 reranks the int8
                 # cell-resident rows, ``valid``/``labels`` stay authoritative.
-                vals, idx = ivf_match_topk(q, valid, ivf, k=k, nprobe=nprobe,
-                                           interpret=interpret)
-                return take_labels_with_sentinel(labels, idx, labels_pad), vals, idx
+                with jax.named_scope(MATCH_SCOPE):
+                    vals, idx = ivf_match_topk(q, valid, ivf, k=k,
+                                               nprobe=nprobe,
+                                               interpret=interpret)
+                with jax.named_scope(MERGE_SCOPE):
+                    found = take_labels_with_sentinel(labels, idx, labels_pad)
+                return found, vals, idx
 
             return ivf_fn
         if self._pallas_enabled(capacity):
@@ -1227,12 +1522,19 @@ class ShardedGallery:
 
             interpret = self.mesh.devices.flat[0].platform != "tpu"
             labels_pad = self.labels_pad
+            if self.mesh.size > 1:
+                return functools.partial(
+                    match_pod_pallas, k=k, mesh=self.mesh,
+                    interpret=interpret, labels_pad=labels_pad)
 
             def fn(q, g, valid, labels):
-                vals, idx = streaming_match_topk(
-                    q, g, valid, k=k, interpret=interpret
-                )
-                return take_labels_with_sentinel(labels, idx, labels_pad), vals, idx
+                with jax.named_scope(MATCH_SCOPE):
+                    vals, idx = streaming_match_topk(
+                        q, g, valid, k=k, interpret=interpret
+                    )
+                with jax.named_scope(MERGE_SCOPE):
+                    found = take_labels_with_sentinel(labels, idx, labels_pad)
+                return found, vals, idx
 
             return fn
         return functools.partial(match_global, k=k, mesh=self.mesh)
@@ -1266,9 +1568,9 @@ class ShardedGallery:
                               and k2[3] not in (None, ivf_sig)]:
                     self._match_cache.pop(stale, None)
                 fn = jax.jit(self.match_fn(k, capacity, use_ivf=True))
-            elif self._pallas_enabled(capacity):
+            elif self._pallas_enabled(capacity) and self.mesh.size == 1:
                 fn = jax.jit(self.match_fn(k, capacity, use_ivf=False))
-            else:
+            else:  # either sharded form: the gallery's own shardings
                 fn = jax.jit(
                     self.match_fn(k, capacity, use_ivf=False),
                     in_shardings=(

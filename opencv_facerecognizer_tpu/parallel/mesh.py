@@ -84,6 +84,13 @@ def make_mesh(
     the axis that changes peak capacity, while dp can also be served by
     larger per-chip batches. Given one axis, the other takes the remainder;
     given both, they must factor the device count exactly.
+
+    What the default selects on a host of several TPU chips: the gallery's
+    rows sharded over all of them, frames and nets replicated, and from
+    65,536 rows a shard the Pallas streaming matcher on every shard with a
+    collective merge (``parallel.gallery.match_pod_pallas``). That path has
+    run on four v5e chips (a watchlist of 50,331,648 rows, 12,582,912 a
+    chip): PERF.md sections 5 and 6 (PR 38) hold the numbers.
     """
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)

@@ -8,8 +8,12 @@ contributes exactly ``max_faces`` slots; empty slots ride along as invalid
 shapes — invalid-slot embeddings are garbage lanes of a batched matmul, not
 wasted recompiles.
 
-Sharding: frames are dp-sharded; detector/embedder params are replicated;
-the gallery match inside is tp-sharded (see ``parallel.gallery``). XLA
+Sharding: frames are dp-sharded and replicated over tp; detector, gate and
+embedder params are replicated (placed on every chip of the mesh once, not
+per call); the gallery match inside is tp-sharded (see
+``parallel.gallery``): every chip of a tp group runs detect, crop and
+embed on the whole batch and searches its own shard of the gallery, and
+the packed result comes back replicated and is read from one chip. XLA
 inserts the collectives; nothing here names a wire protocol.
 """
 
@@ -46,32 +50,42 @@ class RecognitionResult(NamedTuple):
 
 
 def pack_result(result: "RecognitionResult") -> jnp.ndarray:
-    """[B, K, 6 + 2k] f32: boxes | det_score | valid | labels | sims.
+    """[B, K, 6 + 2k] int32, thirty-two bits a lane: boxes | det_score |
+    valid | labels | sims.
 
     One output array instead of five, so the serving loop issues exactly
     one device->host readback per batch (one transfer to wait on, one
     array to hand the readback worker). Whether five small readbacks cost
     measurably more than one on the locally attached chip: not measured.
-    Labels ride
-    as f32 (exact for values < 2^24 — far beyond any gallery capacity).
+    The lanes are INTEGER: labels ride as they are, exact whatever their
+    value (as a float32 VALUE a label rounds from 2^24 on, and a watchlist
+    of 50 M rows labels past that), and the floats ride as their bits, a
+    bitcast. Not the other way round: float lanes carrying a label's bits
+    lose every label under 2^23, whose bits are a denormal float that the
+    TPU flushes to zero on the way through the concatenation (read on
+    four v5e chips, PR 38: subject 1 was published as subject 0).
     """
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+
     return jnp.concatenate([
-        result.boxes,
-        result.det_scores[..., None],
-        result.valid[..., None].astype(jnp.float32),
-        result.labels.astype(jnp.float32),
-        result.similarities,
+        bits(result.boxes),
+        bits(result.det_scores)[..., None],
+        result.valid[..., None].astype(jnp.int32),
+        result.labels.astype(jnp.int32),
+        bits(result.similarities),
     ], axis=-1)
 
 
 def unpack_result(packed: np.ndarray, top_k: int) -> RecognitionResult:
     """Host-side inverse of ``pack_result`` (numpy views, no copies)."""
+    floats = packed.view(np.float32)
     return RecognitionResult(
-        boxes=packed[..., 0:4],
-        det_scores=packed[..., 4],
-        valid=packed[..., 5] > 0.5,
-        labels=packed[..., 6:6 + top_k].astype(np.int32),
-        similarities=packed[..., 6 + top_k:6 + 2 * top_k],
+        boxes=floats[..., 0:4],
+        det_scores=floats[..., 4],
+        valid=packed[..., 5] != 0,
+        labels=packed[..., 6:6 + top_k],
+        similarities=floats[..., 6 + top_k:6 + 2 * top_k],
     )
 
 
@@ -91,8 +105,20 @@ class RecognitionPipeline:
     ):
         self.detector = detector
         self.embed_net = embed_net
-        self.embed_params = embed_params
         self.gallery = gallery
+        mesh = gallery.mesh
+        #: where a batch of frames lives: dp-sharded, on every chip of a tp
+        #: group. The serving loop uploads straight to it on a mesh of
+        #: several chips (``runtime.recognizer``).
+        self.frames_sharding = NamedSharding(mesh, P(DP_AXIS, None, None))
+        self._replicated = NamedSharding(mesh, P())
+        self.embed_params = embed_params
+        # Net parameters are jit ARGUMENTS of every step. On a mesh of
+        # several chips each call would copy them to every chip over
+        # again, so ``_placed`` keeps one replicated copy of each tree,
+        # made again when the tree is swapped (a registry install); the
+        # objects that own the trees are left as they are.
+        self._placed_trees: Dict[str, Tuple[Any, Any]] = {}
         self.face_size = tuple(face_size)
         self.top_k = int(top_k)
         # Stage-1 detection cascade (models.cascade.FaceGate): when set,
@@ -137,9 +163,24 @@ class RecognitionPipeline:
         gallery.prewarm_hooks.append(self.prewarm_capacity)
         gallery.evict_hooks.append(self.evict_below)
 
+    def _placed(self, name: str, tree):
+        """``tree`` as the steps take it: on a mesh of several chips a
+        copy on every one of them, kept until ``tree`` is another object;
+        on one device the tree itself."""
+        if self.gallery.mesh.size == 1:
+            return tree
+        kept = self._placed_trees.get(name)
+        if kept is None or kept[0] is not tree:
+            kept = self._placed_trees[name] = (
+                tree, jax.device_put(tree, self._replicated))
+        return kept[1]
+
+    def _net_params(self):
+        return (self._placed("detector", self.detector.params),
+                self._placed("embedder", self.embed_params))
+
     def _build_step(self, batch: int, height: int, width: int,
                     capacity: Optional[int] = None, use_ivf: bool = False):
-        mesh = self.gallery.mesh
         det = self.detector
         k = self.top_k
         face_size = self.face_size
@@ -177,16 +218,18 @@ class RecognitionPipeline:
                 emb = embed_net.apply({"params": emb_params}, flat)  # [B*K, E] unit-norm
             # 4) match against the gallery (selection in gallery.match_fn:
             # two-stage ivf for a ready quantizer above its threshold,
-            # GSPMD global view when sharded, pallas streaming single-chip)
-            with jax.named_scope("ocvf_match"):
-                if use_ivf:
-                    labels, sims, _ = match(
-                        emb, gallery_emb, gallery_valid, gallery_labels, ivf
-                    )
-                else:
-                    labels, sims, _ = match(
-                        emb, gallery_emb, gallery_valid, gallery_labels
-                    )
+            # pallas streaming on one chip or on every shard, GSPMD global
+            # view for CPU meshes and small shards). The function names
+            # its own scopes: ``ocvf_match`` round the search and, beside
+            # it, ``ocvf_merge`` round what makes one answer of the shards'.
+            if use_ivf:
+                labels, sims, _ = match(
+                    emb, gallery_emb, gallery_valid, gallery_labels, ivf
+                )
+            else:
+                labels, sims, _ = match(
+                    emb, gallery_emb, gallery_valid, gallery_labels
+                )
             return RecognitionResult(
                 boxes=boxes,
                 det_scores=det_scores,
@@ -195,10 +238,9 @@ class RecognitionPipeline:
                 similarities=sims.reshape((batch, max_faces, k)),
             )
 
-        frames_sharding = NamedSharding(mesh, P(DP_AXIS, None, None))
         # ocvf-lint: boundary=jit-recompile-hazard -- THE cache-keyed builder: every serving call reaches this jit only through _step_cache misses, and warmup/prewarm compile every ladder bucket + future tier up front
         return jax.jit(step, in_shardings=(None, None, None, None, None,
-                                           frames_sharding, None))
+                                           self.frames_sharding, None))
 
     def _step_key(self, frames: jnp.ndarray, data, ivf=None) -> Tuple:
         # Gallery capacity (and with it the pallas/GSPMD/ivf selection)
@@ -218,10 +260,16 @@ class RecognitionPipeline:
                 self.gallery._pallas_enabled(capacity),
                 None if ivf is None else ivf.shape_signature())
 
-    @staticmethod
-    def _as_device_frames(frames) -> jnp.ndarray:
+    def _as_device_frames(self, frames) -> jnp.ndarray:
         """uint8 stays uint8 (fast H2D path — cast happens in-graph);
-        everything else normalizes to f32."""
+        everything else normalizes to f32. On a mesh of several chips host
+        frames go straight to the step's placement, not by way of the
+        default device."""
+        if self.gallery.mesh.size > 1 and not isinstance(frames, jax.Array):
+            frames = np.asarray(frames)
+            if frames.dtype != np.uint8:
+                frames = frames.astype(np.float32)
+            return jax.device_put(frames, self.frames_sharding)
         frames = jnp.asarray(frames)
         if frames.dtype != jnp.uint8:
             frames = frames.astype(jnp.float32)
@@ -247,8 +295,7 @@ class RecognitionPipeline:
                 *frames.shape, capacity=data.capacity,
                 use_ivf=ivf is not None)
         return step(
-            self.detector.params,
-            self.embed_params,
+            *self._net_params(),
             data.embeddings,
             data.valid,
             data.labels,
@@ -258,7 +305,7 @@ class RecognitionPipeline:
 
     def recognize_batch_packed(self, frames: jnp.ndarray) -> jnp.ndarray:
         """Same fused step, but the outputs leave the device as ONE packed
-        [B, K, 6 + 2k] f32 array (see ``pack_result``) — the serving loop's
+        [B, K, 6 + 2k] int32 array (see ``pack_result``) — the serving loop's
         single-readback path. Decode host-side with ``unpack_result``."""
         if self.fault_injector is not None:
             self.fault_injector.on_dispatch()
@@ -289,11 +336,10 @@ class RecognitionPipeline:
                                         g_lab, fr, iv))
 
             packed = self._packed_cache[key] = jax.jit(  # ocvf-lint: boundary=jit-recompile-hazard -- packed-cache fill: warmup compiles every dispatch bucket, so serving only lands here on a genuinely new (shape, capacity, matcher) key
-                packed_step,
+                packed_step, out_shardings=self.frames_sharding,
                 donate_argnums=(5,) if self.donate_frames else ())
         return packed(
-            self.detector.params,
-            self.embed_params,
+            *self._net_params(),
             data.embeddings,
             data.valid,
             data.labels,
@@ -314,7 +360,7 @@ class RecognitionPipeline:
         ivf = self.gallery._ivf_data(data)
         packed = self._packed_cache[self._step_key(frames, data, ivf)]
         return packed.lower(
-            self.detector.params, self.embed_params, data.embeddings,
+            *self._net_params(), data.embeddings,
             data.valid, data.labels, frames, ivf if ivf is not None else ())
 
     def cascade_scores(self, frames) -> jnp.ndarray:
@@ -348,8 +394,14 @@ class RecognitionPipeline:
             # The closure's name is the program's on the device trace's
             # "XLA Modules" line (``jit_gate_stage1``), where device time
             # per program is read: rename it and those readers go blind.
-            fn = self._cascade_cache[key] = jax.jit(gate_stage1)  # ocvf-lint: boundary=jit-recompile-hazard -- cache-keyed stage-1 builder: warmup compiles every (rung, ingest dtype) signature up front; serving lands here only on a genuinely new shape
-        return fn(gate.params, frames)
+            # On the step's mesh, frames placed as the step takes them:
+            # every chip of a tp group scores the batch, so the readback
+            # waits on the same queues the step does.
+            fn = self._cascade_cache[key] = jax.jit(  # ocvf-lint: boundary=jit-recompile-hazard -- cache-keyed stage-1 builder: warmup compiles every (rung, ingest dtype) signature up front; serving lands here only on a genuinely new shape
+                gate_stage1,
+                in_shardings=(self._replicated, self.frames_sharding),
+                out_shardings=NamedSharding(self.gallery.mesh, P(DP_AXIS)))
+        return fn(self._placed("gate", gate.params), frames)
 
     # ---- model-registry installs (runtime.registry swaps) ----
 
@@ -447,18 +499,11 @@ class RecognitionPipeline:
         }
         if not served:
             return
-        # Scratch MUST match the gallery's store_dtype: an f32 scratch on a
-        # bf16 gallery warms an executable serving never hits (aval
-        # mismatch -> full retrace on the serving thread post-grow).
-        scratch_emb = jax.device_put(
-            jnp.zeros((capacity, g.dim), g.store_dtype), g._emb_sharding
-        )
-        scratch_lab = jax.device_put(
-            jnp.full((capacity,), g.labels_pad, jnp.int32), g._lab_sharding
-        )
-        scratch_val = jax.device_put(
-            jnp.zeros((capacity,), bool), g._valid_sharding
-        )
+        # Scratch in the gallery's own store_dtype and shardings, made on
+        # the chips shard by shard: an f32 scratch on a bf16 gallery warms
+        # an executable serving never hits (aval mismatch -> full retrace
+        # on the serving thread post-grow).
+        scratch_emb, scratch_lab, scratch_val = g._empty_arrays(capacity)
         for batch, height, width, dtype in served:
             new_key = (batch, height, width, dtype, capacity, pallas, ivf_sig)
             if new_key in self._packed_cache:
@@ -468,13 +513,15 @@ class RecognitionPipeline:
                 step = self._build_step(batch, height, width, capacity,
                                         use_ivf=ivf is not None)
                 self._step_cache[new_key] = step
-            frames = jnp.zeros((batch, height, width), dtype=dtype)
+            # placed as a served batch is, or the serving call retraces
+            frames = self._as_device_frames(
+                np.zeros((batch, height, width), dtype=dtype))
             ivf_arg = ivf if ivf is not None else ()
             # Execute each once: jit compiles per concrete shape; block so
             # the caller (grow worker) only installs AFTER compiles landed.
             # ocvf-lint: boundary=host-sync -- prewarm runs on the gallery's grow-worker thread, never the serving loop; the block IS the contract (install only after compiles landed)
             jax.block_until_ready(step(
-                self.detector.params, self.embed_params,
+                *self._net_params(),
                 scratch_emb, scratch_val, scratch_lab, frames, ivf_arg,
             ))
 
@@ -484,10 +531,10 @@ class RecognitionPipeline:
                                          g_lab, fr, iv))
 
             packed = jax.jit(  # ocvf-lint: boundary=jit-recompile-hazard -- prewarm builder on the grow-worker thread: compiles the future tier so the serving thread never does
-                packed_step,
+                packed_step, out_shardings=self.frames_sharding,
                 donate_argnums=(5,) if self.donate_frames else ())
             packed(  # ocvf-lint: boundary=host-sync -- prewarm executes+blocks off the serving loop; install happens only after the compile landed
-                self.detector.params, self.embed_params,
+                *self._net_params(),
                 scratch_emb, scratch_val, scratch_lab, frames, ivf_arg,
             ).block_until_ready()
             self._packed_cache[new_key] = packed

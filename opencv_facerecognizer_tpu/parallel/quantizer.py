@@ -533,16 +533,16 @@ class CoarseQuantizer:
             # a prefix (append-only within an epoch), so the tail is one
             # contiguous range — ONE batched insert, not a per-row loop
             # of full-array device copies under the write lock.
-            tail = g._host_val.copy()
+            tail = g.host_valid()
             tail[:emb.shape[0]] &= ~val[:len(tail)][:emb.shape[0]]
             tail_ids = np.nonzero(tail)[0]
             if len(tail_ids):
                 lo, hi = int(tail_ids[0]), int(tail_ids[-1]) + 1
                 if hi - lo == len(tail_ids):
-                    self.on_rows_added(g._host_emb[lo:hi], lo)
+                    self.on_rows_added(g.host_rows(lo, hi), lo)
                 else:  # non-contiguous (defensive): per-row fallback
                     for rid in tail_ids:
-                        self.on_rows_added(g._host_emb[rid][None, :],
+                        self.on_rows_added(g.host_rows(int(rid), int(rid) + 1),
                                            int(rid))
 
         g.run_locked(publish)

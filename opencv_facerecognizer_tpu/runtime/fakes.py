@@ -228,9 +228,13 @@ class InstantPipeline:
                                    "mode": "fake"}
         self.compiled_batch_sizes.add(sig)
         # pack_result layout: boxes(4) | det_score | valid | labels(k) |
-        # sims(k); valid=0 everywhere -> zero faces per frame (unless
-        # faces_per_frame scripts some detections in).
-        packed = np.zeros((b, self.max_faces, 6 + 2 * self.top_k), np.float32)
+        # sims(k) in int32 lanes, the floats as their bits (written
+        # through ``packed``, a float32 view of the lanes; a label is
+        # written through ``lanes`` itself); valid=0 everywhere -> zero
+        # faces per frame (unless faces_per_frame scripts some
+        # detections in).
+        lanes = np.zeros((b, self.max_faces, 6 + 2 * self.top_k), np.int32)
+        packed = lanes.view(np.float32)
         if self.video_oracle:
             # Pixel-derived detections (see __init__): one face per
             # distinct identity fill value present in the frame.
@@ -248,10 +252,10 @@ class InstantPipeline:
                                              float(xs.max()) + 1.0)
                     packed[fi, slot, 4] = 1.0   # det_score
                     packed[fi, slot, 5] = 1.0   # valid
-                    packed[fi, slot, 6] = (fv - 160.0) / 24.0  # label
+                    lanes[fi, slot, 6] = int((fv - 160.0) / 24.0)  # label
                     packed[fi, slot, 6 + self.top_k] = self.oracle_sim
                     slot += 1
-            return FakePacked(packed, time.monotonic() + self.compute_s,
+            return FakePacked(lanes, time.monotonic() + self.compute_s,
                               poll_cost_s=self.sync_poll_floor_s)
         if self.faces_per_frame:
             h, w = self.frame_shape
@@ -260,9 +264,9 @@ class InstantPipeline:
                                      max(6.0, w - 2.0))  # y0 x0 y1 x1
                 packed[:, j, 4] = 1.0   # det_score
                 packed[:, j, 5] = 1.0   # valid
-                packed[:, j, 6] = 0.0   # top-1 label
+                lanes[:, j, 6] = 0      # top-1 label
                 packed[:, j, 6 + self.top_k] = 1.0  # top-1 similarity
-        return FakePacked(packed, time.monotonic() + self.compute_s,
+        return FakePacked(lanes, time.monotonic() + self.compute_s,
                           poll_cost_s=self.sync_poll_floor_s)
 
 

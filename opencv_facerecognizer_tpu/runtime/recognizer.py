@@ -526,6 +526,11 @@ class RecognizerService:
                 trace_topic=FRAME_TOPIC, fault_injector=fault_injector,
                 inflight_depth=int(inflight_depth))
             transfer_dtype = self.ingest.transfer_dtype
+            # On a mesh of several chips the step takes its frames on
+            # every chip of a tp group: upload straight to that placement.
+            placement = getattr(pipeline, "frames_sharding", None)
+            if placement is not None and len(placement.device_set) > 1:
+                self.ingest.upload_device = placement
             if (self.admission is not None
                     and self.admission.staging_free_fn is None):
                 # Ring exhaustion backpressures at the front door: a

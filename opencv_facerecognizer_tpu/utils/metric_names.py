@@ -342,6 +342,17 @@ IVF_SIDECAR_LOADS = "ivf_sidecar_loads"
 IVF_SIDECAR_STALE = "ivf_sidecar_stale"
 IVF_SIDECAR_ERRORS = "ivf_sidecar_errors"
 
+# ---- sharded gallery installs (parallel.gallery) ---------------------------
+#: tp shards the gallery's rows are split over (gauge; 1 on one chip).
+GALLERY_SHARDS = "gallery_shards"
+#: rows that crossed the host->device link into the gallery: an ``add``
+#: of n rows counts n, a whole-set host install the rows it holds — never
+#: a tier's capacity.
+GALLERY_ROWS_UPLOADED = "gallery_rows_uploaded"
+#: whole-set installs adopted from device arrays
+#: (``ShardedGallery.install_device_rows``): nothing crossed the link.
+GALLERY_BULK_INSTALLS = "gallery_bulk_installs"
+
 # ---- tracing / flight recorder / exposition (utils.tracing, runtime.expo) --
 TRACE_DUMPS = "trace_dumps"
 TRACE_DUMP_ERRORS = "trace_dump_errors"

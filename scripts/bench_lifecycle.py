@@ -14,8 +14,10 @@ What the artifact records (merged into BENCH_DETAIL.json under
   ``recognize_batch_packed`` call after ``gallery.add`` crossed capacity —
   the XLA recompile + (at 64k->128k) the matcher switch the serving thread
   actually eats; subsequent-call time recorded alongside to show recovery;
-- ``install_ms``: host->device install cost of the grown snapshot
-  (``ShardedGallery._install`` device_put of the doubled arrays).
+- ``install_ms``: install cost of the grown snapshot (since PR 38 the
+  served rows are copied into the doubled tier on the devices and only the
+  added rows cross the link: ``ShardedGallery._grown_arrays`` /
+  ``_splice_rows``).
 
 Run:  PYTHONPATH=. python scripts/bench_lifecycle.py
 """

@@ -78,7 +78,8 @@ def _is_default(default, stated):
         return str(default) == stated
 
 
-@pytest.mark.parametrize("name", ["watchlist8m", "watchlist4m-r50"])
+@pytest.mark.parametrize("name", ["watchlist8m", "watchlist4m-r50",
+                                  "watchlist48m-tp4"])
 def test_benchmark_configurations_parse(name):
     """What a benchmark configuration says of ``ocvf-recognize`` holds for
     the parser in the tree: today only a run on the chip finds a

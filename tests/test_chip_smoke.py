@@ -130,11 +130,14 @@ def test_recognize_dir_mode_exit_code(toy_paths, tmp_path, monkeypatch,
     captured = capsys.readouterr()
     assert len([ln for ln in captured.out.splitlines()
                 if ln.startswith("{")]) == 4
-    # start-up log: mesh, device kind, matcher per tier, and why the kernel
-    # matchers are off on this 8-device mesh
+    # start-up log: mesh, device kind, matcher per tier, what an 8-device
+    # mesh selects (the kernel on every shard from 65,536 rows a shard on
+    # TPU; IVF off) and why the kernel is off here (a CPU)
     assert "mesh dp=1 tp=8" in captured.err
     assert "matcher by capacity tier" in captured.err
-    assert "Pallas and IVF matchers are OFF" in captured.err
+    assert "8 shard(s) of 512 rows" in captured.err
+    assert "the IVF matcher is OFF" in captured.err
+    assert "platform is cpu, not tpu" in captured.err
 
     build = recognize.build_service
 

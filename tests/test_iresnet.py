@@ -193,8 +193,13 @@ def test_load_stack_serves_either_feature_class(artifacts, model, net_class, dim
     assert sorted(names) == sorted(artifacts["names"])
     packed = np.asarray(pipeline.recognize_batch_packed(
         artifacts["scenes"][:2].astype(np.uint8)))
-    # boxes, valid, label, similarity (an empty slot's score is -inf)
-    assert packed.shape == (2, 2, 8) and np.isfinite(packed[..., [0, 1, 2, 3, 5, 6, 7]]).all()
+    # boxes, label, similarity (an empty slot's score is -inf)
+    from opencv_facerecognizer_tpu.parallel.pipeline import unpack_result
+
+    out = unpack_result(packed, 1)
+    assert packed.shape == (2, 2, 8) and packed.dtype == np.int32
+    assert np.isfinite(out.boxes).all() and np.isfinite(out.similarities).all()
+    assert ((out.labels >= -1) & (out.labels < len(names))).all()
     assert pipeline.last_dispatch_info["embed_slots"] == 2 * 2
 
 

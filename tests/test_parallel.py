@@ -395,13 +395,11 @@ def test_gallery_async_grow_normalizes_on_worker_and_waits_residency():
 
 
 def test_gallery_async_grow_chunked_upload_path():
-    """Grow uploads above 2x CHUNK_UPLOAD_BYTES go through the paced
-    chunked device-put (device-side zeros + donated dynamic_update_slice
-    per chunk) — forced here via an instance-level chunk-size override on
-    a SINGLE-device mesh (chunking is scoped to 1-device meshes: with
-    tp>1 the dynamic-offset update replicates each chunk to every device,
-    see _build_snapshot) — and the published snapshot is identical to the
-    host mirror."""
+    """The grow worker uploads the staged rows in paced pieces of at most
+    CHUNK_UPLOAD_BYTES, spliced on the device into the next tier (which
+    already holds the served rows, copied there on the device) — several
+    pieces forced here via an instance-level chunk-size override — and
+    the published snapshot is identical to the host mirror."""
     import jax
 
     mesh = make_mesh(dp=1, tp=1, devices=jax.devices()[:1])
@@ -769,8 +767,8 @@ def test_chunked_upload_stops_pacing_after_first_timeout():
     import jax
 
     mesh = make_mesh(dp=1, tp=1, devices=jax.devices()[:1])
-    g = ShardedGallery(capacity=32, dim=16, mesh=mesh, async_grow=True)
-    g.CHUNK_UPLOAD_BYTES = 1024  # several chunks at 96 rows
+    g = ShardedGallery(capacity=128, dim=16, mesh=mesh, async_grow=True)
+    g.CHUNK_UPLOAD_BYTES = 1024  # several pieces at 96 rows
     calls = []
 
     def never_ready_pacer(buf, deadline, cancel=None, info=None):
@@ -781,7 +779,472 @@ def test_chunked_upload_stops_pacing_after_first_timeout():
 
     g._pace_chunk = never_ready_pacer  # instance attr shadows the static
     info = {}
-    emb = RNG.normal(size=(96, 16)).astype(np.float32)  # 6 chunks of 16 rows
-    g._chunked_emb_put(emb, info=info)
+    emb = RNG.normal(size=(96, 16)).astype(np.float32)  # 6 pieces of 16 rows
+    data = g.data
+    arrays = g._splice_rows(
+        (data.embeddings, data.labels, data.valid), emb,
+        np.arange(96, dtype=np.int32), np.ones(96, bool), 0, owned=False,
+        paced=True, info=info)
     assert len(calls) == 1  # paced once, then gave up for the remainder
     assert info["chunk_pacing_timeout"] is True
+    # unpaced, the remaining pieces still landed, and the served snapshot
+    # was copied, not written
+    np.testing.assert_allclose(np.asarray(arrays[0])[:96], emb, rtol=1e-6)
+    assert not np.asarray(data.valid).any() and np.asarray(arrays[2])[:96].all()
+
+
+# ---- a watchlist sharded over tp: the kernel on every shard (PR 38) ----
+#
+# On a CPU the selection is forced through ``use_pallas`` (the kernel then
+# runs in interpret mode); on a mesh of TPU chips ``_pallas_enabled`` makes
+# it from the platform and the rows a shard holds.
+
+
+def _reference_topk(queries, rows, valid, labels, k):
+    """The plain reference: a float32 ``jax.numpy`` top-k over the
+    unsharded rows, ties to the lowest row (``lax.top_k``'s order)."""
+    sims = jnp.dot(jnp.asarray(queries, jnp.float32),
+                   jnp.asarray(rows, jnp.float32).T,
+                   precision=jax.lax.Precision.HIGHEST)
+    sims = jnp.where(jnp.asarray(valid)[None, :], sims, -jnp.inf)
+    vals, idx = jax.lax.top_k(sims, k)
+    return (np.asarray(labels)[np.asarray(idx)], np.asarray(vals),
+            np.asarray(idx))
+
+
+def _bf16_exact(x):
+    """Values bf16 holds exactly, so the kernel's bf16 operands and the
+    float32 reference rank the same rows."""
+    return np.asarray(jnp.asarray(x, jnp.bfloat16).astype(jnp.float32))
+
+
+def _sharded_gallery(tp, cap, dim, rows, labels, valid=None, **kw):
+    mesh = make_mesh(dp=1, tp=tp, devices=jax.devices()[:tp])
+    g = ShardedGallery(capacity=cap, dim=dim, mesh=mesh, use_pallas=True, **kw)
+    data = g.data
+    put = lambda a, like: jax.device_put(jnp.asarray(a, like.dtype), like.sharding)  # noqa: E731
+    valid = np.ones(cap, bool) if valid is None else valid
+    g.install_device_rows(put(rows, data.embeddings), put(labels, data.labels),
+                          put(valid, data.valid), cap)
+    return g
+
+
+class _FakeMesh:
+    """A mesh of ``tp`` devices of a platform, for the selection alone."""
+
+    def __init__(self, platform, dp, tp):
+        import types
+
+        dev = types.SimpleNamespace(platform=platform, device_kind="fake")
+        self.devices = np.array([dev] * (dp * tp), dtype=object).reshape(dp, tp)
+        self.shape = {DP_AXIS: dp, TP_AXIS: tp}
+        self.size = dp * tp
+
+
+@pytest.mark.parametrize("tp", [2, 4, 8])
+@pytest.mark.parametrize("shard_rows,expect", [
+    (ShardedGallery.PALLAS_MIN_CAPACITY, "pallas"),
+    (ShardedGallery.PALLAS_MIN_CAPACITY - 1, "xla")])
+def test_pallas_selection_by_rows_a_shard_on_tpu_meshes(tp, shard_rows, expect):
+    """``_pallas_enabled`` / ``matcher_name`` / ``describe_matchers`` on
+    meshes of 2, 4 and 8 TPU devices, either side of ``PALLAS_MIN_CAPACITY``
+    rows A SHARD (selection only: no array is made for the fake mesh)."""
+    g = ShardedGallery.__new__(ShardedGallery)
+    g.mesh, g._use_pallas_cfg = _FakeMesh("tpu", 1, tp), None
+    g.capacity = shard_rows * tp
+    g.quantizer, g.match_mode, g.store_dtype = None, "exact", jnp.dtype(jnp.bfloat16)
+    assert g._pallas_enabled() is (expect == "pallas")
+    assert g.matcher_name() == expect
+    # a future tier is selected by ITS rows a shard, as prewarm asks
+    assert g.matcher_name(ShardedGallery.PALLAS_MIN_CAPACITY * tp) == "pallas"
+    assert g.matcher_name(ShardedGallery.PALLAS_MIN_CAPACITY * tp - tp) == "xla"
+    lines = g.describe_matchers()
+    assert f"mesh dp=1 tp={tp}" in lines[0]
+    label = ShardedGallery._MATCHER_LABELS[expect]
+    assert f"{g.capacity} rows -> {label} [current]" in lines[1]
+    assert f"{tp} shard(s) of {shard_rows} rows" in lines[2]
+    assert "the IVF matcher is OFF" in lines[2]
+    assert not any("are OFF" in ln or "not tpu" in ln for ln in lines)
+    # the same shards on a CPU mesh stay on the GSPMD form, and say why
+    g.mesh = _FakeMesh("cpu", 1, tp)
+    assert g.matcher_name() == "xla"
+    assert any("platform is cpu, not tpu" in ln for ln in g.describe_matchers())
+
+
+@pytest.mark.parametrize("tp", [2, 4, 8])
+def test_forced_kernel_on_a_cpu_mesh_selects_the_shard_map_form(tp):
+    from opencv_facerecognizer_tpu.parallel.gallery import match_pod_pallas
+
+    mesh = make_mesh(dp=1, tp=tp, devices=jax.devices()[:tp])
+    g = ShardedGallery(capacity=16 * tp, dim=8, mesh=mesh, use_pallas=True)
+    assert g._pallas_enabled() and g.matcher_name() == "pallas"
+    fn = g.match_fn(1)
+    assert fn.func is match_pod_pallas and fn.keywords["interpret"] is True
+    assert fn.keywords["mesh"] is mesh
+    off = ShardedGallery(capacity=16 * tp, dim=8, mesh=mesh, use_pallas=False)
+    assert off.matcher_name() == "xla"
+
+
+@pytest.mark.parametrize("tp", [2, 4, 8])
+def test_sharded_kernel_match_equals_float32_reference(tp):
+    """Through ``ShardedGallery.match`` (the selection, not the function
+    called by hand): labels and ROW INDICES exactly, similarities to the
+    bf16 tolerance, with a best row on every shard in turn."""
+    rng = np.random.default_rng(100 + tp)
+    cap, dim, k = 32 * tp, 16, 3
+    rows = _bf16_exact(_unit(rng.normal(size=(cap, dim)).astype(np.float32)))
+    labels = (1000 + np.arange(cap)).astype(np.int32)
+    # query s is (nearly) a row of shard s: the best row moves shard by shard
+    best_rows = np.array([s * 32 + int(rng.integers(32)) for s in range(tp)])
+    q = _bf16_exact(rows[best_rows] + 0.05 * rng.normal(size=(tp, dim)))
+    q = np.concatenate([q, _bf16_exact(_unit(rng.normal(size=(8, dim))))])
+    g = _sharded_gallery(tp, cap, dim, rows, labels, store_dtype=jnp.bfloat16)
+    lab, sims, idx = (np.asarray(v) for v in g.match(q, k=k))
+    r_lab, r_sims, r_idx = _reference_topk(q, rows, np.ones(cap, bool), labels, k)
+    np.testing.assert_array_equal(idx, r_idx)
+    np.testing.assert_array_equal(lab, r_lab)
+    np.testing.assert_allclose(sims, r_sims, atol=1e-2)
+    np.testing.assert_array_equal(idx[:tp, 0], best_rows)
+    assert sorted(set(idx[:tp, 0] // 32)) == list(range(tp))
+
+
+@pytest.mark.parametrize("tp", [2, 4, 8])
+def test_sharded_kernel_match_sparse_shards_and_fewer_valid_than_k(tp):
+    """Most shards empty, one shard with fewer valid rows than k: the
+    reference's rows in the reference's order, then sentinels (index -1,
+    pad label), never a neighbour shard's rows."""
+    rng = np.random.default_rng(200 + tp)
+    cap, dim, k = 16 * tp, 8, 4
+    rows = _bf16_exact(_unit(rng.normal(size=(cap, dim)).astype(np.float32)))
+    labels = (500 + np.arange(cap)).astype(np.int32)
+    valid = np.zeros(cap, bool)
+    last = (tp - 1) * 16
+    valid[[last + 3, last + 9]] = True      # two rows, on the LAST shard
+    valid[5] = True                          # one on the first
+    q = _bf16_exact(_unit(rng.normal(size=(8, dim)).astype(np.float32)))
+    g = _sharded_gallery(tp, cap, dim, rows, labels, valid=valid)
+    lab, sims, idx = (np.asarray(v) for v in g.match(q, k=k))
+    r_lab, r_sims, r_idx = _reference_topk(q, rows, valid, labels, k)
+    np.testing.assert_array_equal(idx[:, :3], r_idx[:, :3])
+    np.testing.assert_array_equal(lab[:, :3], r_lab[:, :3])
+    np.testing.assert_allclose(sims[:, :3], r_sims[:, :3], atol=1e-2)
+    assert (idx[:, 3] == -1).all() and (lab[:, 3] == g.labels_pad).all()
+    assert (sims[:, 3] < -1e29).all()
+
+
+@pytest.mark.parametrize("tp", [2, 4, 8])
+def test_a_lost_shard_is_seen(tp):
+    """One shard's ``valid`` cleared: every query whose best row lay there
+    is answered from another shard, exactly as the reference over the rows
+    that are left says, and the answer differs from the whole gallery's."""
+    rng = np.random.default_rng(300 + tp)
+    cap, dim = 32 * tp, 16
+    rows = _bf16_exact(_unit(rng.normal(size=(cap, dim)).astype(np.float32)))
+    labels = np.arange(cap).astype(np.int32)
+    q = _bf16_exact(rows[np.arange(tp) * 32 + 7] + 0.05 * rng.normal(size=(tp, dim)))
+    whole = _sharded_gallery(tp, cap, dim, rows, labels)
+    _, sims_all, idx_all = (np.asarray(v) for v in whole.match(q, k=1))
+    np.testing.assert_array_equal(idx_all[:, 0], np.arange(tp) * 32 + 7)
+    for lost in range(tp):
+        valid = np.ones(cap, bool)
+        valid[lost * 32:(lost + 1) * 32] = False
+        g = _sharded_gallery(tp, cap, dim, rows, labels, valid=valid)
+        lab, sims, idx = (np.asarray(v) for v in g.match(q, k=1))
+        r_lab, r_sims, r_idx = _reference_topk(q, rows, valid, labels, 1)
+        np.testing.assert_array_equal(idx, r_idx)
+        np.testing.assert_array_equal(lab, r_lab)
+        changed = idx[:, 0] != idx_all[:, 0]
+        assert changed.tolist() == [s == lost for s in range(tp)]
+        assert (idx[:, 0] // 32 != lost).all()
+        assert sims[lost, 0] < sims_all[lost, 0] - 1e-3
+
+
+def _tiny_pipeline(gallery):
+    from opencv_facerecognizer_tpu.models.detector import CNNFaceDetector
+    from opencv_facerecognizer_tpu.models.embedder import (
+        FaceEmbedNet, init_embedder,
+    )
+    from opencv_facerecognizer_tpu.parallel.pipeline import RecognitionPipeline
+
+    det = CNNFaceDetector(features=(8, 8), head_features=8, max_faces=2,
+                          score_threshold=0.0, space_to_depth=2)
+    det.load_params(det.net.init(jax.random.PRNGKey(0),
+                                 np.zeros((1, 64, 64)))["params"])
+    net = FaceEmbedNet(embed_dim=16, stem_features=8, stage_features=(8,),
+                       stage_blocks=(1,))
+    emb_params = init_embedder(net, num_classes=4, input_shape=(32, 32),
+                               seed=0)["net"]
+    return RecognitionPipeline(det, net, emb_params, gallery,
+                               face_size=(32, 32), top_k=1)
+
+
+def _step_embeddings(pipe, frames):
+    """The embeddings the fused step matches, made with the step's own
+    stages outside it: [B * max_faces, E]."""
+    from opencv_facerecognizer_tpu.models import detector as detector_mod
+    from opencv_facerecognizer_tpu.models import embedder as embedder_mod
+    from opencv_facerecognizer_tpu.ops import image as image_ops
+
+    det = pipe.detector
+    x = jnp.asarray(frames, jnp.float32)
+    boxes, _scores, _valid = detector_mod.decode_detections(
+        det.net.apply({"params": det.params}, x), det.max_faces,
+        det.score_threshold, det.iou_threshold)
+    crops = image_ops.batched_crop_resize(x, boxes, pipe.face_size)
+    flat = embedder_mod.normalize_faces(
+        crops.reshape((-1, *pipe.face_size)), pipe.face_size)
+    return np.asarray(pipe.embed_net.apply({"params": pipe.embed_params}, flat))
+
+
+@pytest.mark.parametrize("tp", [2, 4, 8])
+def test_packed_step_on_a_tp_mesh_equals_float32_reference(tp):
+    """Through ``RecognitionPipeline.recognize_batch_packed`` on a (dp 1,
+    tp) mesh with the kernel selected on every shard: nets and frames
+    replicated, rows and ``valid`` sharded, one packed result read from one
+    device. Labels (one a row, some past 2^24: exact through the packed
+    lanes) and similarities against the float32 reference over the
+    unsharded rows, each face's best row on another shard in turn; and
+    with that shard lost the answer changes."""
+    from opencv_facerecognizer_tpu.parallel.pipeline import unpack_result
+    from opencv_facerecognizer_tpu.utils.dataset import make_synthetic_scenes
+
+    rng = np.random.default_rng(400 + tp)
+    cap, dim = 16 * tp, 16
+    mesh = make_mesh(dp=1, tp=tp, devices=jax.devices()[:tp])
+    frames = make_synthetic_scenes(4, (64, 64), max_faces=2, seed=5)[0]
+    probe = _tiny_pipeline(ShardedGallery(capacity=cap, dim=dim, mesh=mesh))
+    emb = _step_embeddings(probe, frames)  # [8, 16]
+    rows = _unit(rng.normal(size=(cap, dim)).astype(np.float32))
+    at = np.array([(i % tp) * 16 + 3 + i // tp for i in range(len(emb))])
+    rows[at] = emb  # face i's own row, on shard i % tp
+    rows = _bf16_exact(rows)
+    labels = ((1 << 24) + 1 + 3 * np.arange(cap)).astype(np.int32)
+    r_lab, r_sims, r_idx = _reference_topk(emb, rows, np.ones(cap, bool), labels, 1)
+    np.testing.assert_array_equal(r_idx[:, 0], at)
+
+    g = _sharded_gallery(tp, cap, dim, rows, labels, store_dtype=jnp.bfloat16)
+    pipe = _tiny_pipeline(g)
+    assert g.matcher_name() == "pallas"
+    packed = pipe.recognize_batch_packed(frames)
+    assert packed.sharding.is_fully_replicated and len(packed.sharding.device_set) == tp
+    out = unpack_result(np.asarray(packed), 1)
+    np.testing.assert_array_equal(out.labels.reshape(-1), r_lab[:, 0])
+    np.testing.assert_allclose(out.similarities.reshape(-1), r_sims[:, 0], atol=2e-2)
+    # the stage-1 gate's placement is the step's: nothing to compare, it
+    # has to run on the same mesh
+    plain = pipe.recognize_batch(frames)
+    np.testing.assert_array_equal(np.asarray(plain.labels).reshape(-1), r_lab[:, 0])
+
+    lost = 1
+    valid = np.ones(cap, bool)
+    valid[lost * 16:(lost + 1) * 16] = False
+    g.install_device_rows(g.data.embeddings, g.data.labels,
+                          jax.device_put(jnp.asarray(valid), g.data.valid.sharding), cap)
+    out2 = unpack_result(np.asarray(pipe.recognize_batch_packed(frames)), 1)
+    l_lab, _l_sims, _ = _reference_topk(emb, rows, valid, labels, 1)
+    np.testing.assert_array_equal(out2.labels.reshape(-1), l_lab[:, 0])
+    moved = out2.labels.reshape(-1) != out.labels.reshape(-1)
+    assert moved.tolist() == [(i % tp) == lost for i in range(len(emb))]
+
+
+# ---- install: n rows over the link, two tier-sized arrays at most ----
+
+
+def _counted_gallery(cap, dim, tp, **kw):
+    from opencv_facerecognizer_tpu.utils.metrics import Metrics
+
+    metrics = Metrics()
+    mesh = make_mesh(dp=1, tp=tp, devices=jax.devices()[:tp])
+    gallery = ShardedGallery(capacity=cap, dim=dim, mesh=mesh, **kw)
+    gallery.attach_observability(metrics)
+    return gallery, metrics
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+@pytest.mark.parametrize("store_dtype", ["float32", "bfloat16"])
+def test_add_moves_n_rows_over_the_link_and_keeps_the_served_snapshot(tp, store_dtype):
+    """Invariant (a): an ``add`` of n rows uploads n rows (counter
+    ``gallery_rows_uploaded``) and splices them into a COPY of the served
+    arrays: a reader's snapshot is untouched, the new one holds old and new
+    rows, every array keeps the tier's shape and sharding."""
+    from opencv_facerecognizer_tpu.utils import metric_names as mn
+
+    g, metrics = _counted_gallery(4096, 8, tp, store_dtype=getattr(jnp, store_dtype))
+    assert metrics.gauge(mn.GALLERY_SHARDS) == tp
+    assert metrics.counter(mn.GALLERY_ROWS_UPLOADED) == 0  # construction: nothing
+    first = _unit(RNG.normal(size=(13, 8)).astype(np.float32))
+    g.add(first, np.arange(13, dtype=np.int32))
+    assert metrics.counter(mn.GALLERY_ROWS_UPLOADED) == 13
+    held = g.data  # what a reader took
+    more = _unit(RNG.normal(size=(1000, 8)).astype(np.float32))
+    g.add(more, np.arange(13, 1013, dtype=np.int32))
+    assert metrics.counter(mn.GALLERY_ROWS_UPLOADED) == 1013 == g.rows_uploaded
+    assert held.size == 13 and int(np.asarray(held.valid).sum()) == 13
+    assert not np.asarray(held.embeddings)[13:].any()
+    new = g.data
+    assert new.size == 1013 and np.asarray(new.valid)[:1013].all()
+    assert not np.asarray(new.valid)[1013:].any()
+    tol = 1e-6 if store_dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(new.embeddings, np.float32)[:1013],
+                               np.concatenate([first, more]), atol=tol)
+    np.testing.assert_array_equal(np.asarray(new.labels)[:1013], np.arange(1013))
+    for old, cur in zip(held[:3], new[:3]):
+        assert cur.shape == old.shape and cur.sharding == old.sharding
+        assert cur.dtype == old.dtype
+    assert {s.data.shape for s in new.embeddings.addressable_shards} == {(4096 // tp, 8)}
+
+
+def test_no_capacity_sized_host_array_at_construction_or_add(monkeypatch):
+    """Invariant (b): nothing of capacity x dim is allocated on the host
+    when a gallery is made or rows are added; the mirror holds what the
+    host enrolled and grows with it."""
+    cap, dim = 1 << 16, 8
+    big = []
+    real = {name: getattr(np, name) for name in ("zeros", "empty", "full", "ones")}
+
+    def watch(name):
+        def alloc(shape, *a, **k):
+            if int(np.prod(shape)) >= cap * dim:
+                big.append((name, shape))
+            return real[name](shape, *a, **k)
+        return alloc
+
+    for name in real:
+        monkeypatch.setattr(np, name, watch(name))
+    g, _ = _counted_gallery(cap, dim, 4)
+    assert len(g._host_emb) == 0
+    g.add(_unit(RNG.normal(size=(100, dim)).astype(np.float32)),
+          np.arange(100, dtype=np.int32))
+    assert 100 <= len(g._host_emb) <= 256 and len(g._host_lab) == len(g._host_emb)
+    g.add(_unit(RNG.normal(size=(5000, dim)).astype(np.float32)),
+          np.arange(100, 5100, dtype=np.int32))
+    assert 5100 <= len(g._host_emb) <= 2 * 5100
+    g.reset()
+    assert len(g._host_emb) == 0 and g.size == 0
+    assert not big, big
+    # asked for, a snapshot IS whole-capacity: that is its contract
+    emb, lab, val, size = g.snapshot()
+    assert emb.shape == (cap, dim) and size == 0 and big
+
+
+@pytest.mark.parametrize("tp", [1, 4])
+def test_install_device_rows_adopts_appends_after_and_reads_back_on_demand(tp):
+    """Invariant (c): ``install_device_rows`` adopts tp-sharded device
+    arrays as the next snapshot (nothing crosses the link, epoch bumped, an
+    in-flight grow dropped), a later ``add`` appends after them and keeps
+    them, and ``snapshot()`` reads them back when called."""
+    from opencv_facerecognizer_tpu.utils import metric_names as mn
+
+    cap, dim, n = 256, 8, 200
+    g, metrics = _counted_gallery(cap, dim, tp, store_dtype=jnp.bfloat16)
+    g.add(_unit(RNG.normal(size=(4, dim)).astype(np.float32)),
+          np.arange(4, dtype=np.int32))
+    epoch0, uploaded0 = g.data.epoch, metrics.counter(mn.GALLERY_ROWS_UPLOADED)
+    rows = _bf16_exact(_unit(RNG.normal(size=(cap, dim)).astype(np.float32)))
+    data = g.data
+    emb = jax.device_put(jnp.asarray(rows, jnp.bfloat16), data.embeddings.sharding)
+    lab = jax.device_put(jnp.asarray(7000 + np.arange(cap), jnp.int32), data.labels.sharding)
+    val = jax.device_put(jnp.arange(cap) < n, data.valid.sharding)
+    g.install_device_rows(emb, lab, val, n)
+    assert g.data.embeddings is emb and g.size == n and g.data.epoch == epoch0 + 1
+    assert metrics.counter(mn.GALLERY_BULK_INSTALLS) == 1 == g.bulk_installs
+    assert metrics.counter(mn.GALLERY_ROWS_UPLOADED) == uploaded0  # none moved
+    assert len(g._host_emb) == 0  # the host holds none of them
+    lab1, _, idx1 = (np.asarray(v) for v in g.match(rows[[0, n - 1]], k=1))
+    np.testing.assert_array_equal(idx1[:, 0], [0, n - 1])
+    np.testing.assert_array_equal(lab1[:, 0], [7000, 7000 + n - 1])
+
+    extra = _bf16_exact(_unit(RNG.normal(size=(10, dim)).astype(np.float32)))
+    g.add(extra, np.arange(10, dtype=np.int32))
+    assert g.size == n + 10
+    assert metrics.counter(mn.GALLERY_ROWS_UPLOADED) == uploaded0 + 10
+    assert len(g._host_emb) >= 10 and g._host_base == n
+    lab2, _, idx2 = (np.asarray(v) for v in g.match(
+        np.concatenate([rows[[0, n - 1]], extra[[0, 9]]]), k=1))
+    np.testing.assert_array_equal(idx2[:, 0], [0, n - 1, n, n + 9])
+    np.testing.assert_array_equal(lab2[:, 0], [7000, 7000 + n - 1, 0, 9])
+    s_emb, s_lab, s_val, s_size = g.snapshot()
+    assert s_emb.shape == (cap, dim) and s_size == n + 10
+    np.testing.assert_array_equal(s_emb[:n], rows[:n])          # read back
+    np.testing.assert_allclose(s_emb[n:n + 10], _unit(extra), atol=1e-6)  # the mirror
+    assert s_val[:n + 10].all() and not s_val[n + 10:].any()
+    np.testing.assert_array_equal(s_lab[n - 1:n + 2], [7000 + n - 1, 0, 1])
+    # a snapshot of them restores into another gallery
+    other, _ = _counted_gallery(cap, dim, tp)
+    other.load_snapshot(s_emb, s_lab, s_val, s_size)
+    assert other.size == n + 10 and other.rows_uploaded == n + 10
+    np.testing.assert_array_equal(np.asarray(other.match(rows[[5]], k=1)[2])[:, 0], [5])
+    # wrong shapes and dtypes are refused before anything is touched
+    with pytest.raises(ValueError):
+        g.install_device_rows(emb.astype(jnp.float32), lab, val, n)
+    with pytest.raises(ValueError):
+        g.install_device_rows(emb, lab[: cap - 1], val, n)
+    assert g.size == n + 10
+
+
+def test_install_device_rows_drops_an_inflight_grow_and_grows_past_capacity():
+    import threading
+
+    mesh = make_mesh(dp=1, tp=2, devices=jax.devices()[:2])
+    g = ShardedGallery(capacity=8, dim=4, mesh=mesh, async_grow=True)
+    hold = threading.Event()
+    g.prewarm_hooks.append(lambda cap: hold.wait(5))
+    g.add(RNG.normal(size=(8, 4)).astype(np.float32), np.arange(8, dtype=np.int32))
+    g.add(RNG.normal(size=(4, 4)).astype(np.float32), np.arange(8, 12, dtype=np.int32))
+    assert g.pending_rows == 4
+    data = g.data
+    rows = jax.device_put(jnp.asarray(_unit(RNG.normal(size=(8, 4)).astype(np.float32))),
+                          data.embeddings.sharding)
+    g.install_device_rows(rows, data.labels, jax.device_put(jnp.ones(8, bool), data.valid.sharding), 8)
+    hold.set()
+    assert g.wait_ready(timeout=30)
+    assert g.size == 8 and g.pending_rows == 0 and g.data.embeddings is rows
+    # full: the next add grows ON the devices and keeps the installed rows
+    g.async_grow = False
+    g.add(RNG.normal(size=(3, 4)).astype(np.float32), np.array([70, 71, 72], np.int32))
+    assert g.capacity == 16 and g.size == 11
+    np.testing.assert_allclose(np.asarray(g.data.embeddings)[:8], np.asarray(rows))
+    np.testing.assert_array_equal(np.asarray(g.data.labels)[8:11], [70, 71, 72])
+    assert g.snapshot()[0].shape == (16, 4)
+
+
+def test_gallery_install_span_carries_rows_and_bytes():
+    from opencv_facerecognizer_tpu.utils.metrics import Metrics
+    from opencv_facerecognizer_tpu.utils.tracing import LIFECYCLE_TOPIC, Tracer
+
+    g, _ = _counted_gallery(64, 8, 2, store_dtype=jnp.bfloat16)
+    g.add(_unit(RNG.normal(size=(3, 8)).astype(np.float32)), np.arange(3, dtype=np.int32))
+    metrics, tracer = Metrics(), Tracer(ring_size=64, sample=1.0, seed=0)
+    g.attach_observability(metrics, tracer)
+    g.attach_observability(metrics, tracer)  # wired twice: counted once
+    from opencv_facerecognizer_tpu.utils import metric_names as mn
+    assert metrics.counter(mn.GALLERY_ROWS_UPLOADED) == 3
+    g.add(_unit(RNG.normal(size=(5, 8)).astype(np.float32)), np.arange(3, 8, dtype=np.int32))
+    data = g.data
+    g.install_device_rows(data.embeddings, data.labels, data.valid, 8)
+    spans = [s for s in tracer.snapshot(LIFECYCLE_TOPIC) if s["stage"] == "gallery_install"]
+    assert [(s["rows"], s["bytes"], s["source"]) for s in spans] == [
+        (5, 5 * 8 * 2, "host"), (8, 8 * 8 * 2, "device")]
+    assert metrics.counter(mn.GALLERY_ROWS_UPLOADED) == 8
+    assert metrics.counter(mn.GALLERY_BULK_INSTALLS) == 1
+
+
+def test_packed_labels_are_exact_past_two_to_the_24th():
+    """A watchlist of 50 M rows labels past 2^24, where a float32 rounds:
+    the packed result's lanes are int32 and carry a label as it is (and
+    the floats as their bits)."""
+    from opencv_facerecognizer_tpu.parallel.pipeline import (
+        RecognitionResult, pack_result, unpack_result)
+
+    labels = np.array([[-1, 0, 1, (1 << 24) + 1, 50331647 + 1032, 2**31 - 1]], np.int32).T
+    labels = labels.reshape(1, 6, 1)
+    result = RecognitionResult(
+        boxes=jnp.zeros((1, 6, 4)), det_scores=jnp.ones((1, 6)),
+        valid=jnp.ones((1, 6), bool), labels=jnp.asarray(labels),
+        similarities=jnp.full((1, 6, 1), 0.5))
+    packed = np.asarray(jax.jit(pack_result)(result))
+    assert packed.dtype == np.int32
+    out = unpack_result(packed, 1)
+    np.testing.assert_array_equal(out.labels, labels)
+    np.testing.assert_array_equal(out.similarities, np.float32(0.5))
+    assert out.valid.all() and (out.det_scores == 1).all() and not out.boxes.any()
