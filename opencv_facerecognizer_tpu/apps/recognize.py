@@ -334,7 +334,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--expo-port", type=int, default=None, metavar="PORT",
                    help="serve the read-only observability endpoint "
                         "(GET /metrics /prom /health /ledger /brownout "
-                        "/spans /attribution) on this TCP port; 0 binds "
+                        "/spans) on this TCP port; 0 binds "
                         "an ephemeral port (printed on stderr). Off-hot-"
                         "path threads; unset = off. /prom is Prometheus "
                         "text format; /health is the SLO verdict (503 "

@@ -174,6 +174,16 @@ class FrameBatcher:
         self._trace_topic = trace_topic
         self._lock = threading.Lock()
         self._not_empty = threading.Condition(self._lock)
+        # Seconds ``put`` and ``get_batch`` waited to acquire the lock and
+        # the acquisitions (one clock pair round each acquire), and the
+        # seconds ``get_batch`` spent in the condition's waits, summed
+        # under the lock itself; ``get_batch`` hands them to ``metrics``
+        # once a batch (``batcher_lock_wait_s``, ``batcher_lock_acquires``,
+        # ``batcher_pop_wait_s``), so the producers pay no counter call a
+        # frame for them.
+        self._lock_wait_s = 0.0
+        self._lock_acquires = 0
+        self._pop_wait_s = 0.0
         self._frames: deque = deque()
         self._dropped_malformed = 0
         self._dropped_overflow = 0
@@ -207,7 +217,10 @@ class FrameBatcher:
         dropped = None  # (reason, entry) settled outside the lock
         accepted = True
         closed = False
+        t_lock = time.monotonic()
         with self._not_empty:
+            self._lock_wait_s += time.monotonic() - t_lock
+            self._lock_acquires += 1
             if self._closed:
                 # Counted under the lock (the one sanctioned
                 # FrameBatcher._lock -> Metrics._lock nesting, cross-checked
@@ -386,9 +399,18 @@ class FrameBatcher:
         set, frames that outlived their freshness bound while queued are
         shed here — counted, journaled, and never dispatched."""
         stale: List[tuple] = []
+        lock_wait_s, lock_acquires, pop_wait_s = 0.0, 0, 0.0
         try:
+            t_lock = time.monotonic()
             with self._not_empty:
+                self._lock_wait_s += time.monotonic() - t_lock
+                self._lock_acquires += 1
                 popped = self._pop_batch_locked(block, stale)
+                if popped is not None:
+                    lock_wait_s, self._lock_wait_s = self._lock_wait_s, 0.0
+                    lock_acquires, self._lock_acquires = (
+                        self._lock_acquires, 0)
+                    pop_wait_s, self._pop_wait_s = self._pop_wait_s, 0.0
         finally:
             if stale:
                 if self.metrics is not None:
@@ -401,6 +423,10 @@ class FrameBatcher:
             return None
         items, count, full, buf = popped
         if self.metrics is not None:
+            self.metrics.incr_many(
+                (mn.BATCHER_LOCK_WAIT_S, lock_wait_s),
+                (mn.BATCHER_LOCK_ACQUIRES, lock_acquires),
+                (mn.BATCHER_POP_WAIT_S, pop_wait_s))
             self.metrics.incr(mn.BATCHER_BATCHES_SIZE if full
                               else mn.BATCHER_BATCHES_DEADLINE)
             self.metrics.incr(mn.BATCHER_FRAMES_BATCHED, count)
@@ -456,12 +482,12 @@ class FrameBatcher:
                 if age < deadline:
                     if not block:
                         return None
-                    self._not_empty.wait(timeout=deadline - age)
+                    self._wait_not_empty(deadline - age)
                     continue
             else:
                 if self._closed or not block:
                     return None
-                self._not_empty.wait(timeout=self.flush_timeout)
+                self._wait_not_empty(self.flush_timeout)
                 if not self._frames:
                     # Idle tick: give the caller a turn (the fallback
                     # serving loop drains its in-flight queue on None).
@@ -487,7 +513,7 @@ class FrameBatcher:
             # notifies this cv) or the timeout re-checks; the queued
             # frames age meanwhile, which is exactly the backpressure
             # signal admission + stale shedding act on.
-            self._not_empty.wait(timeout=min(self.flush_timeout, 0.01))
+            self._wait_not_empty(min(self.flush_timeout, 0.01))
         count = min(len(self._frames), self.batch_size)
         full = count >= self.batch_size
         items = [self._frames.popleft() for _ in range(count)]
@@ -503,6 +529,13 @@ class FrameBatcher:
         if self._ring is None:
             buf = self._buffer_pool.pop() if self._buffer_pool else None
         return items, count, full, buf
+
+    def _wait_not_empty(self, timeout: float) -> None:
+        """Caller holds the lock: one wait on the condition, its seconds
+        added to ``_pop_wait_s``."""
+        t0 = time.monotonic()
+        self._not_empty.wait(timeout=timeout)
+        self._pop_wait_s += time.monotonic() - t0
 
     @property
     def pending(self) -> int:

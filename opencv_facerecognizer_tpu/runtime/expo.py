@@ -3,8 +3,8 @@ runtime's observability state (observability layer, beside
 ``utils.tracing``).
 
 Everything the runtime already knows about itself — ``Metrics.summary()``,
-the admission ledger, the brownout level, the tracer's recent spans and
-the derived stage-attribution gauges — was previously reachable only by
+the admission ledger, the brownout level and the tracer's recent spans —
+was previously reachable only by
 publishing a ``stats`` control command into the frame stream, which (a)
 needs a connector client and (b) is unusable once the loop itself is the
 thing being debugged. ``ExpoServer`` exposes the same state over plain
@@ -35,8 +35,6 @@ path                    payload
                         merged, newest 256; limit is bounds-checked —
                         non-integer or non-positive values answer 400,
                         values beyond ``SPAN_LIMIT_MAX`` are clamped)
-``/attribution``        stage-attribution gauges, refreshed on read (see
-                        ``fold_attribution``)
 ``/replicas``           the topic router's replica registry
                         (``runtime.replication.TopicRouter.registry``):
                         per-replica health, routed counts, observed topic
@@ -60,26 +58,12 @@ Metrics surface (``expo_requests`` / ``expo_errors``). The one nuance:
 ``/health`` reads the monitor's LAST verdict; the evaluation itself runs
 on the serving loop's tick and (as a liveness backstop for wedged loops)
 on this server's background refresh thread — never on a request thread.
-
-**Stage attribution** (``fold_attribution``): one derived gauge family
-registered in ``utils.metric_names``:
-
-- ``stage_share_b<bucket>_<detect|crop|embed|match>`` — per-bucket stage
-  shares of the fused device step. The stages run inside ONE jitted call
-  at serving time (deliberately — the single-readback design), so live
-  per-stage splits are unobservable; the shares come from the
-  ablated-prefix stage table a benchmark run ON THIS MACHINE writes to
-  ``BENCH_DETAIL.json`` (``stage_attribution.per_batch``, by ``bench.py``)
-  for exactly the buckets the dispatch spans show serving. No such table
-  is committed — another machine's numbers must never ride a live gauge —
-  so these gauges stay UNSET until that benchmark has run here.
 """
 
 from __future__ import annotations
 
 import json
 import logging
-import os
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -89,11 +73,6 @@ from urllib.parse import parse_qs, urlparse
 from opencv_facerecognizer_tpu.runtime.promtext import render as render_prom
 from opencv_facerecognizer_tpu.runtime.slo import STATE_CRITICAL
 from opencv_facerecognizer_tpu.utils import metric_names as mn
-from opencv_facerecognizer_tpu.utils import tracing
-
-#: the fused step's in-device stages, in execution order (bench.py's
-#: ablated-prefix stage table uses the same names).
-DEVICE_STAGES = ("detect", "crop", "embed", "match")
 
 #: hard cap on ``/spans`` ``limit=`` — a scrape cannot ask this surface
 #: to serialize an unbounded span dump.
@@ -105,90 +84,18 @@ class _BadQuery(ValueError):
     """A malformed query parameter — mapped to HTTP 400 (the bounds-check
     contract: bad input is answered, never guessed at)."""
 
-#: default bench artifact location: resolved relative to the REPO (two
-#: levels above this module), not the process CWD — ``ocvf-recognize``
-#: launched from any directory must still find the stage table a local
-#: benchmark run wrote (none is committed; absent = gauges unset).
-DEFAULT_BENCH_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "BENCH_DETAIL.json")
-
-
-def load_stage_quotes(bench_path: str = DEFAULT_BENCH_PATH
-                      ) -> Dict[int, Dict[str, float]]:
-    """Per-batch-size stage cost quotes (ms) from the local bench
-    artifact's ``stage_attribution.per_batch`` table; ``{}`` when the
-    artifact (or the section) is absent — the gauges are then simply not
-    set, never fabricated."""
-    try:
-        with open(bench_path) as fh:
-            table = json.load(fh)["stage_attribution"]["per_batch"]
-    except (OSError, KeyError, ValueError, TypeError):
-        return {}
-    out: Dict[int, Dict[str, float]] = {}
-    for batch, stages in table.items():
-        try:
-            out[int(batch)] = {
-                s: float(stages[s]["ms_per_batch"])
-                for s in DEVICE_STAGES if s in stages
-            }
-        except (KeyError, TypeError, ValueError):
-            continue
-    return out
-
-
-def fold_attribution(tracer, metrics, bench_path: str = DEFAULT_BENCH_PATH,
-                     window_s: float = 30.0,
-                     _quotes_cache: Dict[str, Any] = {}) -> Dict[str, float]:
-    """Fold the tracer's recent batch spans into the derived
-    stage-attribution gauges (module docstring); returns the values set.
-    Cheap enough for a periodic background refresh: one ring snapshot +
-    host arithmetic. A successfully loaded bench quote table is cached
-    per path in the (deliberately shared) default-arg dict; a MISS is
-    never cached — an artifact written after startup is picked up on the
-    next refresh instead of being pinned absent for the process life."""
-    out: Dict[str, float] = {}
-    if tracer is None or metrics is None:
-        return out
-    quotes = _quotes_cache.get(bench_path)
-    if quotes is None:
-        quotes = load_stage_quotes(bench_path)
-        if quotes:
-            _quotes_cache[bench_path] = quotes
-    if not quotes:
-        return out
-    spans = tracer.snapshot(topic=tracing.BATCH_TOPIC)
-    lo = time.monotonic() - window_s
-    buckets = {s.get("bucket") for s in spans
-               if s.get("stage") == "dispatch" and s["t0"] >= lo
-               and s.get("bucket")}
-    for bucket in buckets:
-        # Nearest measured batch size stands in for unmeasured buckets
-        # (the ladder defaults 8/32/128 match the bench sweep exactly).
-        nearest = min(quotes, key=lambda b: abs(b - bucket))
-        stage_ms = quotes[nearest]
-        total = sum(stage_ms.values())
-        if total <= 0:
-            continue
-        for stage, ms in stage_ms.items():
-            share = ms / total
-            metrics.set_gauge(mn.STAGE_SHARE_PREFIX + f"b{bucket}_{stage}",
-                              share)
-            out[mn.STAGE_SHARE_PREFIX + f"b{bucket}_{stage}"] = share
-    return out
-
 
 class ExpoServer:
     """Read-only HTTP exposition of the serving runtime's state (module
     docstring). ``port=0`` binds an ephemeral port (read ``.port`` after
     construction). ``start()`` spawns the HTTP threads plus a background
-    gauge-refresh loop; ``stop()`` tears both down. Never wired into the
-    serving hot path — a wedged loop still answers."""
+    refresh loop (the SLO monitor's backstop tick); ``stop()`` tears both
+    down. Never wired into the serving hot path — a wedged loop still
+    answers."""
 
     def __init__(self, service=None, tracer=None, metrics=None,
                  host: str = "127.0.0.1", port: int = 0,
                  refresh_s: float = 2.0,
-                 bench_path: str = DEFAULT_BENCH_PATH,
                  slo=None, router=None, rollout=None, registry=None):
         self.service = service
         self.tracer = tracer if tracer is not None else getattr(
@@ -220,7 +127,6 @@ class ExpoServer:
         #: Falls back to the service's attached registry, like rollout.
         self.registry = registry
         self.refresh_s = float(refresh_s)
-        self.bench_path = bench_path
         self._started_t = time.monotonic()
         self._stop = threading.Event()
         self._refresh_thread: Optional[threading.Thread] = None
@@ -269,14 +175,10 @@ class ExpoServer:
             self._refresh_thread = None
 
     def _refresh_loop(self) -> None:
-        """Periodic fold of the derived gauges — off the hot path, so the
-        exposition surface stays current even when nobody polls it (the
-        gauges also land in the ``--metrics-jsonl`` stream)."""
+        """The SLO monitor's backstop tick, off the hot path: ``/health``
+        stays current even when the serving loop, its primary ticker, is
+        wedged."""
         while not self._stop.wait(timeout=self.refresh_s):
-            # The backstop tick runs FIRST and in its own try: a
-            # persistently-failing attribution fold must not starve the
-            # /health liveness backstop — that backstop exists for exactly
-            # the moments when other parts of the system are misbehaving.
             if self.slo is not None:
                 try:
                     # Backstop tick (interval-throttled inside the
@@ -292,14 +194,6 @@ class ExpoServer:
                         # backstop, so triage points at the monitor, not
                         # the HTTP surface.
                         self.metrics.incr(mn.SLO_TICK_ERRORS)
-            try:
-                fold_attribution(self.tracer, self.metrics,
-                                 bench_path=self.bench_path)
-            except Exception:  # noqa: BLE001 — refresh must never die
-                logging.getLogger(__name__).exception(
-                    "expo attribution refresh failed")
-                if self.metrics is not None:
-                    self.metrics.incr(mn.EXPO_ERRORS)
 
     # ---- request handling ----
 
@@ -311,9 +205,8 @@ class ExpoServer:
         if path in ("/", "/index"):
             return {
                 "endpoints": ["/", "/metrics", "/prom", "/health", "/ledger",
-                              "/brownout", "/spans", "/attribution",
-                              "/replicas", "/rollout", "/registry",
-                              "/tracks"],
+                              "/brownout", "/spans", "/replicas",
+                              "/rollout", "/registry", "/tracks"],
                 "uptime_s": round(time.monotonic() - self._started_t, 1),
                 "brownout_level": getattr(service, "brownout_level", None),
                 "health": (self.slo.state if self.slo is not None else None),
@@ -337,9 +230,6 @@ class ExpoServer:
             topic = (query.get("topic") or [None])[0]
             return {"topics": self.tracer.topics(),
                     "spans": self.tracer.snapshot(topic=topic, limit=limit)}
-        if path == "/attribution":
-            return fold_attribution(self.tracer, self.metrics,
-                                    bench_path=self.bench_path)
         if path == "/replicas":
             # Same unwired shape as /health: a null payload with a
             # pointer, never a 404 — the path is part of the contract.

@@ -16,9 +16,8 @@ orchestrator/scrape stack speaks the Prometheus text format
   families (``frames_rejected_<reason>``, ``batcher_dropped_<reason>``,
   ``slo_burn_<objective>``, ``slo_events_<reason>``,
   ``track_flushes_<reason>``, ``transport_fault_<kind>``,
-  ``router_rejected_<reason>``,
-  ``stage_share_b<bucket>_<stage>``) become one metric each with a
-  ``reason=`` / ``objective=`` / ``bucket=``+``stage=`` label instead of
+  ``router_rejected_<reason>``) become one metric each with a
+  ``reason=`` / ``objective=`` / ``kind=`` label instead of
   N single-sample families — the Prometheus-idiomatic shape, and the
   reason label values are escaped per the exposition rules (``\\\\``,
   ``\\"``, ``\\n``).
@@ -43,8 +42,7 @@ from opencv_facerecognizer_tpu.utils import metric_names as mn
 #: every family name this module emits is prefixed with this namespace.
 NAMESPACE = "ocvf"
 
-#: prefix-family -> (metric name, label key(s)). ``stage_share_`` gets
-#: special two-label parsing (``b<bucket>_<stage>``) below.
+#: prefix-family -> (metric name, label key).
 _LABEL_FAMILIES: Tuple[Tuple[str, str, str], ...] = (
     (mn.FRAMES_REJECTED_PREFIX, "frames_rejected", "reason"),
     (mn.BATCHER_DROPPED_PREFIX, "batcher_dropped", "reason"),
@@ -57,7 +55,6 @@ _LABEL_FAMILIES: Tuple[Tuple[str, str, str], ...] = (
 
 _NAME_RE = re.compile(r"[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_NAME_RE = re.compile(r"[a-zA-Z_][a-zA-Z0-9_]*$")
-_STAGE_SHARE_RE = re.compile(r"b(\d+)_([a-zA-Z0-9_]+)$")
 
 
 def escape_label_value(value: str) -> str:
@@ -125,11 +122,6 @@ class _Family:
 def _fold_family(name: str) -> Optional[Tuple[str, Dict[str, str]]]:
     """``(family metric name, labels)`` when ``name`` belongs to a
     registered dynamic prefix family; None for plain names."""
-    if name.startswith(mn.STAGE_SHARE_PREFIX):
-        m = _STAGE_SHARE_RE.match(name[len(mn.STAGE_SHARE_PREFIX):])
-        if m:
-            return "stage_share", {"bucket": m.group(1), "stage": m.group(2)}
-        return None
     for prefix, family, label in _LABEL_FAMILIES:
         if name.startswith(prefix) and len(name) > len(prefix):
             return family, {label: name[len(prefix):]}

@@ -244,6 +244,11 @@ class _ReadbackBlocker:
 CHIP_BOUND_SHARE = 2.0 / 3.0
 CHIP_BOUND_ALPHA = 0.05
 
+#: Admitted frames between two reads of the handler thread's CPU clock
+#: (``intake_thread_cpu_s``): a batch's worth, so the handler pays the
+#: system call as often as the loop and the worker pay theirs.
+INTAKE_CPU_EVERY = 128
+
 
 class _Leaf:
     """One leaf of the serving loop's time (README "Observability", the
@@ -453,6 +458,12 @@ class RecognizerService:
         # thread only.
         self._loop_busy: Dict[str, float] = {}
         self._dispatch_span = 0
+        # The readback worker's CPU clock at its last ``_publish``'s end
+        # (``readback_cpu_s`` runs from there), and for each thread that
+        # runs ``_on_frame`` its frames since it last read its CPU clock
+        # and that reading (``intake_thread_cpu_s``).
+        self._publish_cpu_mark: Optional[float] = None
+        self._intake_cpu_marks: Dict[int, List[float]] = {}
         # The batch whose gate went to the device ahead of the step before
         # it (``_serve_one``), until the next iteration serves it — kept
         # across a crash of the loop for the restarted one — and the
@@ -895,10 +906,16 @@ class RecognizerService:
                 **attrs)
         return _Leaf(self._loop_busy, stage, span)
 
-    def _flush_loop_busy(self, wall: float) -> None:
+    def _flush_loop_busy(self, wall: float, cpu: float) -> None:
         """One ``incr`` per leaf the iteration passed, the rest of its
         wall time under ``loop_s_unnamed``: the counters tile the loop's
-        time, so a window's deltas say where a lost second sat."""
+        time, so a window's deltas say where a lost second sat. Beside
+        them ``loop_cpu_s``, the seconds of CPU the loop's thread ran in
+        the iteration (``cpu``): the wall time outside the waits the loop
+        makes by design, less it, is what the thread waited for the
+        interpreter or a lock. One read of the CPU clock an iteration,
+        not two a leaf: it is a system call, microseconds where the wall
+        clock takes a twentieth of one (PERF.md section 6, PR 41)."""
         busy = self._loop_busy
         self._chip_wait_s += CHIP_BOUND_ALPHA * (
             busy.get("gate_wait", 0.0) - self._chip_wait_s)
@@ -910,7 +927,7 @@ class RecognizerService:
         busy.clear()
         self.metrics.incr(mn.LOOP_S_PREFIX + "unnamed",
                           max(0.0, wall - named))
-        self.metrics.incr(mn.LOOP_BATCHES)
+        self.metrics.incr_many((mn.LOOP_CPU_S, cpu), (mn.LOOP_BATCHES, 1.0))
 
     # ---- cascade early-exit gate (ISSUE 13) ----
 
@@ -1296,6 +1313,27 @@ class RecognizerService:
                   else tracing.NULL_SPAN):
                 self._intake_admitted(msg, priority, tid)
             self.metrics.incr(mn.INTAKE_S, time.monotonic() - t_in)
+            self._count_intake_thread_cpu()
+
+    def _count_intake_thread_cpu(self) -> None:
+        """``intake_thread_cpu_s``: the CPU of the thread that runs the
+        handler, read off its own clock once in ``INTAKE_CPU_EVERY``
+        admitted frames and counted whole (the clock is cumulative, so
+        nothing between two reads is lost): in the handler and out of it,
+        so an upper bound of the CPU inside ``intake_s``. A read a frame
+        would cost the frame more than its bookkeeping does (PERF.md
+        section 6, PR 41)."""
+        ident = threading.get_ident()
+        mark = self._intake_cpu_marks.get(ident)
+        if mark is None:
+            self._intake_cpu_marks[ident] = [0, time.thread_time()]
+            return
+        mark[0] += 1
+        if mark[0] >= INTAKE_CPU_EVERY:
+            cpu = time.thread_time()
+            if cpu > mark[1]:  # not a new thread under an old identifier
+                self.metrics.incr(mn.INTAKE_THREAD_CPU_S, cpu - mark[1])
+            mark[0], mark[1] = 0, cpu
 
     def _intake_admitted(self, msg, priority: int, tid: int) -> None:
         """Decode one admitted frame and hand it to the batcher (or to
@@ -1680,6 +1718,7 @@ class RecognizerService:
         # An iteration runs from the end of the one before to the end of
         # the batch it serves; idle ticks in between belong to it.
         t_iter = time.monotonic()
+        c_iter = time.thread_time()
         # A batch the loop already holds (``_ahead``: its gate went to the
         # device ahead of the step before it) is served before anything
         # is popped, after stop() and after a supervisor's restart too:
@@ -1740,9 +1779,10 @@ class RecognizerService:
                     continue
                 held = self._open_batch(batch, t_pop, t_popped)
             self._serve_one(held)
+            c_end = time.thread_time()
             t_end = time.monotonic()
-            self._flush_loop_busy(t_end - t_iter)
-            t_iter = t_end
+            self._flush_loop_busy(t_end - t_iter, c_end - c_iter)
+            t_iter, c_iter = t_end, c_end
 
     def _open_batch(self, batch, t_pop: float, t_popped: float) -> _Held:
         """A popped batch's way through the loop up to its gate's
@@ -2331,6 +2371,7 @@ class RecognizerService:
         so stop() after drain() loses nothing. The entry stays at the head
         of the deque while we wait — the backpressure slot is only freed
         (cv notified) once its batch's device round-trip actually ended."""
+        self._publish_cpu_mark = None  # a new thread's CPU clock starts anew
         while True:
             with self._inflight_cv:
                 while self._running and not self._inflight:
@@ -2480,6 +2521,7 @@ class RecognizerService:
         from opencv_facerecognizer_tpu.parallel.pipeline import unpack_result
 
         t_pub = time.monotonic()
+        c_pub = time.thread_time()
         published = 0
         # ``tracker.update``: seconds and calls of this batch, counted
         # once below; a span (child of ``publish_span``) per sampled frame.
@@ -2604,8 +2646,19 @@ class RecognizerService:
                                    mn.FRAMES_DROPPED_CRASHED,
                                    "publish.crashed", batch=batch_tid)
             # Busy time of publishing, beside its count (frames_completed
-            # above): the whole of this method, settle spans included.
-            self.metrics.incr(mn.PUBLISH_S, time.monotonic() - t_pub)
+            # above): the whole of this method, settle spans included, by
+            # the wall clock and by this thread's CPU clock (two reads a
+            # batch). ``readback_cpu_s`` runs from one batch's second read
+            # to the next's: the thread's CPU as a whole, so less
+            # ``publish_cpu_s`` it is what the worker ran outside this
+            # method (the materialize, the latency observations, the
+            # recycle), which no wall counter covers.
+            c_end = time.thread_time()
+            wall = time.monotonic() - t_pub
+            mark, self._publish_cpu_mark = self._publish_cpu_mark, c_end
+            self.metrics.incr_many(
+                (mn.PUBLISH_S, wall), (mn.PUBLISH_CPU_S, c_end - c_pub),
+                (mn.READBACK_CPU_S, c_end - (c_pub if mark is None else mark)))
 
     # ---- enrolment (interactive-trainer protocol) ----
 

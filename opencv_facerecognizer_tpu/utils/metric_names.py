@@ -57,6 +57,15 @@ LOOP_S_PREFIX = "loop_s_"
 LOOP_LEAVES = ("pop_wait", "track_cache", "gate_enqueue", "gate_wait",
                "compact", "track_miss", "settle_early", "upload",
                "step_enqueue", "inflight_wait")
+#: seconds of CPU the loop's thread ran (``time.thread_time()``), one
+#: read an iteration where ``loop_s_*`` are flushed: the wall time outside
+#: the waits the loop makes by design (``loop_s_*`` less ``gate_wait``,
+#: ``inflight_wait`` and ``batcher_pop_wait_s``) less this is what the
+#: thread waited for the interpreter or a lock. Native code that runs
+#: with the interpreter released counts as CPU. One read a batch and not
+#: two a leaf because the clock is a system call: 5.7 us on the chip's
+#: host against 0.07 for ``time.monotonic()`` (PERF.md section 6, PR 41).
+LOOP_CPU_S = "loop_cpu_s"
 #: iterations of the serving loop that popped a batch (the denominator of
 #: every ``loop_s_*`` per-batch quotient).
 LOOP_BATCHES = "loop_batches"
@@ -76,10 +85,23 @@ EARLY_EXITS_DEFERRED = "early_exits_deferred"
 PUBLISH_S = "publish_s"
 PUBLISH_S_TRACK_UPDATE = "publish_s_track_update"
 TRACK_UPDATES = "track_updates"
+#: ``publish_s``'s CPU twin (the worker's ``time.thread_time()`` at the
+#: same two instants), and the worker's CPU as a whole, from one batch's
+#: second read to the next's: what it ran outside ``_publish`` too (the
+#: materialize, the per-frame latency observations, the recycle).
+PUBLISH_CPU_S = "publish_cpu_s"
+READBACK_CPU_S = "readback_cpu_s"
 #: the connector thread's handler from entry to the return of
 #: ``batcher.put`` (on the JPEG path plus the decode worker's hand-over),
 #: admitted frames only: beside ``frames_admitted``.
 INTAKE_S = "intake_s"
+#: seconds of CPU the thread that runs the handler ran, in the handler
+#: and out of it (the connector's receive loop; a benchmark's generator):
+#: its own CPU clock read once in ``INTAKE_CPU_EVERY`` admitted frames and
+#: counted whole. An upper bound of the CPU inside ``intake_s``, so
+#: ``intake_s`` less it is at least what the handler waited (for the
+#: interpreter after the native decode, for the batcher's lock).
+INTAKE_THREAD_CPU_S = "intake_thread_cpu_s"
 #: admitted wire-form frames (``__frame__``: base64) that the native
 #: decoder turned into their array, the interpreter's lock released; over
 #: ``frames_admitted``, 100 % where every frame arrives in wire form and
@@ -176,6 +198,18 @@ BATCHER_BATCHES_SIZE = "batcher_batches_size"
 BATCHER_BATCHES_DEADLINE = "batcher_batches_deadline"
 BATCHER_BUFFER_REUSE = "batcher_buffer_reuse"
 BATCHER_FLUSH_DEADLINE_MS = "batcher_flush_deadline_ms"
+#: seconds ``put`` (the intake threads) and ``get_batch`` (the serving
+#: loop) spent acquiring the batcher's one lock, and the acquisitions:
+#: one clock pair round each acquire, summed under the lock itself and
+#: handed over by ``get_batch`` once a batch. The condition's wait for
+#: frames is not in it (that is the loop's leaf ``pop_wait``).
+BATCHER_LOCK_WAIT_S = "batcher_lock_wait_s"
+BATCHER_LOCK_ACQUIRES = "batcher_lock_acquires"
+#: seconds ``get_batch`` spent in its condition's waits (for frames, for
+#: a batch to close, for a staging buffer): the part of the loop's leaf
+#: ``pop_wait`` that is a wait by design; the rest of the leaf is the
+#: batch's assembly. Handed over with the lock's sums.
+BATCHER_POP_WAIT_S = "batcher_pop_wait_s"
 
 # ---- ingest pipeline (runtime.ingest) ---------------------------------------
 #: staging-ring buffer allocations: the per-rung preallocation at
@@ -366,9 +400,6 @@ TRACE_SPAN_ERRORS = "trace_span_errors"
 TRACE_SPANS_SHED = "trace_spans_shed"
 EXPO_REQUESTS = "expo_requests"
 EXPO_ERRORS = "expo_errors"
-#: derived stage-attribution gauge family:
-#: ``stage_share_b<bucket>_<detect|crop|embed|match>``
-STAGE_SHARE_PREFIX = "stage_share_"
 
 # ---- signals layer: SLO / health / watchdogs (runtime.slo) -----------------
 #: health state machine gauge: 0 = ok, 1 = warn, 2 = critical.
@@ -545,8 +576,7 @@ LEDGER_DROP_COUNTERS = (
 )
 
 #: The dynamic prefix families promtext folds into labeled Prometheus
-#: families (plus STAGE_SHARE_PREFIX, which gets its own two-label
-#: parser).  promtext._LABEL_FAMILIES must mirror this set exactly.
+#: families.  promtext._LABEL_FAMILIES must mirror this set exactly.
 PROM_FOLDED_PREFIXES = (
     FRAMES_REJECTED_PREFIX,
     BATCHER_DROPPED_PREFIX,

@@ -278,6 +278,28 @@ def test_metrics_registry_constants_and_prefix_concat(tmp_path):
                                          ("metrics-registry", 9)]
 
 
+def test_metrics_registry_checks_every_pair_of_incr_many(tmp_path):
+    """``Metrics.incr_many((name, value), ...)``: each pair's name is held
+    to the registry like ``incr``'s; an argument that is no literal pair
+    hides its names, and is flagged once."""
+    findings = lint_tree(tmp_path, {
+        "utils/metric_names.py": METRIC_FIXTURE_REGISTRY,
+        "app.py": """\
+            import utils.metric_names as mn
+
+            def f(metrics, reason, pairs):
+                metrics.incr_many((mn.GOOD, 1.0), ("other_metric", 2.0),
+                                  (mn.FAMILY_PREFIX + reason, 3.0))
+                metrics.incr_many((mn.GOOD, 1.0), ("bad_typo_metric", 2.0))
+                metrics.incr_many((mn.GOOD, 1.0), (mn.DOES_NOT_EXIST, 2.0))
+                metrics.incr_many(*pairs)
+            """,
+    }, rules=["metrics-registry"])
+    assert rules_and_lines(findings) == [("metrics-registry", 6),
+                                         ("metrics-registry", 7),
+                                         ("metrics-registry", 8)]
+
+
 def test_metrics_registry_prefix_strictness(tmp_path):
     """Prefix/name pools stay disjoint: a bare prefix is not a counter
     name, a full name is not a prefix, and concatenation requires a

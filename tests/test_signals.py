@@ -752,9 +752,7 @@ def _service_with_expo(slo_interval_s=0.05, refresh_s=10.0):
     pipeline, service, connector = build_overload_stack(
         frame_shape=FRAME_HW, batch_size=4, dispatch_s=0.0,
         metrics=metrics, slo_monitor=monitor, tracer=tracer)
-    expo = ExpoServer(service, port=0, refresh_s=refresh_s,
-                      bench_path=os.path.join(REPO_ROOT,
-                                              "BENCH_DETAIL.json"))
+    expo = ExpoServer(service, port=0, refresh_s=refresh_s)
     return pipeline, service, connector, expo, monitor, metrics
 
 

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from opencv_facerecognizer_tpu.runtime.connector import FakeConnector
-from opencv_facerecognizer_tpu.runtime.expo import ExpoServer, fold_attribution
+from opencv_facerecognizer_tpu.runtime.expo import ExpoServer
 from opencv_facerecognizer_tpu.runtime.fakes import InstantPipeline
 from opencv_facerecognizer_tpu.runtime.faults import FaultInjector
 from opencv_facerecognizer_tpu.runtime.journal import DeadLetterJournal
@@ -284,8 +284,7 @@ def test_expo_endpoint_read_only_contract():
     _pipe, connector, service = _make_service(tracer)
     service.start(warmup=False)
     expo = ExpoServer(service, tracer=tracer, metrics=service.metrics,
-                      port=0, bench_path=os.path.join(REPO_ROOT,
-                                                      "BENCH_DETAIL.json"))
+                      port=0)
     expo.start()
     base = f"http://{expo.host}:{expo.port}"
     try:
@@ -304,13 +303,13 @@ def test_expo_endpoint_read_only_contract():
         status, spans = _get(base + f"/spans?topic={FRAME_TOPIC}&n=1000")
         assert {s["stage"] for s in spans["spans"]} \
             == {"receive", "intake", "queue_wait", "settle"}
-        status, attribution = _get(base + "/attribution")
-        assert status == 200 and "device_busy_fraction" not in attribution
-
-        # Unknown path -> 404; every mutating verb -> 405 (read-only).
-        with pytest.raises(urllib.error.HTTPError) as err:
-            _get(base + "/nope")
-        assert err.value.code == 404
+        # Unknown path -> 404 (the stage-attribution exporter is gone:
+        # nothing fed it); every mutating verb -> 405 (read-only).
+        assert "/attribution" not in index["endpoints"]
+        for path in ("/nope", "/attribution"):
+            with pytest.raises(urllib.error.HTTPError) as err:
+                _get(base + path)
+            assert err.value.code == 404, path
         for method in ("POST", "PUT", "DELETE"):
             req = urllib.request.Request(base + "/metrics", data=b"{}",
                                          method=method)
@@ -321,30 +320,6 @@ def test_expo_endpoint_read_only_contract():
     finally:
         expo.stop()
         service.stop()
-
-
-# ---- stage attribution ----
-
-
-def test_fold_attribution_sets_registered_gauges():
-    tracer = Tracer(sample=1.0)
-    batch_tid = tracer.new_trace()
-    tracer.emit(batch_tid, "dispatch", topic=tracing.BATCH_TOPIC,
-                dur=0.001, bucket=8, frames=8)
-    tracer.emit(batch_tid, "ready_wait", topic=tracing.BATCH_TOPIC, dur=0.01)
-    metrics = Metrics()
-    gauges = fold_attribution(tracer, metrics,
-                              bench_path=os.path.join(REPO_ROOT,
-                                                      "BENCH_DETAIL.json"))
-    assert "device_busy_fraction" not in gauges
-    # Stage shares come from the committed bench stage table for the
-    # observed bucket, sum to ~1, and ride registered gauge names.
-    shares = {k: v for k, v in gauges.items()
-              if k.startswith("stage_share_b8_")}
-    if shares:  # only when BENCH_DETAIL.json carries the stage table
-        assert sum(shares.values()) == pytest.approx(1.0)
-        assert metrics.gauge("stage_share_b8_detect") == shares[
-            "stage_share_b8_detect"]
 
 
 # ---- leaf spans, parents, profiler annotations, busy-time counters ----
@@ -413,9 +388,11 @@ def _run_video(tracer, monkeypatch=None, n=96):
     flushes = []
     flush = service._flush_loop_busy
 
-    def recorded_flush(wall):
-        flush(wall)
-        flushes.append((time.monotonic(), wall))
+    def recorded_flush(wall, cpu):
+        flush(wall, cpu)
+        # (instant, the iteration's wall and CPU seconds, the loop
+        # thread's CPU clock at that instant)
+        flushes.append((time.monotonic(), wall, cpu, time.thread_time()))
 
     service._flush_loop_busy = recorded_flush
     counters_seen = []
@@ -599,7 +576,7 @@ def test_busy_counters_registered_monotone_and_tile_the_loops_wall_time(
     # leaves + unnamed == the wall time the loop handed to each flush ...
     flushes = video_run["flushes"]
     assert final[mn.LOOP_BATCHES] == len(flushes)
-    walls = sum(wall for _at, wall in flushes)
+    walls = sum(flush[1] for flush in flushes)
     assert sum(loop.values()) == pytest.approx(walls, abs=1e-6)
     # ... and the flushes are contiguous from the loop's start to the last
     last = flushes[-1][0]
@@ -608,6 +585,165 @@ def test_busy_counters_registered_monotone_and_tile_the_loops_wall_time(
     # the parts lie inside the wholes
     assert final[mn.PUBLISH_S_TRACK_UPDATE] <= final[mn.PUBLISH_S]
     assert final[mn.INTAKE_S] > 0 and final["frames_admitted"] == 96
+
+
+def test_cpu_counters_registered_and_count_each_threads_cpu(video_run):
+    from opencv_facerecognizer_tpu.utils import metric_names as mn
+
+    new = {mn.LOOP_CPU_S, mn.PUBLISH_CPU_S, mn.READBACK_CPU_S,
+           mn.INTAKE_THREAD_CPU_S, mn.BATCHER_LOCK_WAIT_S,
+           mn.BATCHER_LOCK_ACQUIRES, mn.BATCHER_POP_WAIT_S}
+    assert new <= set(mn.all_names())
+    # no name of the new ones falls into the family ``loop_s_*``
+    assert not any(name.startswith(mn.LOOP_S_PREFIX) for name in new)
+    final = video_run["metrics"].counters()
+    flushes = video_run["flushes"]
+    # ``loop_cpu_s`` is the iterations' CPU, flushed beside ``loop_s_*`` ...
+    assert final[mn.LOOP_CPU_S] == pytest.approx(
+        sum(flush[2] for flush in flushes), rel=1e-9)
+    assert all(0.0 <= flush[2] <= flush[1] + 1e-3 for flush in flushes)
+    # ... and the iterations are contiguous from the thread's start to the
+    # last flush: within 5 % of the thread's own CPU clock read there
+    assert final[mn.LOOP_CPU_S] == pytest.approx(flushes[-1][3], rel=0.05)
+    # no more CPU than wall time less the waits the loop makes by design
+    # (the condition's, inside ``pop_wait``; the fake device has none)
+    loop_wall = sum(v for k, v in final.items()
+                    if k.startswith(mn.LOOP_S_PREFIX))
+    assert final[mn.LOOP_CPU_S] <= (loop_wall - final[mn.BATCHER_POP_WAIT_S]
+                                    + 1e-3)
+    assert 0.0 < final[mn.BATCHER_POP_WAIT_S] \
+        <= final[mn.LOOP_S_PREFIX + "pop_wait"]
+    seen = video_run["counters_seen"] + [final]
+    for before, after in zip(seen, seen[1:]):
+        assert all(after.get(k, 0.0) >= before.get(k, 0.0) for k in new)
+
+
+@pytest.mark.parametrize("part, whole", [
+    ("publish_cpu_s", "publish_s"),
+    ("publish_cpu_s", "readback_cpu_s"),
+    ("batcher_pop_wait_s", "loop_s_pop_wait"),
+])
+def test_cpu_sections_lie_inside_their_wall_twins_and_wholes(video_run, part,
+                                                             whole):
+    final = video_run["metrics"].counters()
+    assert 0.0 < final[part] <= final[whole]
+
+
+def test_intake_threads_cpu_is_read_once_in_a_batchs_worth_of_frames(
+        monkeypatch):
+    from opencv_facerecognizer_tpu.runtime import recognizer
+
+    _pipe, connector, service = _make_service(None)
+    reads = []
+    real = time.thread_time
+
+    def counted():
+        reads.append(threading.get_ident())
+        return real()
+
+    service.start(warmup=False)
+    try:
+        me = threading.get_ident()
+        monkeypatch.setattr(recognizer.time, "thread_time", counted)
+        n = 2 * recognizer.INTAKE_CPU_EVERY + 5
+        _drive(connector, 1)
+        until = real() + 0.02
+        while real() < until:  # CPU this thread runs outside the handler
+            pass
+        _drive(connector, n - 1, start=1)
+        monkeypatch.setattr(recognizer.time, "thread_time", real)
+        assert service.drain(timeout=20.0)
+    finally:
+        service.stop()
+    # the first frame on a thread reads its clock, then every 128th does
+    assert reads.count(me) == 3
+    final = service.metrics.counters()
+    assert final["frames_admitted"] == n
+    # whole-thread CPU: the 20 ms outside the handler are in it
+    assert final["intake_thread_cpu_s"] >= 0.02 and final["intake_s"] > 0.0
+
+
+def test_a_held_batcher_lock_shows_in_its_counter_and_in_intakes_wall_time():
+    _pipe, _connector, service = _make_service(None)  # never started
+    batcher, metrics = service.batcher, service.metrics
+    frame = np.zeros(FRAME_HW, np.float32)
+    held = threading.Event()
+
+    def hold():
+        with batcher._lock:
+            held.set()
+            time.sleep(0.05)  # the put below has to see 25 ms of it
+
+    holder = threading.Thread(target=hold)
+    holder.start()
+    assert held.wait(5.0)
+    c0 = time.thread_time()
+    service._on_frame(FRAME_TOPIC, {"frame": frame, "meta": {"seq": 0}})
+    cpu = time.thread_time() - c0
+    holder.join()
+    # summed in the batcher's own attributes: no counter call a frame
+    assert batcher._lock_wait_s >= 0.025 and batcher._lock_acquires == 1
+    assert metrics.counter("batcher_lock_wait_s") == 0.0
+    assert metrics.counter("batcher_lock_acquires") == 0.0
+    # the wait is wall time of intake and no CPU of its thread
+    assert metrics.counter("intake_s") - cpu >= 0.025
+    for seq in range(1, 4):
+        service._on_frame(FRAME_TOPIC, {"frame": frame, "meta": {"seq": seq}})
+    # ... and handed over by the get_batch that pops a batch: its own
+    # acquisition with the four puts'
+    assert batcher.get_batch(block=False).count == 4
+    assert metrics.counter("batcher_lock_wait_s") >= 0.025
+    assert metrics.counter("batcher_lock_acquires") == 5
+    assert batcher._lock_wait_s == 0.0 and batcher._lock_acquires == 0
+    assert batcher.get_batch(block=False) is None  # nothing to hand over
+    assert metrics.counter("batcher_lock_acquires") == 5
+    assert batcher._lock_acquires == 1  # kept for the next batch
+
+
+def test_get_batchs_condition_waits_are_counted_apart_from_its_assembly():
+    from opencv_facerecognizer_tpu.runtime.batcher import FrameBatcher
+
+    metrics = Metrics()
+    batcher = FrameBatcher(4, FRAME_HW, flush_timeout=0.03, metrics=metrics)
+    batcher.put(np.zeros(FRAME_HW, np.float32))
+    t0 = time.monotonic()
+    batch = batcher.get_batch(block=True)  # closes by deadline, 30 ms on
+    waited = time.monotonic() - t0
+    assert batch.count == 1 and waited >= 0.025
+    assert 0.02 <= metrics.counter("batcher_pop_wait_s") <= waited
+    # a full batch is popped without a wait
+    for _ in range(4):
+        batcher.put(np.zeros(FRAME_HW, np.float32))
+    before = metrics.counter("batcher_pop_wait_s")
+    assert batcher.get_batch(block=True).count == 4
+    assert metrics.counter("batcher_pop_wait_s") == before
+
+
+@pytest.mark.parametrize("work, cpu_bounds", [
+    ("sleep", (0.0, 0.005)),
+    ("spin", (0.015, 1.0)),
+])
+def test_the_loops_flush_counts_wall_and_cpu_of_an_iteration(work,
+                                                             cpu_bounds):
+    """What the serving loop does round an iteration, round 20 ms of
+    sleep and of spin: the wall clock reads both, the CPU clock the spin
+    alone."""
+    _pipe, _connector, service = _make_service(None)  # never started
+    t0, c0 = time.monotonic(), time.thread_time()
+    with service._leaf("compact"):
+        if work == "sleep":
+            time.sleep(0.020)
+        else:
+            until = time.thread_time() + 0.020
+            while time.thread_time() < until:
+                pass
+    c1, t1 = time.thread_time(), time.monotonic()
+    service._flush_loop_busy(t1 - t0, c1 - c0)
+    final = service.metrics.counters()
+    assert final["loop_s_compact"] >= 0.020 and final["loop_batches"] == 1
+    assert cpu_bounds[0] <= final["loop_cpu_s"] <= cpu_bounds[1]
+    assert final["loop_cpu_s"] <= final["loop_s_compact"] \
+        + final["loop_s_unnamed"]
 
 
 def test_no_annotation_is_constructed_without_a_tracer(monkeypatch):

@@ -5,7 +5,8 @@ surface must be a constant from the canonical registry module
 The chaos soaks and the admission ledger compare counters *by string name*
 across 11+ files — one typo silently breaks an accounting invariant with no
 error anywhere.  This rule kills the drift: write sites (``incr`` /
-``observe`` / ``set_gauge``) and read sites (``counter`` / ``percentile`` /
+``observe`` / ``set_gauge``, and each ``(name, value)`` pair handed to
+``incr_many``) and read sites (``counter`` / ``percentile`` /
 ``counters_with_prefix``) are both checked.  Accepted argument shapes:
 
 - a string literal whose value is registered,
@@ -36,6 +37,9 @@ NAME_METHODS = frozenset({"incr", "observe", "set_gauge", "counter",
                           # the connectors' and tracker's None-guarded shims
                           "_count", "_incr"})
 GENERIC_METHODS = frozenset({"counter", "percentile"})
+#: ``Metrics.incr_many((name, value), ...)``: every positional argument is
+#: a literal pair whose first element is a metric name.
+PAIRS_METHODS = frozenset({"incr_many"})
 
 
 def _metrics_ish_receiver(func: ast.Attribute) -> bool:
@@ -107,7 +111,9 @@ class MetricsRegistryChecker(Checker):
 
     def __init__(self) -> None:
         self._registry_tree: Optional[ast.Module] = None
-        self._pending: List[Tuple[FileContext, _FileImports, ast.Call, str]] = []
+        #: (file, its imports, the call, the method, the name expression)
+        self._pending: List[Tuple[FileContext, _FileImports, ast.Call, str,
+                                  ast.expr]] = []
 
     def check_file(self, ctx: FileContext) -> List[Finding]:
         norm = ctx.path.replace("\\", "/")
@@ -115,16 +121,31 @@ class MetricsRegistryChecker(Checker):
             self._registry_tree = ctx.tree
             return []
         imports = _FileImports(ctx.tree)
+        findings: List[Finding] = []
         for node in ast.walk(ctx.tree):
-            if (isinstance(node, ast.Call)
+            if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in NAME_METHODS
                     and node.args):
-                if (node.func.attr in GENERIC_METHODS
+                continue
+            method = node.func.attr
+            if method in PAIRS_METHODS:
+                for pair in node.args:
+                    if isinstance(pair, ast.Tuple) and len(pair.elts) == 2:
+                        self._pending.append(
+                            (ctx, imports, node, "incr", pair.elts[0]))
+                    else:
+                        findings.append(ctx.finding(
+                            self.rule, node,
+                            f"{method} takes literal (name, value) pairs — "
+                            "a computed argument hides its metric names "
+                            "from the registry check"))
+            elif method in NAME_METHODS:
+                if (method in GENERIC_METHODS
                         and not _metrics_ish_receiver(node.func)):
                     continue
-                self._pending.append((ctx, imports, node, node.func.attr))
-        return []
+                self._pending.append(
+                    (ctx, imports, node, method, node.args[0]))
+        return findings
 
     @staticmethod
     def _fallback_registry_path() -> str:
@@ -170,8 +191,8 @@ class MetricsRegistryChecker(Checker):
         values, prefixes = _registry_from_tree(self._registry_tree)
         constants = _registry_constants(self._registry_tree)
         findings: List[Finding] = []
-        for ctx, imports, call, method in self._pending:
-            problem = self._check_name_expr(call.args[0], method, values,
+        for ctx, imports, call, method, name in self._pending:
+            problem = self._check_name_expr(name, method, values,
                                             prefixes, constants, imports)
             if problem is not None:
                 findings.append(ctx.finding(self.rule, call, problem))
