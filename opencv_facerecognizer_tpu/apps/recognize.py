@@ -456,9 +456,9 @@ def _load_stack(args, mesh=None):
     one-chip and a four-chip host)."""
     import numpy as np
 
-    from opencv_facerecognizer_tpu.models.detector import CNNFaceDetector
     from opencv_facerecognizer_tpu.models.embedder import CNNEmbedding
     from opencv_facerecognizer_tpu.models.iresnet import IResNetEmbedding
+    from opencv_facerecognizer_tpu.models.scrfd import load_detector
     from opencv_facerecognizer_tpu.parallel import ShardedGallery, make_mesh
     from opencv_facerecognizer_tpu.parallel.pipeline import RecognitionPipeline
     from opencv_facerecognizer_tpu.utils import dataset as dataset_utils
@@ -479,7 +479,9 @@ def _load_stack(args, mesh=None):
     if not isinstance(feature, (CNNEmbedding, IResNetEmbedding)):
         raise SystemExit("--model must be a cnn checkpoint (ocvf-train "
                          "--model cnn) or an IResNetEmbedding one")
-    detector = CNNFaceDetector.load(args.detector)
+    # either detector class, by the checkpoint's header (a CNNFaceDetector
+    # file names no kind, an SCRFD one names its own)
+    detector = load_detector(args.detector)
     face_gate = None
     if args.cascade:
         from opencv_facerecognizer_tpu.models.cascade import FaceGate

@@ -41,6 +41,63 @@ class EmbedNet(Protocol):
     def apply(self, variables: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray: ...
 
 
+class Detector(Protocol):
+    """What the step needs of a detector, whatever its head: how many face
+    slots a frame gets, the parameters (a jit ARGUMENT of every step, so a
+    swap keeps the executables warm) and one function to trace,
+    ``(params, float32 frames [B, H, W]) -> (boxes [B, K, 4] pixel yxyx,
+    scores [B, K], valid [B, K])``. The function names its own scopes:
+    ``ocvf_detect`` round the net's forward and, where the decode is work
+    of its own, a SIBLING ``ocvf_decode`` (the trace reader files an
+    operation under the first ``ocvf_<stage>`` of its ``tf_op``).
+    ``models.scrfd.SCRFDDetector`` is one; ``as_detector`` makes one of a
+    ``CNNFaceDetector``."""
+
+    kind: str
+    max_faces: int
+
+    @property
+    def params(self) -> Any: ...
+
+    def detect_traced(self, params: Any, frames: jnp.ndarray
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]: ...
+
+
+class HeatmapDetector:
+    """``CNNFaceDetector`` behind the ``Detector`` boundary (the class
+    itself stays as the committed nets' recipe hashed it): the stride-8
+    centre-heatmap net and its decode, both under ``ocvf_detect`` as they
+    have been since the scope was named."""
+
+    kind = "heatmap"
+
+    def __init__(self, detector: detector_mod.CNNFaceDetector):
+        self.wrapped = detector
+
+    @property
+    def max_faces(self) -> int:
+        return self.wrapped.max_faces
+
+    @property
+    def params(self):
+        return self.wrapped.params
+
+    def detect_traced(self, params, frames):
+        det = self.wrapped
+        with jax.named_scope("ocvf_detect"):
+            outputs = det.net.apply({"params": params}, frames)
+            return detector_mod.decode_detections(
+                outputs, det.max_faces, det.score_threshold, det.iou_threshold)
+
+
+def as_detector(detector) -> Detector:
+    """``detector`` as the steps take it: a ``CNNFaceDetector`` wrapped,
+    anything that already traces its own detection as it is."""
+    if isinstance(detector, detector_mod.CNNFaceDetector):
+        return HeatmapDetector(detector)
+    return detector
+
+
 class RecognitionResult(NamedTuple):
     boxes: jnp.ndarray  # [B, K, 4] pixel yxyx
     det_scores: jnp.ndarray  # [B, K]
@@ -94,7 +151,7 @@ class RecognitionPipeline:
 
     def __init__(
         self,
-        detector: detector_mod.CNNFaceDetector,
+        detector: "Detector | detector_mod.CNNFaceDetector",
         embed_net: EmbedNet,
         embed_params: Dict[str, Any],
         gallery: ShardedGallery,
@@ -104,6 +161,9 @@ class RecognitionPipeline:
         cascade=None,
     ):
         self.detector = detector
+        #: the detector's kind, for the dispatch's provenance (the object is
+        #: never replaced: a registry swap loads parameters into it)
+        self._detector_kind = as_detector(detector).kind
         self.embed_net = embed_net
         self.gallery = gallery
         mesh = gallery.mesh
@@ -181,7 +241,7 @@ class RecognitionPipeline:
 
     def _build_step(self, batch: int, height: int, width: int,
                     capacity: Optional[int] = None, use_ivf: bool = False):
-        det = self.detector
+        det = as_detector(self.detector)
         k = self.top_k
         face_size = self.face_size
         embed_net = self.embed_net
@@ -201,12 +261,10 @@ class RecognitionPipeline:
             # them that way (4x fewer host->device bytes than f32); the cast
             # to f32 happens here, on device.
             frames = frames.astype(jnp.float32)
-            # 1) detect (dense convs; dp-sharded batch)
-            with jax.named_scope("ocvf_detect"):
-                outputs = det.net.apply({"params": det_params}, frames)
-                boxes, det_scores, valid = detector_mod.decode_detections(
-                    outputs, max_faces, det.score_threshold, det.iou_threshold
-                )
+            # 1) detect (dense convs; dp-sharded batch): the detector's own
+            # traced function, which names ``ocvf_detect`` (and, where its
+            # decode is work of its own, ``ocvf_decode``) itself
+            boxes, det_scores, valid = det.detect_traced(det_params, frames)
             # 2) align: dynamic crop+resize and per-crop standardization,
             # all slots (invalid ones too)
             with jax.named_scope("ocvf_crop"):
@@ -317,11 +375,15 @@ class RecognitionPipeline:
         # Host-side dispatch provenance for the frame-lifecycle tracer's
         # batch spans (runtime.recognizer reads it right after the call):
         # plain attr store, best-effort — informational, never synchronized.
-        # ``embed_slots``: face slots this step sends through the embedder
-        # (every frame of the rung carries max_faces, valid or not).
+        # ``detect_frames``: frames this step sends through the detector
+        # (the whole rung); ``embed_slots``: face slots it sends through the
+        # embedder (every frame of the rung carries max_faces, valid or
+        # not); ``detector``: the kind of detector the step traced.
         self.last_dispatch_info = {
             "cache_hit": packed is not None,
             "mode": "ivf" if ivf is not None else "exact",
+            "detector": self._detector_kind,
+            "detect_frames": int(frames.shape[0]),
             "embed_slots": int(frames.shape[0]) * int(self.detector.max_faces)}
         if packed is None:
             self._evict_stale_ivf(key)

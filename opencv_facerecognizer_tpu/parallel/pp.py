@@ -45,13 +45,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from opencv_facerecognizer_tpu.models import detector as detector_mod
 from opencv_facerecognizer_tpu.models import embedder as embedder_mod
 from opencv_facerecognizer_tpu.ops import image as image_ops
 from opencv_facerecognizer_tpu.parallel.gallery import ShardedGallery
 from opencv_facerecognizer_tpu.parallel.mesh import DP_AXIS, TP_AXIS
 from opencv_facerecognizer_tpu.parallel.pipeline import (
-    RecognitionResult, pack_result,
+    Detector, RecognitionResult, as_detector, pack_result,
 )
 
 
@@ -82,7 +81,7 @@ class TwoStagePipeline:
 
     def __init__(
         self,
-        detector: detector_mod.CNNFaceDetector,
+        detector: Detector,  # or a CNNFaceDetector: ``as_detector`` wraps it
         embed_net: embedder_mod.FaceEmbedNet,
         embed_params: Dict[str, Any],
         gallery: ShardedGallery,
@@ -110,15 +109,11 @@ class TwoStagePipeline:
         self.top_k = int(top_k)
         self.mesh_a = mesh_a
         self.mesh_b = mesh_b
-        det = detector
-        max_faces = det.max_faces
+        det = as_detector(detector)
 
         def stage_a(det_params, frames):
             frames = frames.astype(jnp.float32)  # uint8 fast-transfer path
-            outputs = det.net.apply({"params": det_params}, frames)
-            boxes, det_scores, valid = detector_mod.decode_detections(
-                outputs, max_faces, det.score_threshold, det.iou_threshold
-            )
+            boxes, det_scores, valid = det.detect_traced(det_params, frames)
             crops = image_ops.batched_crop_resize(frames, boxes, face_size)
             return boxes, det_scores, valid, crops
 

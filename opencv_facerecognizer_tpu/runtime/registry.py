@@ -129,7 +129,8 @@ class RegistryStateError(RuntimeError):
 def registry_params_path(state_dir: str, role: str, version: int) -> str:
     """The conventional durable location for a candidate's params blob:
     ``state_dir/registry/<role>-v<version>.params`` (msgpack for the real
-    models — ``FaceGate.save``/``CNNFaceDetector.save`` write here)."""
+    models — ``FaceGate.save``, ``CNNFaceDetector.save`` and ``SCRFDDetector.save``
+    write here)."""
     return os.path.join(str(state_dir), PARAMS_DIR,
                         f"{role}-v{int(version)}.params")
 

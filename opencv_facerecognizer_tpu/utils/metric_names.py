@@ -39,6 +39,9 @@ BATCHES_DEAD_LETTERED = "batches_dead_lettered"
 #: dispatched step (the pipeline reports it with the dispatch), so that a
 #: share of peak counts the work that ran, not what a rung implies.
 EMBED_SLOTS = "embed_slots"
+#: frames sent through the detector: the rung's frames of every dispatched
+#: step, real or padding (the pipeline reports it with the dispatch).
+DETECT_FRAMES = "detect_frames"
 LOOP_CRASHES = "loop_crashes"
 DISPATCH_FAILURES = "dispatch_failures"
 DISPATCH_RETRIES = "dispatch_retries"
