@@ -1,9 +1,14 @@
 """On-device non-maximum suppression with static shapes (SURVEY.md §7.6).
 
-XLA needs static shapes, so NMS is expressed as a fixed-size mask update:
-``nms_mask`` takes exactly K candidate boxes (padded upstream) and returns a
-boolean keep-mask — no dynamic output sizes anywhere, so the whole detector
-decode stays inside one jitted graph and batches under vmap.
+XLA needs static shapes, so NMS takes exactly K candidate boxes (padded
+upstream) and has no dynamic output size anywhere: the whole detector decode
+stays inside one jitted graph and batches under vmap. Two forms of the same
+greedy rule. ``nms_mask`` sweeps all K candidates and returns the boolean
+keep-mask (K loop steps; the plain form, and the tests' reference).
+``nms_fixed``, which the detectors' decodes call, returns the
+``max_outputs`` best kept boxes and costs ``max_outputs`` steps: the n-th box
+greedy NMS keeps is the best-scored candidate the n-1 before it left
+standing, so it selects that one n times and never decides the rest.
 
 Boxes are [y0, x0, y1, x1] in any consistent unit.
 """
@@ -41,8 +46,7 @@ def nms_mask(
 
     Candidates are visited in descending score order; a box is kept iff no
     already-kept, higher-scored box overlaps it above ``iou_threshold``.
-    O(K^2) IoU + a K-step ``fori_loop`` — fine for the K<=128 detector
-    budget, and fully jittable/vmappable.
+    O(K^2) IoU + a K-step ``fori_loop``, fully jittable/vmappable.
     """
     k = boxes.shape[0]
     order = jnp.argsort(-scores)
@@ -70,11 +74,38 @@ def nms_fixed(
     score_threshold: float = 0.0,
 ):
     """NMS returning exactly ``max_outputs`` (boxes, scores, valid-mask),
-    best first; unused slots are zero boxes with -inf score."""
-    keep = nms_mask(boxes, scores, iou_threshold, score_threshold)
-    masked_scores = jnp.where(keep, scores, -jnp.inf)
-    top_scores, top_idx = jax.lax.top_k(masked_scores, max_outputs)
-    top_boxes = jnp.take(boxes, top_idx, axis=0)
+    best first; unused slots are zero boxes with -inf score.
+
+    Selects instead of sweeping. Greedy NMS visits candidates best first and
+    keeps one iff no kept box overlaps it, so the n-th box it keeps is the
+    best-scored candidate that none of the n-1 kept before it suppressed
+    (lowest index on a tie, as the stable sort visits them). Each step takes
+    that candidate, writes it to the next slot and strikes every candidate
+    whose IoU with it exceeds ``iou_threshold`` (``pairwise_iou`` of all
+    candidates against the one box: the comparisons the [K, K] matrix would
+    have made). After ``max_outputs`` steps the outputs are what ``nms_mask``
+    followed by the ``max_outputs`` best of the kept gives, bit for bit; what
+    the sweep would decide about the other candidates is never returned, and
+    here never computed. Cost: one loop of min(``max_outputs``, K) steps of
+    O(K), its trip count static; no sort, no [K, K] matrix, no gather.
+    """
+    k = boxes.shape[0]
+    steps = min(int(max_outputs), k)
+    idx = jnp.arange(k)
+
+    def select(open_, _):
+        live = jnp.where(open_, scores, -jnp.inf)
+        taken = idx == jnp.argmax(live)  # the first index of the maximum: the tie order
+        # The one taken row, exactly: max(-inf, x) is x. With nothing open the
+        # row is arbitrary, its slot reads -inf and it strikes nothing open.
+        box = jnp.max(jnp.where(taken[:, None], boxes, -jnp.inf), axis=0)
+        overlaps = pairwise_iou(boxes, box[None, :])[:, 0] > iou_threshold
+        return open_ & ~overlaps & ~taken, (box, jnp.max(live))
+
+    _, (top_boxes, top_scores) = jax.lax.scan(select, scores > score_threshold, None, length=steps)
+    unused = max_outputs - steps
+    top_boxes = jnp.pad(top_boxes, ((0, unused), (0, 0)))
+    top_scores = jnp.pad(top_scores, (0, unused), constant_values=-jnp.inf)
     valid = jnp.isfinite(top_scores)
     return (
         jnp.where(valid[:, None], top_boxes, 0.0),
