@@ -456,8 +456,6 @@ def _load_stack(args, mesh=None):
     one-chip and a four-chip host)."""
     import numpy as np
 
-    from opencv_facerecognizer_tpu.models.embedder import CNNEmbedding
-    from opencv_facerecognizer_tpu.models.iresnet import IResNetEmbedding
     from opencv_facerecognizer_tpu.models.scrfd import load_detector
     from opencv_facerecognizer_tpu.parallel import ShardedGallery, make_mesh
     from opencv_facerecognizer_tpu.parallel.pipeline import RecognitionPipeline
@@ -476,9 +474,17 @@ def _load_stack(args, mesh=None):
 
     model = serialization.load_model(args.model)
     feature = model.feature
-    if not isinstance(feature, (CNNEmbedding, IResNetEmbedding)):
-        raise SystemExit("--model must be a cnn checkpoint (ocvf-train "
-                         "--model cnn) or an IResNetEmbedding one")
+    # what the step needs of a feature, whatever its class: a net with a
+    # flax-style ``apply`` (``parallel.pipeline.EmbedNet``), its parameters
+    # and the crop size it takes
+    if not (callable(getattr(getattr(feature, "net", None), "apply", None))
+            and "net" in (getattr(feature, "_params", None) or {})
+            and len(getattr(feature, "input_size", ())) == 2):
+        raise SystemExit(
+            f"--model must be an embedder checkpoint: a feature with a net "
+            f"to apply, its parameters and an input_size (ocvf-train --model "
+            f"cnn writes one); {args.model} holds a "
+            f"{getattr(feature, 'name', type(feature).__name__)!r} feature")
     # either detector class, by the checkpoint's header (a CNNFaceDetector
     # file names no kind, an SCRFD one names its own)
     detector = load_detector(args.detector)

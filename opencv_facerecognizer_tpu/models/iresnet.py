@@ -137,6 +137,10 @@ class IResNet(nn.Module):
     unit embeddings. The defaults are the published r50; tests use a small
     variant (any H, W divisible by 16)."""
 
+    #: the name of the feature class that owns this net: what the step's
+    #: dispatch reports as its embedder (``parallel.pipeline``)
+    feature_name = "iresnet_embedding"
+
     embed_dim: int = 512
     stem_features: int = 64
     stage_features: Sequence[int] = (64, 128, 256, 512)
@@ -246,44 +250,31 @@ def calibrate_batch_stats(net: IResNet, params: Dict[str, Any],
     return merge(params, sown["batch_stats"]), emb
 
 
-class IResNetEmbedding(AbstractFeature):
-    """An ``IResNet`` behind the ``AbstractFeature`` boundary, beside
-    ``CNNEmbedding``: same attributes the serving app reads (``net``,
-    ``input_size``, ``_params["net"]``), same checkpoint protocol.
+class SeededNetFeature(AbstractFeature):
+    """What the embedders that are not trained here share behind the
+    ``AbstractFeature`` boundary, beside ``CNNEmbedding``: the attributes
+    the serving app reads (``net``, ``input_size``, ``_params["net"]``)
+    and the checkpoint protocol. A subclass builds ``self.net`` (a flax
+    module with a ``calibrate`` field whose BatchNorms are ``_BatchNorm``),
+    then calls ``_bind``; it states ``get_config`` and how its parameters
+    are drawn (``_random_params``).
 
     ``compute(X, y)`` does not train: with no parameters loaded it draws
-    them from ``seed`` (``random_params``) and then, either way, fits the
-    BatchNorms' stored moments to ``X`` (``calibrate_batch_stats``);
-    ``extract`` embeds. Learned weights arrive through ``set_state``."""
+    them from ``seed`` and then, either way, fits the BatchNorms' stored
+    moments to ``X`` (``calibrate_batch_stats``); ``extract`` embeds.
+    Learned weights arrive through ``set_state``."""
 
-    name = "iresnet_embedding"
     sample_ndim = 2
 
-    def __init__(
-        self,
-        embed_dim: int = 512,
-        input_size: Tuple[int, int] = R50_FACE_SIZE,
-        stem_features: int = 64,
-        stage_features: Sequence[int] = (64, 128, 256, 512),
-        stage_blocks: Sequence[int] = (3, 4, 14, 3),
-        in_channels: int = 3,
-        eps: float = 1e-5,
-        seed: int = 0,
-    ):
-        self.embed_dim = int(embed_dim)
+    def _bind(self, net, input_size: Tuple[int, int], seed: int) -> None:
+        self.net = net
         self.input_size = tuple(int(v) for v in input_size)
-        self.stem_features = int(stem_features)
-        self.stage_features = tuple(int(v) for v in stage_features)
-        self.stage_blocks = tuple(int(v) for v in stage_blocks)
-        self.in_channels = int(in_channels)
-        self.eps = float(eps)
         self.seed = int(seed)
-        self.net = IResNet(
-            embed_dim=self.embed_dim, stem_features=self.stem_features,
-            stage_features=self.stage_features, stage_blocks=self.stage_blocks,
-            in_channels=self.in_channels, eps=self.eps)
         self._params: Optional[Dict[str, Any]] = None
         self._apply = jax.jit(lambda p, x: self.net.apply({"params": p}, x))
+
+    def _random_params(self) -> Dict[str, Any]:
+        raise NotImplementedError
 
     # -- feature protocol --
     def compute(self, X, y=None):
@@ -291,7 +282,7 @@ class IResNetEmbedding(AbstractFeature):
             X = np.stack([np.asarray(v) for v in X])
         X = jnp.asarray(X, jnp.float32)
         net_params = (self._params["net"] if self._params is not None else
-                      random_params(self.net, self.input_size, self.seed))
+                      self._random_params())
         net_params, emb = calibrate_batch_stats(
             self.net, net_params, normalize_faces(X, self.input_size))
         self._params = {"net": net_params}
@@ -299,24 +290,12 @@ class IResNetEmbedding(AbstractFeature):
 
     def _extract_batch(self, X):
         if self._params is None:
-            raise RuntimeError("IResNetEmbedding.extract called before "
+            raise RuntimeError(f"{type(self).__name__}.extract called before "
                                "compute() or set_state()")
         return self._apply(self._params["net"],
                            normalize_faces(X, self.input_size))
 
     # -- serialization protocol --
-    def get_config(self):
-        return {
-            "embed_dim": self.embed_dim,
-            "input_size": list(self.input_size),
-            "stem_features": self.stem_features,
-            "stage_features": list(self.stage_features),
-            "stage_blocks": list(self.stage_blocks),
-            "in_channels": self.in_channels,
-            "eps": self.eps,
-            "seed": self.seed,
-        }
-
     def get_state(self):
         if self._params is None:
             return {}
@@ -335,3 +314,47 @@ class IResNetEmbedding(AbstractFeature):
                 node = node.setdefault(part, {})
             node[last] = jnp.asarray(leaf)
         self._params = {"net": net}
+
+
+class IResNetEmbedding(SeededNetFeature):
+    """An ``IResNet`` behind the ``AbstractFeature`` boundary
+    (``SeededNetFeature``: seeded parameters, calibrated BatchNorms)."""
+
+    name = IResNet.feature_name
+
+    def __init__(
+        self,
+        embed_dim: int = 512,
+        input_size: Tuple[int, int] = R50_FACE_SIZE,
+        stem_features: int = 64,
+        stage_features: Sequence[int] = (64, 128, 256, 512),
+        stage_blocks: Sequence[int] = (3, 4, 14, 3),
+        in_channels: int = 3,
+        eps: float = 1e-5,
+        seed: int = 0,
+    ):
+        self.embed_dim = int(embed_dim)
+        self.stem_features = int(stem_features)
+        self.stage_features = tuple(int(v) for v in stage_features)
+        self.stage_blocks = tuple(int(v) for v in stage_blocks)
+        self.in_channels = int(in_channels)
+        self.eps = float(eps)
+        self._bind(IResNet(
+            embed_dim=self.embed_dim, stem_features=self.stem_features,
+            stage_features=self.stage_features, stage_blocks=self.stage_blocks,
+            in_channels=self.in_channels, eps=self.eps), input_size, seed)
+
+    def _random_params(self):
+        return random_params(self.net, self.input_size, self.seed)
+
+    def get_config(self):
+        return {
+            "embed_dim": self.embed_dim,
+            "input_size": list(self.input_size),
+            "stem_features": self.stem_features,
+            "stage_features": list(self.stage_features),
+            "stage_blocks": list(self.stage_blocks),
+            "in_channels": self.in_channels,
+            "eps": self.eps,
+            "seed": self.seed,
+        }

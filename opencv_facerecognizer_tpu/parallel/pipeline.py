@@ -36,7 +36,12 @@ from opencv_facerecognizer_tpu.parallel.mesh import DP_AXIS, TP_AXIS
 class EmbedNet(Protocol):
     """What the step needs of an embedder: a flax-style ``apply`` taking
     ``{"params": ...}`` and [N, h, w] standardized crops, giving [N, E]
-    unit rows (``FaceEmbedNet``, ``IResNet``)."""
+    unit rows (``FaceEmbedNet``, ``IResNet``, ``ViT``). A net may name
+    scopes of its own inside the step's ``ocvf_embed``, under names the
+    trace reader's ``ocvf_<stage>`` does not match (``vit_attn``). For the
+    dispatch's provenance a net may state ``feature_name`` (the name of the
+    feature class that owns it) and, where a crop becomes tokens,
+    ``tokens(face_size)``."""
 
     def apply(self, variables: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray: ...
 
@@ -165,6 +170,15 @@ class RecognitionPipeline:
         #: never replaced: a registry swap loads parameters into it)
         self._detector_kind = as_detector(detector).kind
         self.embed_net = embed_net
+        self.face_size = tuple(face_size)
+        #: the embedder's kind and the tokens it makes of a crop (0: the net
+        #: has no token axis), for the dispatch's provenance.
+        #: ``FaceEmbedNet``'s file is hashed into the committed nets' recipe
+        #: and names no feature class: a net that names none is its
+        self._embedder_kind = getattr(embed_net, "feature_name",
+                                      embedder_mod.CNNEmbedding.name)
+        self._tokens_per_slot = (int(embed_net.tokens(self.face_size))
+                                 if hasattr(embed_net, "tokens") else 0)
         self.gallery = gallery
         mesh = gallery.mesh
         #: where a batch of frames lives: dp-sharded, on every chip of a tp
@@ -179,7 +193,6 @@ class RecognitionPipeline:
         # made again when the tree is swapped (a registry install); the
         # objects that own the trees are left as they are.
         self._placed_trees: Dict[str, Tuple[Any, Any]] = {}
-        self.face_size = tuple(face_size)
         self.top_k = int(top_k)
         # Stage-1 detection cascade (models.cascade.FaceGate): when set,
         # the serving runtime scores every batch with ``cascade_scores``
@@ -378,13 +391,19 @@ class RecognitionPipeline:
         # ``detect_frames``: frames this step sends through the detector
         # (the whole rung); ``embed_slots``: face slots it sends through the
         # embedder (every frame of the rung carries max_faces, valid or
-        # not); ``detector``: the kind of detector the step traced.
+        # not); ``detector`` / ``embedder``: the kind of each the step
+        # traced; ``embed_tokens``: slots x the tokens a crop becomes, only
+        # for an embedder that has a token axis.
+        slots = int(frames.shape[0]) * int(self.detector.max_faces)
         self.last_dispatch_info = {
             "cache_hit": packed is not None,
             "mode": "ivf" if ivf is not None else "exact",
             "detector": self._detector_kind,
+            "embedder": self._embedder_kind,
             "detect_frames": int(frames.shape[0]),
-            "embed_slots": int(frames.shape[0]) * int(self.detector.max_faces)}
+            "embed_slots": slots}
+        if self._tokens_per_slot:
+            self.last_dispatch_info["embed_tokens"] = slots * self._tokens_per_slot
         if packed is None:
             self._evict_stale_ivf(key)
             step = self._step_cache.get(key)

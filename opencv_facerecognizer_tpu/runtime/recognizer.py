@@ -2125,9 +2125,11 @@ class RecognizerService:
         # watchdog, so it is fetched regardless of tracing.
         info = getattr(self.pipeline, "last_dispatch_info", None) or {}
         if info.get("embed_slots"):
-            # the step's two counts of work under one acquisition of the lock
+            # the step's counts of work under one acquisition of the lock
+            # (tokens stay 0 where the embedder has no token axis)
             self.metrics.incr_many((mn.EMBED_SLOTS, info["embed_slots"]),
-                                   (mn.DETECT_FRAMES, info.get("detect_frames", 0)))
+                                   (mn.DETECT_FRAMES, info.get("detect_frames", 0)),
+                                   (mn.EMBED_TOKENS, info.get("embed_tokens", 0)))
         if batch_tid:
             # Bucketed-dispatch provenance: bucket size, jit-cache verdict
             # and exact-vs-ivf matcher mode (the pipeline records both on
@@ -2140,6 +2142,7 @@ class RecognizerService:
                         cache_hit=info.get("cache_hit"),
                         mode=info.get("mode"), exit="full",
                         detector=info.get("detector"),
+                        embedder=info.get("embedder"),
                         brownout=self._brownout_level)
         # What follows lies after ``dispatch``: roots of the batch trace.
         self._dispatch_span = 0

@@ -42,6 +42,10 @@ EMBED_SLOTS = "embed_slots"
 #: frames sent through the detector: the rung's frames of every dispatched
 #: step, real or padding (the pipeline reports it with the dispatch).
 DETECT_FRAMES = "detect_frames"
+#: tokens sent through an embedder that has a token axis: ``embed_slots`` x
+#: the tokens a crop becomes (144 for the ViT at 112x112, patch 9); stays 0
+#: for the convolutional embedders.
+EMBED_TOKENS = "embed_tokens"
 LOOP_CRASHES = "loop_crashes"
 DISPATCH_FAILURES = "dispatch_failures"
 DISPATCH_RETRIES = "dispatch_retries"

@@ -164,9 +164,11 @@ def _registry() -> Dict[str, type]:
         # trainer or the serving app (round-3 drive finding).
         from opencv_facerecognizer_tpu.models import embedder as e
         from opencv_facerecognizer_tpu.models import iresnet as r
+        from opencv_facerecognizer_tpu.models import vit as v
 
         _REGISTRY[e.CNNEmbedding.name] = e.CNNEmbedding
         _REGISTRY[r.IResNetEmbedding.name] = r.IResNetEmbedding
+        _REGISTRY[v.ViTEmbedding.name] = v.ViTEmbedding
     return _REGISTRY
 
 

@@ -210,7 +210,7 @@ def test_a_checkpoint_of_neither_class_is_refused(artifacts, tmp_path):
     serialization.save_model(path, PredictableModel(Identity(), NearestNeighbor()))
     args = _args(artifacts, "embedder.ckpt")
     args.model = path
-    with pytest.raises(SystemExit, match="IResNetEmbedding"):
+    with pytest.raises(SystemExit, match="embedder checkpoint"):
         recognize_app._load_stack(args)
 
 

@@ -355,3 +355,27 @@ def test_iresnet_r50_compiles_for_v5e_at_the_top_rung(one_chip):
         params, crops).compile().memory_analysis()
     assert memory.output_size_in_bytes == 1024 * 512 * 4
     assert memory.temp_size_in_bytes < 4 * 2**30, memory.temp_size_in_bytes
+
+
+def test_vit_b_compiles_for_v5e_at_the_top_rung(one_chip):
+    """The published ViT-B over one top-rung step's 1,024 crops (147,456
+    tokens through 24 unrolled blocks in one call) fits the chip beside a
+    4.3 GB gallery and its own 455 MB of float32 parameters: 1.89 GiB of
+    temporaries when this was written (the f32 scores of a block are 0.63
+    GiB, its MLP's hidden layer 0.56 GiB in bf16)."""
+    import jax
+
+    from opencv_facerecognizer_tpu.models import vit
+
+    net = vit.ViT()
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, *vit.VIT_B_FACE_SIZE)))["params"]
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
+    crops = jax.ShapeDtypeStruct((1024, *vit.VIT_B_FACE_SIZE), jnp.float32,
+                                 sharding=one_chip)
+    memory = jax.jit(lambda p, x: net.apply({"params": p}, x)).lower(
+        params, crops).compile().memory_analysis()
+    assert memory.output_size_in_bytes == 1024 * 512 * 4
+    assert memory.argument_size_in_bytes > 113_832_960 * 4
+    assert memory.temp_size_in_bytes < 3 * 2**30, memory.temp_size_in_bytes
