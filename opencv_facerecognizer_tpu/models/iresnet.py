@@ -271,7 +271,10 @@ class SeededNetFeature(AbstractFeature):
         self.input_size = tuple(int(v) for v in input_size)
         self.seed = int(seed)
         self._params: Optional[Dict[str, Any]] = None
-        self._apply = jax.jit(lambda p, x: self.net.apply({"params": p}, x))
+        # closes over the net, not over self: a jitted function that held the
+        # feature would make a cycle of it, and its parameters (455 MB for
+        # ViT-B) would stay on the device until the cycle collector ran
+        self._apply = jax.jit(lambda p, x: net.apply({"params": p}, x))
 
     def _random_params(self) -> Dict[str, Any]:
         raise NotImplementedError

@@ -35,12 +35,25 @@ Design, TPU-first, and what differs from the published code:
   the sum rounded once. The head's 512 outputs, its last BatchNorm and the
   L2 norm stay f32. (The published module leaves autocast for q k^T,
   softmax and A v: float32 operands there.)
-- What the chip's time asked for (PERF.md, PR 46): q, k and v are written
-  head-major by three matmuls over the one stored qkv kernel (``_QKV``),
-  1 / sqrt(head) folded into q's columns (exact for the published head of
-  64); the softmax's division is put off to A v's [T, head] output
-  (exp(s - max) rounded to bf16 as A v's operand, summed in f32), which
-  changes A's rounding and nothing else.
+- What the chip's time asked for (PERF.md, PRs 46 and 47): 1 / sqrt(head)
+  is folded into q's columns of the stored qkv kernel (exact for the
+  published head of 64); the softmax's division is put off to A v's
+  [T, head] output (exp(s - max) rounded to bf16 as A v's operand, summed in
+  f32), which changes A's rounding and nothing else. From the block's
+  normalised input to ``proj``'s input the attention is
+  ``ops.vit_attention.attend``: one set of equations with two lowerings,
+  chosen from what the computation is LOWERED for and the shapes alone (no
+  flag, no variable). Lowered for one TPU chip, where ``head`` divides 128,
+  the width is a multiple of 128, the crops a multiple of 8 (the serving
+  rungs' 64 / 256 / 1,024 slots, enrolment's 32) and a crop at most 256
+  tokens, ONE Pallas kernel a block cuts q, k and v out of the one
+  [N, T, 3 d] qkv result and never writes a score to HBM (1.17 ms a block
+  at 1,024 faces); everywhere else (the CPU, ViT-L's head of 96, a ragged
+  N, a longer sequence, a ``jit`` that XLA partitions over several chips,
+  under ``vmap`` or ``grad``) XLA's form: q, k and v written head-major by
+  three matmuls over the one stored kernel and the [N, heads, T, T] float32
+  scores in memory (8.4 ms a block on the chip). The text lowered for a CPU
+  is XLA's form letter for letter.
 - The blocks are UNROLLED, each with parameters of its own (``block0`` ..):
   a checkpoint holds exactly the published parameters block by block, XLA
   fuses across a block's boundary, and the profiler names every block.
@@ -53,7 +66,8 @@ Design, TPU-first, and what differs from the published code:
   kernel is (C, kh, kw): a permutation of that kernel's rows. The flatten
   before the head is token-major as published.
 - ``jax.named_scope``s for the trace, inside whatever scope the caller
-  opens: ``vit_attn`` round q k^T, softmax and A v only, ``vit_mlp`` round
+  opens: ``vit_attn`` round q k^T, softmax and A v only (in either form of
+  the attention: not the qkv matmul, not ``proj``), ``vit_mlp`` round
   fc1 .. fc2, ``vit_head`` round the flatten and the head.
 - A [N, H, W] grayscale batch (what the serving step crops) is replicated
   onto the patch embedding's ``in_channels`` planes.
@@ -69,6 +83,7 @@ from flax import linen as nn
 
 from opencv_facerecognizer_tpu.models.iresnet import (
     SeededNetFeature, _BatchNorm, parameter_count)
+from opencv_facerecognizer_tpu.ops import vit_attention
 
 #: the published vit_b's input
 VIT_B_FACE_SIZE = (112, 112)
@@ -104,30 +119,24 @@ class _Linear(nn.Module):
 class _QKV(nn.Module):
     """The published ``qkv = Linear(d -> 3 d, no bias)`` as it is stored
     (one ``kernel`` of [d, 3 d], columns ordered (q | k | v) x heads x
-    head), applied as three matmuls that write q, k and v as [N, heads, T,
-    head] each: the layout the two attention matmuls batch over, so nothing
-    is transposed between them (one matmul and a split moved the [N, T, 3 d]
-    result through memory twice: 534 against 485 ms for 24 blocks on the
-    chip, PERF.md). 1 / sqrt(head) multiplies q's columns of the kernel: for
-    the published head of 64 a power of two, so q k^T is bit for bit that
-    of scaling the scores."""
+    head), handed to ``ops.vit_attention.attend`` as [d, 3, heads, head] in
+    the operands' dtype: each form of the attention applies it in the
+    layout its matmuls batch over (XLA's as three matmuls that write q, k
+    and v head-major, so nothing is transposed between them; the kernel's as
+    the one matmul whose [N, T, 3 d] result it cuts by lanes). 1 / sqrt(head)
+    multiplies q's columns of the kernel: for the published head of 64 a
+    power of two, so q k^T is bit for bit that of scaling the scores."""
 
     heads: int
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
-        width = x.shape[-1]
+    def __call__(self, width: int):
         head = width // self.heads
         kernel = self.param("kernel", _INIT, (width, 3 * width), jnp.float32)
         kernel = kernel.reshape(width, 3, self.heads, head)
         scale = jnp.asarray([head ** -0.5, 1.0, 1.0], jnp.float32)
-        kernel = (kernel * scale[None, :, None, None]).astype(self.dtype)
-        x = x.astype(self.dtype)
-        return tuple(
-            jnp.einsum("ntc,chd->nhtd", x, kernel[:, part],
-                       preferred_element_type=jnp.float32).astype(self.dtype)
-            for part in range(3))
+        return (kernel * scale[None, :, None, None]).astype(self.dtype)
 
 
 def _layer_norm(eps: float, name: str) -> nn.LayerNorm:
@@ -145,26 +154,18 @@ class _Block(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        n, t, width = x.shape
+        width = x.shape[-1]
 
         def linear(features, name):
             return _Linear(features, dtype=self.dtype, name=name)
 
         y = _layer_norm(self.eps, "norm1")(x)
-        q, k, v = _QKV(self.heads, self.dtype, name="qkv")(y)
-        with jax.named_scope("vit_attn"):
-            scores = jnp.einsum("nhqd,nhkd->nhqk", q, k,
-                                preferred_element_type=jnp.float32)
-            # softmax in f32, its division put off to the [T, head] output:
-            # exp(s - max) is rounded to the operands' precision, summed in
-            # f32, and A v is divided by the sum (a 1 in every row of A v's
-            # operand: the largest weight is exact)
-            weights = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
-            total = jnp.sum(weights, axis=-1, keepdims=True)
-            y = jnp.einsum("nhqk,nhkd->nhqd", weights.astype(self.dtype), v,
-                           preferred_element_type=jnp.float32) / total
-        y = y.transpose(0, 2, 1, 3)
-        y = linear(width, "proj")(y.reshape(n, t, width))
+        kernel = _QKV(self.heads, self.dtype, name="qkv")(width)
+        # qkv, then q k^T, softmax and A v under the scope ``vit_attn``: one
+        # Pallas kernel where the lowering targets a TPU and the shapes fit
+        # it, XLA's batched matmuls elsewhere
+        y = vit_attention.attend(y.astype(self.dtype), kernel)
+        y = linear(width, "proj")(y)
         x = (x.astype(jnp.float32) + y).astype(self.dtype)
 
         y = _layer_norm(self.eps, "norm2")(x)
@@ -182,6 +183,9 @@ class ViT(nn.Module):
     #: the name of the feature class that owns this net: what the step's
     #: dispatch reports as its embedder (``parallel.pipeline``)
     feature_name = "vit_embedding"
+    #: the custom call every block's attention lowers to where the kernel is
+    #: on the path: the dispatch looks for it in the step's lowered text
+    attention_kernel = vit_attention.NAME
 
     embed_dim: int = 512
     depth: int = 24

@@ -40,8 +40,9 @@ class EmbedNet(Protocol):
     scopes of its own inside the step's ``ocvf_embed``, under names the
     trace reader's ``ocvf_<stage>`` does not match (``vit_attn``). For the
     dispatch's provenance a net may state ``feature_name`` (the name of the
-    feature class that owns it) and, where a crop becomes tokens,
-    ``tokens(face_size)``."""
+    feature class that owns it), where a crop becomes tokens
+    ``tokens(face_size)`` and, where its attention has a kernel that only
+    some lowerings reach, ``attention_kernel``: the custom call's name."""
 
     def apply(self, variables: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray: ...
 
@@ -179,6 +180,11 @@ class RecognitionPipeline:
                                       embedder_mod.CNNEmbedding.name)
         self._tokens_per_slot = (int(embed_net.tokens(self.face_size))
                                  if hasattr(embed_net, "tokens") else 0)
+        #: the custom call a net's attention lowers to where its kernel is on
+        #: the path (None: the net states none), and face slots of a step ->
+        #: whether the text the step lowered to holds it
+        self._attention_kernel = getattr(embed_net, "attention_kernel", None)
+        self._attention_forms: Dict[int, str] = {}
         self.gallery = gallery
         mesh = gallery.mesh
         #: where a batch of frames lives: dp-sharded, on every chip of a tp
@@ -393,7 +399,9 @@ class RecognitionPipeline:
         # embedder (every frame of the rung carries max_faces, valid or
         # not); ``detector`` / ``embedder``: the kind of each the step
         # traced; ``embed_tokens``: slots x the tokens a crop becomes, only
-        # for an embedder that has a token axis.
+        # for an embedder that has a token axis; ``embed_attention``: the
+        # form its attention lowered to in this step (``"kernel"`` or
+        # ``"xla"``), only for an embedder that states a kernel.
         slots = int(frames.shape[0]) * int(self.detector.max_faces)
         self.last_dispatch_info = {
             "cache_hit": packed is not None,
@@ -419,14 +427,20 @@ class RecognitionPipeline:
             packed = self._packed_cache[key] = jax.jit(  # ocvf-lint: boundary=jit-recompile-hazard -- packed-cache fill: warmup compiles every dispatch bucket, so serving only lands here on a genuinely new (shape, capacity, matcher) key
                 packed_step, out_shardings=self.frames_sharding,
                 donate_argnums=(5,) if self.donate_frames else ())
-        return packed(
-            *self._net_params(),
-            data.embeddings,
-            data.valid,
-            data.labels,
-            frames,
-            ivf if ivf is not None else (),
-        )
+        args = (*self._net_params(), data.embeddings, data.valid, data.labels,
+                frames, ivf if ivf is not None else ())
+        if self._attention_kernel:
+            form = self._attention_forms.get(slots)
+            if form is None:
+                # once a number of slots, observed and not inferred from the
+                # platform: whether the text this step lowers to for its
+                # devices holds the kernel's custom call (the trace is the
+                # one the call below uses)
+                text = packed.lower(*args).as_text()
+                form = self._attention_forms[slots] = (
+                    "kernel" if self._attention_kernel in text else "xla")
+            self.last_dispatch_info["embed_attention"] = form
+        return packed(*args)
 
     def lower_packed(self, batch: int, height: int, width: int, dtype):
         """``jax.stages.Lowered`` of the packed serving step cached for

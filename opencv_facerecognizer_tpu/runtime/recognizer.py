@@ -2126,10 +2126,14 @@ class RecognizerService:
         info = getattr(self.pipeline, "last_dispatch_info", None) or {}
         if info.get("embed_slots"):
             # the step's counts of work under one acquisition of the lock
-            # (tokens stay 0 where the embedder has no token axis)
-            self.metrics.incr_many((mn.EMBED_SLOTS, info["embed_slots"]),
-                                   (mn.DETECT_FRAMES, info.get("detect_frames", 0)),
-                                   (mn.EMBED_TOKENS, info.get("embed_tokens", 0)))
+            # (tokens stay 0 where the embedder has no token axis, the
+            # kernel's slots where its attention did not lower to the kernel)
+            self.metrics.incr_many(
+                (mn.EMBED_SLOTS, info["embed_slots"]),
+                (mn.DETECT_FRAMES, info.get("detect_frames", 0)),
+                (mn.EMBED_TOKENS, info.get("embed_tokens", 0)),
+                (mn.EMBED_ATTN_KERNEL_SLOTS,
+                 info["embed_slots"] if info.get("embed_attention") == "kernel" else 0))
         if batch_tid:
             # Bucketed-dispatch provenance: bucket size, jit-cache verdict
             # and exact-vs-ivf matcher mode (the pipeline records both on
@@ -2143,6 +2147,7 @@ class RecognizerService:
                         mode=info.get("mode"), exit="full",
                         detector=info.get("detector"),
                         embedder=info.get("embedder"),
+                        attention=info.get("embed_attention"),
                         brownout=self._brownout_level)
         # What follows lies after ``dispatch``: roots of the batch trace.
         self._dispatch_span = 0

@@ -46,6 +46,10 @@ DETECT_FRAMES = "detect_frames"
 #: the tokens a crop becomes (144 for the ViT at 112x112, patch 9); stays 0
 #: for the convolutional embedders.
 EMBED_TOKENS = "embed_tokens"
+#: face slots sent through an embedder whose attention lowered to the Pallas
+#: kernel (``ops.vit_attention``): equals ``embed_slots`` where every step
+#: did (the ViT on a TPU), stays 0 everywhere else.
+EMBED_ATTN_KERNEL_SLOTS = "embed_attn_kernel_slots"
 LOOP_CRASHES = "loop_crashes"
 DISPATCH_FAILURES = "dispatch_failures"
 DISPATCH_RETRIES = "dispatch_retries"

@@ -1,8 +1,10 @@
 """Streaming pallas top-k matcher vs the lax.top_k oracle.
 
 Runs in interpret mode on the CPU suite (SURVEY.md §4 prescription: every
-kernel gets an oracle test); the last test compiles the kernel for the chip
-without one, and ``chip_smoke.py`` runs it compiled on the chip.
+kernel gets an oracle test); the last tests compile the kernels for the chip
+without one (the attention kernel of ``ops.vit_attention`` among them: one
+file may describe the topology), and ``chip_smoke.py`` runs the matcher
+compiled on the chip.
 """
 
 import jax.numpy as jnp
@@ -296,14 +298,19 @@ def test_agrees_with_match_global(k):
 # ---- the chip's compiler, without the chip (on-chip-measurement guide §2) ----
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.mark.parametrize("qn,n,k,d", [
@@ -357,15 +364,22 @@ def test_iresnet_r50_compiles_for_v5e_at_the_top_rung(one_chip):
     assert memory.temp_size_in_bytes < 4 * 2**30, memory.temp_size_in_bytes
 
 
-def test_vit_b_compiles_for_v5e_at_the_top_rung(one_chip):
+def test_vit_b_compiles_for_v5e_at_the_top_rung_with_the_attention_kernel(one_chip):
     """The published ViT-B over one top-rung step's 1,024 crops (147,456
-    tokens through 24 unrolled blocks in one call) fits the chip beside a
-    4.3 GB gallery and its own 455 MB of float32 parameters: 1.89 GiB of
-    temporaries when this was written (the f32 scores of a block are 0.63
-    GiB, its MLP's hidden layer 0.56 GiB in bf16)."""
+    tokens through 24 unrolled blocks in one call), lowered for the v5e from
+    this CPU host, reaches ``ops.vit_attention``'s kernel: the choice follows
+    the lowering's platform, not ``jax.default_backend()``. One custom call a
+    block whose name holds ``vit_attention`` under the scope ``vit_attn``
+    (what the profiler's operation, and with it ``attn_device_ms``, is found
+    by), no [N, heads, T, T] scores left in memory, and the net fits the chip
+    beside a 4.3 GB gallery and its own 455 MB of float32 parameters: 0.73
+    GiB of temporaries when this was written, 1.89 with XLA's attention (the
+    f32 scores of a block were 0.63 GiB; the MLP's hidden layer is 0.56 GiB
+    in bf16)."""
     import jax
 
     from opencv_facerecognizer_tpu.models import vit
+    from opencv_facerecognizer_tpu.ops import vit_attention
 
     net = vit.ViT()
     params = jax.eval_shape(net.init, jax.random.PRNGKey(0),
@@ -374,8 +388,118 @@ def test_vit_b_compiles_for_v5e_at_the_top_rung(one_chip):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
     crops = jax.ShapeDtypeStruct((1024, *vit.VIT_B_FACE_SIZE), jnp.float32,
                                  sharding=one_chip)
-    memory = jax.jit(lambda p, x: net.apply({"params": p}, x)).lower(
-        params, crops).compile().memory_analysis()
+    lowered = jax.jit(lambda p, x: net.apply({"params": p}, x)).lower(params, crops)
+    # what ``parallel.pipeline`` reads the step's form from: the kernel's name
+    # once a block in the text lowered for the chip
+    assert lowered.as_text().count(vit_attention.NAME) == net.depth
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == net.depth == 24
+    for line in calls:
+        assert line.lstrip().startswith("%vit_attention"), line[:80]
+        assert "bf16[1024,144,512]" in line.split(" custom-call(")[0], line[:120]
+        assert "/vit_attn/vit_attention" in line
+    assert "f32[1024,8,144,144]" not in text
+    memory = compiled.memory_analysis()
     assert memory.output_size_in_bytes == 1024 * 512 * 4
     assert memory.argument_size_in_bytes > 113_832_960 * 4
     assert memory.temp_size_in_bytes < 3 * 2**30, memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes < 1.89 * 2**30, memory.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("n", [64, 256, 1024])
+def test_the_attention_kernel_compiles_for_v5e_inside_the_default_vmem_limit(one_chip, n):
+    """Mosaic takes the kernel alone at the slots of every serving rung (8
+    face slots a frame: 64, 256, 1,024 sequences of 144 tokens, 8 heads of
+    64) with no VMEM limit asked for, as one custom call that reads the one
+    qkv array three times and holds nothing in HBM beside its output."""
+    import jax
+
+    from opencv_facerecognizer_tpu.ops import vit_attention
+
+    qkv = jax.ShapeDtypeStruct((n, 144, 3 * 512), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda a: vit_attention.attention(a, 8)).lower(qkv).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "vit_attention" in calls[0], calls
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("side,tokens,kernel", [(144, 256, True), (180, 400, False)])
+def test_a_longer_sequence_takes_the_kernel_up_to_its_bound_and_xla_s_form_beyond(
+        one_chip, side, tokens, kernel):
+    """``ViTEmbedding(input_size, patch)`` is configurable and a grid step
+    holds 8 x T x T float32 scores: at patch 9 a crop of 144 x 144 is 256
+    tokens, the longest the predicate offers the kernel (Mosaic takes it
+    inside the default VMEM limit); 180 x 180 is 400, which Mosaic refuses
+    (21.8 MiB of 16), so the predicate leaves it to XLA's form and the net
+    compiles with no custom call, as it did before there was a kernel."""
+    import jax
+
+    from opencv_facerecognizer_tpu.models import vit
+    from opencv_facerecognizer_tpu.ops import vit_attention
+
+    net = vit.ViT(depth=1)
+    assert net.tokens((side, side)) == tokens
+    assert vit_attention.fits(8, tokens, net.embed_dim, net.heads) == kernel
+    params = jax.eval_shape(net.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, side, side)))["params"]
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), params)
+    crops = jax.ShapeDtypeStruct((8, side, side), jnp.float32, sharding=one_chip)
+    lowered = jax.jit(lambda p, x: net.apply({"params": p}, x)).lower(params, crops)
+    assert (vit_attention.NAME in lowered.as_text()) == kernel
+    calls = [line for line in lowered.compile().as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == (1 if kernel else 0)
+
+
+@pytest.mark.parametrize("how", ["jit_over_four_chips", "shard_map_over_four_chips"])
+def test_on_a_mesh_the_kernel_runs_where_the_lowering_is_for_one_chip_s_share(four_chips, how):
+    """XLA cannot partition a Mosaic kernel (jax refuses to lower one under a
+    ``jit`` over several chips): there the TPU's rule gives XLA's form, which
+    XLA partitions over dp as it did before there was a kernel, and the
+    kernel's name is nowhere in the lowered text (so the dispatch's
+    provenance says ``xla``); inside a ``shard_map`` over the whole mesh each
+    chip's 16 sequences go through the kernel."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from opencv_facerecognizer_tpu.ops import vit_attention
+
+    mesh = Mesh(np.array(four_chips).reshape(4, 1), ("dp", "tp"))
+    rows, whole = NamedSharding(mesh, P("dp")), NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((64, 144, 512), jnp.bfloat16, sharding=rows)
+    kernel = jax.ShapeDtypeStruct((512, 3, 8, 64), jnp.bfloat16, sharding=whole)
+    attend = vit_attention.attend
+    if how == "shard_map_over_four_chips":
+        attend = jax.shard_map(attend, mesh=mesh, in_specs=(P("dp"), P()),
+                               out_specs=P("dp"), check_vma=False)
+    lowered = jax.jit(attend, out_shardings=rows).lower(x, kernel)
+    kernel_on_path = how == "shard_map_over_four_chips"
+    assert (vit_attention.NAME in lowered.as_text()) == kernel_on_path
+    calls = [line for line in lowered.compile().as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == (1 if kernel_on_path else 0)
+    if kernel_on_path:
+        assert "bf16[16,144,512]" in calls[0].split(" custom-call(")[0]
+
+
+def test_the_gradient_through_the_attention_compiles_for_v5e(one_chip):
+    """``jax.grad`` over ``attend`` at the published shape, lowered for the
+    chip: XLA's form and its cotangents, no custom call (the kernel is the
+    plain forward pass's; a Pallas call has no transpose)."""
+    import jax
+
+    from opencv_facerecognizer_tpu.ops import vit_attention
+
+    x = jax.ShapeDtypeStruct((8, 144, 512), jnp.bfloat16, sharding=one_chip)
+    kernel = jax.ShapeDtypeStruct((512, 3, 8, 64), jnp.bfloat16, sharding=one_chip)
+
+    def loss(a, k):
+        return jnp.sum(vit_attention.attend(a, k).astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, kernel).compile()
+    assert "tpu_custom_call" not in compiled.as_text()

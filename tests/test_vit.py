@@ -497,3 +497,157 @@ def test_dispatch_names_the_embedder_and_embed_tokens_counts_slots_times_tokens(
     spans = [s for s in tracer.snapshot(BATCH_TOPIC) if s["stage"] == "dispatch"]
     assert {s.get("embedder") for s in spans} == {"vit_embedding"}
     assert {s.get("detector") for s in spans} == {"heatmap"}
+
+
+# ---- the attention's two forms (``ops.vit_attention``) ----------------------
+
+#: rows of three seeded faces through the parent's net (commit 338fec9, the
+#: attention still in ``_Block``), read on the CPU when the fixture was written
+PARENT_ROWS = np.array([
+    [0.200822, 0.081612, 0.697326, 0.339529, 0.004741, -0.554701, -0.197516, -0.068833],
+    [-0.23742, 0.073575, 0.090533, -0.663633, 0.127126, 0.405256, 0.18274, 0.525191],
+    [-0.341497, -0.194927, -0.641598, -0.261807, 0.059307, 0.35726, 0.121907, 0.468166]],
+    np.float32)
+
+
+def test_a_checkpoint_the_parent_wrote_loads_and_gives_the_parent_s_rows():
+    """``tests/fixtures/vit_written_by_338fec9.ckpt``: a ViT of width 32, 2
+    blocks of 2 heads on 18x18 crops, seeded, calibrated and saved by the
+    parent's code. The parameter tree is unchanged (the stored qkv kernel is
+    one [d, 3 d] array under ``block<i>/qkv/kernel``), so it loads into this
+    net leaf for leaf and embeds as it did."""
+    path = os.path.join(REPO, "tests", "fixtures", "vit_written_by_338fec9.ckpt")
+    feature = serialization.load_model(path).feature
+    assert isinstance(feature, vit.ViTEmbedding) and feature.embed_dim == 32
+    fresh = jax.eval_shape(feature.net.init, jax.random.PRNGKey(0),
+                           jnp.zeros((1, 18, 18)))["params"]
+    shapes = lambda tree: {jax.tree_util.keystr(p): (v.shape, str(v.dtype)) for p, v
+                           in jax.tree_util.tree_flatten_with_path(tree)[0]}
+    assert shapes(feature._params["net"]) == shapes(fresh)
+    assert shapes(fresh)["['block1']['qkv']['kernel']"] == ((32, 96), "float32")
+    faces = np.random.default_rng(47).uniform(0, 255, (6, 18, 18)).astype(np.float32)
+    rows = np.asarray(feature.extract(faces[:3]))
+    # the same lowered text on the CPU; another machine's CPU may round one
+    # bf16 activation the other way
+    np.testing.assert_allclose(rows, PARENT_ROWS, atol=2e-3)
+
+
+def test_the_dispatch_names_the_attention_s_form_for_this_net_only(artifacts):
+    """Read from the text the step lowers to for its devices, once a number
+    of slots: on the CPU XLA's form (the kernel's name stands nowhere in the
+    text; ``tests/test_pallas_match.py`` finds it 24 times in the text lowered
+    for a v5e); the key is absent for an embedder that states no kernel,
+    whose step is lowered once as before."""
+    pipeline, _ = _pipeline(artifacts)
+    assert pipeline._attention_kernel == "vit_attention"
+    frames = artifacts["scenes"][:4].astype(np.uint8)
+    pipeline.recognize_batch_packed(frames)
+    assert pipeline.last_dispatch_info["embed_attention"] == "xla"
+    slots = pipeline.last_dispatch_info["embed_slots"]
+    assert pipeline._attention_forms == {slots: "xla"}
+    pipeline._attention_forms[slots] = "kernel"   # read once: not lowered again
+    pipeline.recognize_batch_packed(frames)
+    assert pipeline.last_dispatch_info["embed_attention"] == "kernel"
+    from scripts.chaos_soak import build_stack
+
+    other, _mesh = build_stack(frame_shape=(32, 32), face=(16, 16))
+    other.recognize_batch_packed(np.zeros((4, 32, 32), np.uint8))
+    assert "embed_attention" not in other.last_dispatch_info
+    assert other._attention_forms == {}
+
+
+@pytest.mark.parametrize("transform", ["grad", "vmap"])
+def test_grad_and_vmap_over_the_net_at_a_shape_the_kernel_takes(transform):
+    """8 crops through a net of width 128 in heads of 64: every block's
+    attention is the primitive that chooses between the two forms, and
+    ``jax.grad`` and ``jax.vmap`` over ``ViT.apply`` trace through it as they
+    did when XLA's form was the only one."""
+    from opencv_facerecognizer_tpu.ops import vit_attention
+
+    net = vit.ViT(embed_dim=128, depth=2, heads=2, patch=9, out_dim=8)
+    crops = jnp.asarray(np.random.default_rng(3).normal(size=(8, 18, 18)), jnp.float32)
+    assert vit_attention.fits(8, net.tokens((18, 18)), 128, 2)
+    params = net.init(jax.random.PRNGKey(0), crops)["params"]
+    assert "vit_attend" in str(jax.make_jaxpr(lambda x: net.apply({"params": params}, x))(crops))
+    if transform == "grad":
+        grads = jax.grad(lambda p: jnp.sum(net.apply({"params": p}, crops)[:, 0]))(params)
+        norm = float(jnp.linalg.norm(grads["block0"]["qkv"]["kernel"]))
+        assert np.isfinite(norm) and norm > 0
+    else:
+        rows = jax.vmap(lambda x: net.apply({"params": params}, x))(jnp.stack([crops, -crops]))
+        np.testing.assert_allclose(np.asarray(rows[0]),
+                                   np.asarray(net.apply({"params": params}, crops)), atol=1e-5)
+
+
+@pytest.mark.parametrize("form,counted", [("kernel", True), ("xla", False), (None, False)])
+def test_embed_attn_kernel_slots_counts_the_slots_of_steps_whose_attention_was_the_kernel(
+        form, counted):
+    """``embed_attn_kernel_slots`` beside ``embed_slots`` in the loop's one
+    counter call a batch: equal where every step's attention lowered to the
+    kernel, 0 where it lowered to XLA's form or the embedder has none; the
+    batch span carries the form."""
+    from opencv_facerecognizer_tpu.runtime.fakes import InstantPipeline
+    from opencv_facerecognizer_tpu.utils import metric_names as mn
+    from opencv_facerecognizer_tpu.utils.tracing import BATCH_TOPIC, Tracer
+
+    class Reporting(InstantPipeline):
+        def recognize_batch_packed(self, frames):
+            packed = super().recognize_batch_packed(frames)
+            slots = int(np.asarray(frames).shape[0]) * 2
+            self.last_dispatch_info.update(embed_slots=slots, detect_frames=slots // 2)
+            if form is not None:
+                self.last_dispatch_info["embed_attention"] = form
+            return packed
+
+    connector = FakeConnector()
+    tracer = Tracer(ring_size=256, sample=1.0, seed=0)
+    service = RecognizerService(Reporting((16, 16)), connector, batch_size=8,
+                                bucket_sizes=(4, 8), frame_shape=(16, 16),
+                                flush_timeout=0.05, tracer=tracer)
+    service.start(warmup=False)
+    try:
+        sent = 0
+        for burst in (3, 7):
+            for _ in range(burst):
+                connector.inject(FRAME_TOPIC, {"frame": np.zeros((16, 16), np.float32),
+                                               "meta": {"i": sent}})
+                sent += 1
+            deadline = time.monotonic() + 60
+            while (len(connector.messages(RESULT_TOPIC)) < sent
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+    finally:
+        assert service.drain(timeout=30.0)
+        service.stop()
+    assert service.metrics.counter(mn.EMBED_SLOTS) == (4 + 8) * 2
+    assert service.metrics.counter(mn.EMBED_ATTN_KERNEL_SLOTS) == (
+        (4 + 8) * 2 if counted else 0)
+    spans = [s for s in tracer.snapshot(BATCH_TOPIC) if s["stage"] == "dispatch"]
+    assert len(spans) == 2 and {s.get("attention") for s in spans} == {form}
+
+
+@pytest.mark.parametrize("make", [
+    lambda: vit.ViTEmbedding(input_size=(18, 18), embed_dim=32, depth=1, heads=2, out_dim=8),
+    lambda: iresnet.IResNetEmbedding(input_size=(16, 16), embed_dim=8, stem_features=4,
+                                     stage_features=(4, 4, 8, 8), stage_blocks=(1, 1, 1, 1))],
+    ids=["vit", "iresnet"])
+def test_a_feature_is_freed_by_its_reference_count_alone(make):
+    """The jitted ``_apply`` holds the net and not the feature: no cycle, so
+    a feature that goes out of scope gives its parameters back at once (a
+    set-up that makes one embedder and loads another held 455 MB of ViT-B
+    twice on the chip until the cycle collector happened to run)."""
+    import gc
+    import weakref
+
+    gc.collect()
+    gc.disable()
+    try:
+        feature = make()
+        size = feature.input_size
+        feature.compute(np.random.default_rng(0).uniform(0, 255, (4, *size)).astype(np.float32))
+        feature.extract(np.zeros((2, *size), np.float32))
+        gone = weakref.ref(feature)
+        del feature
+        assert gone() is None
+    finally:
+        gc.enable()
